@@ -5,7 +5,7 @@ PYTHON ?= python
 
 ANALYZE_SCOPE = edl_tpu edl_tpu/serving edl_tpu/serving/kvcache.py edl_tpu/serving/router.py edl_tpu/ckpt_plane edl_tpu/parallel/planner.py edl_tpu/runtime/compile_cache.py bench.py bench_rescale.py bench_pipeline.py bench_coord.py bench_collective.py bench_serve.py
 
-.PHONY: analyze analyze-json baseline test chaos chaos-composed chaos-preempt lint obs-smoke serve-smoke serve-lm-smoke ckpt-plane-smoke modelcheck modelcheck-native tsan-smoke bench-coord-smoke bench-replan-smoke bench-spot-smoke verify bench-pipeline bench-coord bench-collective bench-serve
+.PHONY: analyze analyze-json baseline test chaos chaos-composed chaos-preempt lint obs-smoke serve-smoke serve-lm-smoke ckpt-plane-smoke modelcheck modelcheck-native tsan-smoke bench-coord-smoke bench-replan-smoke bench-spot-smoke verify bench-pipeline bench-coord bench-collective bench-serve chip-smoke
 
 analyze:
 	$(PYTHON) -m edl_tpu.analysis $(ANALYZE_SCOPE)
@@ -154,9 +154,9 @@ bench-spot-smoke:
 verify: analyze test modelcheck modelcheck-native serve-smoke serve-lm-smoke ckpt-plane-smoke tsan-smoke chaos-preempt bench-coord-smoke bench-replan-smoke bench-spot-smoke
 
 ## Pipeline-schedule crossover sweep at CPU-sim scale; regenerates
-## BENCH_PIPELINE.json (the artifact behind BENCH_NOTES.md's table).
+## BENCH_PIPELINE.json (the artifact behind doc/performance.md's guidance).
 bench-pipeline:
-	$(PYTHON) bench_pipeline.py
+	JAX_PLATFORMS=cpu EDL_BENCH_PLATFORM=cpu $(PYTHON) bench_pipeline.py
 
 ## Coordinator control-plane load bench at 100/1k/10k simulated workers;
 ## regenerates BENCH_COORD.json (doc/performance.md, control-plane section).
@@ -167,12 +167,20 @@ bench-coord:
 ## bucketed-overlap accumulation) on flat + hierarchical meshes;
 ## regenerates BENCH_COLLECTIVE.json (doc/performance.md, data-plane section).
 bench-collective:
-	$(PYTHON) bench_collective.py
+	JAX_PLATFORMS=cpu EDL_BENCH_PLATFORM=cpu $(PYTHON) bench_collective.py
 
 ## Serving-tier arms: open-loop load vs batching-on/off, per-bucket-config
 ## p50/p99 + QPS/chip, and rescale-under-traffic (replica added + drained
 ## mid-load, zero dropped requests); regenerates BENCH_SERVE.json.
 bench-serve:
-	$(PYTHON) bench_serve.py
+	JAX_PLATFORMS=cpu $(PYTHON) bench_serve.py
+
+## Chip bring-up proof: drives the LM train and serve paths once on one TPU
+## v5e chip at GPT-2-medium widths and checks what comes out (see the
+## script's docstring). Sets no platform: it fails where JAX finds no TPU.
+## `python chip_smoke.py --chips 4` (rescale across four chips) is run by
+## hand on a four-chip host.
+chip-smoke:
+	$(PYTHON) chip_smoke.py
 
 lint: analyze

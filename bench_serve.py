@@ -53,7 +53,8 @@ from __future__ import annotations
 
 import os
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# Which platform JAX uses is the environment's choice (`make bench-serve` sets
+# JAX_PLATFORMS=cpu); this only gives a CPU run its eight virtual devices.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

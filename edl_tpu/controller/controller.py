@@ -53,8 +53,8 @@ class Controller:
         self.autoscaler = Autoscaler(cluster, cfg)
         self.autoscaler.on_scaled = self._on_scaled
         # Rescale targets also flow into each job's coordinator KV so live
-        # workers actually observe them (VERDICT r2 gap #2: the elastic
-        # story's two halves, now connected).
+        # workers actually observe them (the elastic story's two halves,
+        # connected).
         self.actuator = CoordinatorActuator()
         self.autoscaler.actuator = self.actuator
         self.updaters: Dict[str, JobUpdater] = {}

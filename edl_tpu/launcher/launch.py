@@ -22,7 +22,6 @@ import os
 import shlex
 import subprocess
 import sys
-import tempfile
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
@@ -34,6 +33,23 @@ log = logging.getLogger("edl_tpu.launcher.launch")
 
 #: coordinator KV key counting trainer process failures job-wide.
 FAILED_COUNT_KEY = "edl/trainer_failed_count"
+
+#: where JAX's persistent compilation cache goes when nothing outside placed
+#: it: one fixed, git-ignored directory at the root of the checkout. The
+#: directory is part of the cache's key, so a path that moves between runs
+#: (a temporary directory, a pid, the time) can never hit.
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+
+def jax_cache_dir() -> str:
+    """The repo's one rule for JAX's persistent compilation cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, that directory and no other;
+    where it is not, the checkout's fixed ``.jax_cache/``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE_DIR
 
 #: fatal signals -> human reason (ref: docker/paddle_k8s:44-60 maps the
 #: shell's 128+N encoding; subprocess reports signal death as -N).
@@ -244,17 +260,14 @@ def start_trainer(
     env = dict(os.environ)
     env.update(extra_env or {})
     cwd = ctx.workspace or None
-    # Persistent XLA compilation cache for the entry, pod-local by default:
-    # a warm restart (RESCALE_EXIT_CODE) re-runs the SAME program at a new
-    # world size it may well have compiled before, and a rescale's recovery
-    # budget is dominated by exactly that recompile on real chips
-    # (BENCH_RESCALE_ONCHIP.json itemizes it). Opt out by exporting
-    # JAX_COMPILATION_CACHE_DIR= (empty).
+    # Persistent XLA compilation cache for the entry: a warm restart
+    # (RESCALE_EXIT_CODE) re-runs the SAME program at a new world size it
+    # may well have compiled before, and a rescale's recovery budget is
+    # dominated by exactly that recompile on real chips. Placed from
+    # outside where JAX_COMPILATION_CACHE_DIR is set (opt out by exporting
+    # it empty), else at the checkout's one fixed path (jax_cache_dir).
     if "JAX_COMPILATION_CACHE_DIR" not in env:
-        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
-            ctx.workspace or tempfile.gettempdir(),
-            f"edl-xla-cache-{ctx.job_name or 'job'}",
-        )
+        env["JAX_COMPILATION_CACHE_DIR"] = jax_cache_dir()
     # Forward pod termination to the entry: K8s (and ProcessCluster)
     # SIGTERM the launcher — pod PID 1. Without forwarding, the training
     # child outlives its pod as an orphan, holding gang membership and

@@ -43,7 +43,6 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from edl_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from edl_tpu.models.base import Model
@@ -546,7 +545,7 @@ def _batch_specs(cfg: TransformerConfig, mesh: Mesh) -> Dict[str, P]:
 
 def _loss(cfg: TransformerConfig, params: dict, batch: dict, mesh: Mesh):
     specs = _batch_specs(cfg, mesh)
-    return shard_map(
+    return jax.shard_map(
         partial(_kernel, cfg, mesh),
         mesh=mesh,
         in_specs=(_param_spec(cfg, mesh), specs["tokens"], specs["targets"]),

@@ -35,8 +35,9 @@ REQUIRED_FAMILIES = (
 
 
 def main() -> int:
-    # Hermetic CPU backend BEFORE jax imports: the smoke must run anywhere.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # Which platform JAX uses is the environment's choice (`make
+    # obs-smoke` sets JAX_PLATFORMS=cpu); this only gives a CPU run its
+    # eight virtual devices, and must happen BEFORE jax is imported.
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

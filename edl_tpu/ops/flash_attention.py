@@ -18,14 +18,19 @@ per-row logsumexp ``L = m + log(den)``; backward recomputes ``P = exp(S -
 L)`` blockwise (never storing it) with ``delta = rowsum(dO * O)`` folded
 in: dS = P * (dP - delta) * scale, dQ = dS K, dK = dS^T Q, dV = P^T dO.
 
-Shapes follow the models' convention: q/k/v are (B, S, H, D). Unaligned
-sequence lengths pad up to the block size: padded KEY rows are masked by a
-valid-length compare; padded QUERY rows produce unobserved garbage and are
-sliced away.
+Shapes follow the models' convention: q/k/v are (B, S, H, D). Blocks are
+multiples of 128 rows; unaligned sequence lengths pad up to the block size:
+padded KEY rows are masked by a valid-length compare; padded QUERY rows
+produce unobserved garbage and are sliced away.
 
-On CPU (tests, the virtual-device mesh) the kernels run in Pallas
-interpret mode automatically — the same program, executed by the
-interpreter, so the CPU test suite validates exactly what the TPU runs.
+Which engine runs the kernels follows the platform each program is LOWERED
+for (`_pallas_call`): lowered for a TPU — attached or merely described —
+they compile through Mosaic; lowered for the CPU (tests, the virtual-device
+mesh) the same kernel bodies run in the Pallas interpreter, under a named
+scope that shows in the program's text. The interpreter checks the
+kernels' arithmetic; only the TPU's compiler checks that they lower
+(`tests/test_tpu_compile.py`) and only the chip that they are right there
+(`chip_smoke.py`).
 """
 
 from __future__ import annotations
@@ -46,8 +51,26 @@ _NEG_INF = -1e30
 
 
 
-def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+def _pallas_call(kernel, **kwargs):
+    """`pl.pallas_call` whose execution mode follows the platform the
+    program is LOWERED for, never the process's default backend: a program
+    lowered for a TPU (attached, or a described topology) gets the Mosaic
+    kernel, a program lowered for the CPU gets the Pallas interpreter, and
+    no setting can route a TPU program through the interpreter. The
+    interpreted branch is named, so `compiled.as_text()` shows
+    ``flash_attention_interpreted`` where it was taken and
+    ``tpu_custom_call`` where it was not."""
+    mosaic = pl.pallas_call(kernel, **kwargs)
+    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+
+    def on_cpu(*args):
+        with jax.named_scope("flash_attention_interpreted"):
+            return interpreted(*args)
+
+    def call(*args):
+        return jax.lax.platform_dependent(*args, cpu=on_cpu, default=mosaic)
+
+    return call
 
 
 def _pad_seq(x: jax.Array, mult: int) -> jax.Array:
@@ -62,27 +85,38 @@ def _positions(start, shape, dim):
 
 
 def _masked_scores(q, k, *, q_start, k_start, k_origin, k_len, scale,
-                   causal, blk_q, blk_k):
+                   causal, blk_q, blk_k, transposed=False):
     """Shared by all three kernels: f32 scores with invalid entries at the
-    ``_NEG_INF`` sentinel, plus the validity mask itself.
+    ``_NEG_INF`` sentinel, plus the validity mask itself. ``transposed``
+    gives both as (blk_k, blk_q) — keys on sublanes, queries on lanes —
+    which is the orientation the backward kernels work in.
 
     Callers must mask their exp() THROUGH ``valid`` (``where(valid,
     exp(...), 0)``), never infer it back from the scores: a fully-masked
     row's running max / lse lands exactly on the sentinel, so
     ``exp(s - m)`` would be 1 there, not 0."""
+    lhs, rhs = (k, q) if transposed else (q, k)
+    shape = (blk_k, blk_q) if transposed else (blk_q, blk_k)
+    q_dim, k_dim = (1, 0) if transposed else (0, 1)
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        lhs, rhs, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    ) * scale  # (blk_q, blk_k)
-    k_pos = _positions(k_start, (blk_q, blk_k), 1)
+    ) * scale
+    k_pos = _positions(k_start, shape, k_dim)
     valid = k_pos - k_origin < k_len  # mask padded key rows
     if causal:
-        q_pos = _positions(q_start, (blk_q, blk_k), 0)
+        q_pos = _positions(q_start, shape, q_dim)
         valid = jnp.logical_and(valid, k_pos <= q_pos)
     return jnp.where(valid, s, _NEG_INF), valid
 
 
 # -- forward -------------------------------------------------------------------
+#
+# Per-query-row statistics (running max, denominator, logsumexp, delta)
+# cross HBM as ROWS: shape (BH, 1, Sq), block (1, 1, blk_q). Mosaic wants
+# a block's last two dims divisible by (8, 128) or equal to the array's;
+# (1, blk_q) over (1, Sq) is, (1, blk_q) over (BH, Sq) is not, and a
+# (blk_q, 1) column would pad every value to a 128-lane row in HBM.
 
 
 def _fwd_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref,
@@ -128,25 +162,30 @@ def _fwd_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref,
 
     @pl.when(ki == n_k - 1)
     def _emit():
-        l = l_ref[:, :1]
+        l = l_ref[...]  # (blk_q, 128), every lane the same value
         # fully-masked (padded) query rows: den 0 -> emit 0, lse -inf
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(
-            l[:, 0] > 0, m_ref[:, 0] + jnp.log(safe[:, 0]), _NEG_INF
-        )
+        o_ref[0] = (acc_ref[...] / safe[:, :1]).astype(o_ref.dtype)
+        lse = jnp.where(l > 0, m_ref[...] + jnp.log(safe), _NEG_INF)
+        # column -> row: the lane-broadcast tile transposes on the XLU to
+        # (128, blk_q), whose every sublane is the row we want.
+        lse_ref[0] = lse.T[:1]
+
+
+def _row_spec(blk_q, index_map):
+    return pl.BlockSpec((1, 1, blk_q), index_map)
 
 
 def _fwd(q3, k3, v3, qo, ko, kl, *, scale, causal, blk_q, blk_k,
          out_dtype):
-    """q3: (BH, Sq, D); k3/v3: (BH, Sk, D) -> (o3, lse (BH, Sq) f32)."""
+    """q3: (BH, Sq, D); k3/v3: (BH, Sk, D) -> (o3, lse (BH, 1, Sq) f32)."""
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k
     )
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.pallas_call(
+    return _pallas_call(
         kernel,
         grid=(BH, Sq // blk_q, Sk // blk_k),
         in_specs=[
@@ -157,22 +196,46 @@ def _fwd(q3, k3, v3, qo, ko, kl, *, scale, causal, blk_q, blk_k,
         ],
         out_specs=[
             pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i)),
+            _row_spec(blk_q, lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), out_dtype),
-            jax.ShapeDtypeStruct((BH, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, 128), jnp.float32),  # running max m
             pltpu.VMEM((blk_q, 128), jnp.float32),  # running denominator l
             pltpu.VMEM((blk_q, D), jnp.float32),  # output accumulator
         ],
-        interpret=_interpret(),
     )(qo, ko, kl, q3, k3, v3)
 
 
 # -- backward ------------------------------------------------------------------
+#
+# Both kernels work on TRANSPOSED tiles, (blk_k, blk_q): the per-query
+# lse/delta rows then broadcast down the sublanes as they arrive, and
+# dV = P^T dO and dK = dS^T Q become plain matmuls.
+
+
+def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, q_start,
+              k_start, k_origin, k_len, scale, causal, blk_q, blk_k):
+    """(P^T, dS^T, dO) for one (key block, query block) pair, f32."""
+    q = q_ref[0]
+    k = k_ref[0]
+    v = v_ref[0]
+    do = do_ref[0].astype(jnp.float32)  # (blk_q, D)
+    s_t, valid = _masked_scores(
+        q, k, q_start=q_start, k_start=k_start, k_origin=k_origin,
+        k_len=k_len, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
+        transposed=True,
+    )
+    p_t = jnp.where(valid, jnp.exp(s_t - lse_ref[0]), 0.0)  # (blk_k, blk_q)
+    dp_t = jax.lax.dot_general(
+        v.astype(jnp.float32), do, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds_t = p_t * (dp_t - delta_ref[0]) * scale
+    return p_t, ds_t, do
 
 
 def _bwd_dq_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref, do_ref,
@@ -191,25 +254,16 @@ def _bwd_dq_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(live)
     def _block():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)  # (blk_q, D)
-        s, valid = _masked_scores(
-            q, k, q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
+        _, ds_t, _ = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
             k_len=kl_ref[0], scale=scale, causal=causal,
             blk_q=blk_q, blk_k=blk_k,
         )
-        p = jnp.where(valid, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (blk_q, blk_k)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
         acc_ref[...] += jax.lax.dot_general(
-            ds, k.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            ds_t, k_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        )  # dS K: (blk_q, D)
 
     @pl.when(ki == n_k - 1)
     def _emit():
@@ -233,29 +287,19 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(live)
     def _block():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        s, valid = _masked_scores(
-            q, k, q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
+        p_t, ds_t, do = _bwd_tile(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+            q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
             k_len=kl_ref[0], scale=scale, causal=causal,
             blk_q=blk_q, blk_k=blk_k,
         )
-        p = jnp.where(valid, jnp.exp(s - lse_ref[0][:, None]), 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
+        dv_acc[...] += jnp.dot(
+            p_t, do, preferred_element_type=jnp.float32
+        )  # P^T dO: (blk_k, D)
+        dk_acc[...] += jnp.dot(
+            ds_t, q_ref[0].astype(jnp.float32),
             preferred_element_type=jnp.float32,
-        )  # (blk_k, D)
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0][:, None]) * scale
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (blk_k, D)
+        )  # dS^T Q: (blk_k, D)
 
     @pl.when(qi == n_q - 1)
     def _emit():
@@ -272,14 +316,14 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
     # into delta with a sign flip. dlse is zeros when lse wasn't consumed.
     delta = jnp.sum(
         do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
-    ) - dlse.astype(jnp.float32)  # (BH, Sq)
+    )[:, None, :] - dlse.astype(jnp.float32)  # (BH, 1, Sq)
 
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
     q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0))
-    row_spec = pl.BlockSpec((1, blk_q), lambda b, i, j: (b, i))
+    row_spec = _row_spec(blk_q, lambda b, i, j: (b, 0, i))
     k_spec = pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0))
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k),
         grid=(BH, Sq // blk_q, Sk // blk_k),
@@ -288,14 +332,13 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        interpret=_interpret(),
     )(qo, ko, kl, q3, k3, v3, do3, lse, delta)
 
     # K outer / Q inner: the accumulators belong to the K block.
     q_spec_t = pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, i, 0))
-    row_spec_t = pl.BlockSpec((1, blk_q), lambda b, j, i: (b, i))
+    row_spec_t = _row_spec(blk_q, lambda b, j, i: (b, 0, i))
     k_spec_t = pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv = _pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k),
         grid=(BH, Sk // blk_k, Sq // blk_q),
@@ -314,7 +357,6 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
             pltpu.VMEM((blk_k, D), jnp.float32),
             pltpu.VMEM((blk_k, D), jnp.float32),
         ],
-        interpret=_interpret(),
     )(qo, ko, kl, q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 
@@ -392,10 +434,11 @@ def flash_attention(
     def round_up(n, m):
         return ((n + m - 1) // m) * m
 
-    # Tile alignment: blk_q is a sublane extent (multiple of 8), blk_k a
-    # lane extent (multiple of 128); short sequences shrink the block and
-    # pad up to it, with padded keys masked via the valid-length compare.
-    blk_q = min(block_q, round_up(Sq, 8))
+    # Tile alignment: both extents are multiples of 128 (scores and their
+    # transposes are whole MXU tiles, statistics rows are whole lane rows);
+    # short sequences shrink the block to one tile and pad up to it, with
+    # padded keys masked via the valid-length compare.
+    blk_q = min(block_q, round_up(Sq, 128))
     blk_k = min(block_k, round_up(Sk, 128))
 
     def to3(x):  # (B, S, H, D) -> (B*H, S, D)
@@ -419,4 +462,4 @@ def flash_attention(
     out = o3[:, :Sq].reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     if not return_lse:
         return out
-    return out, lse3[:, :Sq].reshape(B, H, Sq)
+    return out, lse3[:, 0, :Sq].reshape(B, H, Sq)

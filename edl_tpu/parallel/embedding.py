@@ -27,7 +27,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from edl_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -182,7 +181,7 @@ class ShardedEmbedding:
             # Return each participant its own batch slice, summed over owners.
             return jax.lax.psum_scatter(contrib, axis, scatter_dimension=0, tiled=True)
 
-        return shard_map(
+        return jax.shard_map(
             kernel,
             mesh=mesh,
             in_specs=(self.table_spec(), P(axis)),
@@ -207,7 +206,7 @@ class ShardedEmbedding:
             return jax.lax.psum(contrib, shard_ax)
 
         out_spec = P(batch_ax, None) if have else P(None, None)
-        return shard_map(
+        return jax.shard_map(
             kernel,
             mesh=mesh,
             in_specs=(self.table_spec(), batch_spec),

@@ -54,7 +54,7 @@ to GPipe's effective ``M + n - 1`` — plain 1F1B's win is HBM headroom (O(n)
 stash admits a much larger M where GPipe OOMs). Interleaving closes that
 gap at the schedule level: bubble ``(nv + n - 2)/v`` full-tick equivalents
 vs plain's ``2(n-1)``. The committed sweep (`bench_pipeline.py` ->
-`BENCH_PIPELINE.json`, crossover table in `BENCH_NOTES.md`) quantifies all
+`BENCH_PIPELINE.json`) quantifies all
 three on the same mesh: per-step wall time and stash bytes across M and v.
 Pick the schedule from those numbers — GPipe while the O(M) stash fits,
 1F1B when activation memory binds, interleaved 1F1B (v >= 2, n >= 3) to buy
@@ -83,7 +83,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from edl_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -279,7 +278,7 @@ def pipeline_apply(
             microbatches=M,
         )
 
-    return shard_map(
+    return jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(param_specs, x_spec),

@@ -29,7 +29,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from edl_tpu.parallel.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 #: scores below this are "masked"; finite so exp() is exactly 0 without nans.
@@ -250,7 +249,7 @@ def ring_attention(
         scale=scale,
         flash=flash,
     )
-    return shard_map(
+    return jax.shard_map(
         kernel,
         mesh=mesh,
         in_specs=(spec, spec, spec),

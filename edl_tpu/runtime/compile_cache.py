@@ -141,8 +141,13 @@ class CompileCache:
 
     # -- load ------------------------------------------------------------------
 
-    def load(self, key: str) -> Optional[Any]:
+    def load(self, key: str, mesh) -> Optional[Any]:
         """Return a ready-to-dispatch executable for ``key`` or None.
+
+        ``mesh`` is the mesh ``key`` was derived from: a stored executable
+        is loaded onto exactly its devices, in its order (JAX would
+        otherwise load it onto every device of the backend and refuse
+        inputs placed on the mesh).
 
         Any defect in the stored entry — torn write, bit rot, a payload
         written by different code — evicts the entry and reports a miss;
@@ -178,7 +183,8 @@ class CompileCache:
             from jax.experimental import serialize_executable
 
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=list(mesh.devices.flat))
         except Exception as e:  # edl: noqa[EDL005] any unreadable/undeserializable entry (torn write, jax version drift, device set gone) must evict and demote to a normal compile, never fail the rescale
             self._evict(path)
             self.misses.inc(reason="corrupt")

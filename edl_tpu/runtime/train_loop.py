@@ -704,7 +704,7 @@ class Trainer:
                 _aval_signature(abstract_batch),
                 _aval_signature(abstract_state),
             )
-            hit = cache.load(cache_key)
+            hit = cache.load(cache_key, self.mesh)
             if hit is not None:
                 seconds = time.perf_counter() - t0
                 self._warm = _WarmStep(

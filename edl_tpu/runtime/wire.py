@@ -3,8 +3,7 @@
 The reference streams minibatches to trainers from recordio files on local
 disk (`example/ctr/ctr/train.py:221-227` downloads its shard first), so its
 input path is never the bottleneck. On TPU the host->device hop is often the
-narrowest link in the system (PCIe on a TPU VM; far less over remote
-tunnels), so the framework ships a transport codec: batches cross the wire in
+narrowest link in the system (PCIe on a TPU VM), so the framework ships a transport codec: batches cross the wire in
 the smallest dtype that preserves training semantics and are decoded on
 device inside the jitted step, where the casts fuse into the first consumers
 for free.

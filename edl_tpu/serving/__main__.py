@@ -57,9 +57,10 @@ N_STREAMS = 12
 MAX_NEW_TOKENS = 8
 
 
-def _hermetic_cpu() -> None:
-    # Hermetic CPU backend BEFORE jax imports: the smokes must run anywhere.
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+def _eight_host_devices() -> None:
+    # Which platform JAX uses is the environment's choice (`make
+    # serve-smoke` sets JAX_PLATFORMS=cpu); this only gives a CPU run its
+    # eight virtual devices, and must happen BEFORE jax is imported.
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -68,7 +69,7 @@ def _hermetic_cpu() -> None:
 
 
 def main_lm() -> int:
-    _hermetic_cpu()
+    _eight_host_devices()
 
     import json
     import tempfile
@@ -167,7 +168,7 @@ def main_lm() -> int:
 
 
 def main() -> int:
-    _hermetic_cpu()
+    _eight_host_devices()
 
     import json
     import tempfile

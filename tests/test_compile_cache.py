@@ -6,6 +6,7 @@ dispatch cache).
 import jax
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec
 
 from edl_tpu.models import fit_a_line
 from edl_tpu.parallel import local_mesh
@@ -40,20 +41,24 @@ def test_round_trip_serves_identical_executable(tmp_path):
     def f(x):
         return x * 2.0 + 1.0
 
-    aval = jax.ShapeDtypeStruct((8,), np.float32)
+    # Compiled FOR the mesh the key names: an entry is loaded back onto
+    # exactly that mesh's devices (a program compiled for one default
+    # device under an 8-device mesh's key was never a coherent entry).
+    aval = jax.ShapeDtypeStruct(
+        (8,), np.float32, sharding=NamedSharding(mesh, PartitionSpec("data")))
     compiled = jax.jit(f).lower(aval).compile()
     cache = CompileCache(str(tmp_path))
     key = cache.key(mesh, "test-config", repr(aval), "no-state")
-    assert cache.load(key) is None  # absent
+    assert cache.load(key, mesh) is None  # absent
     assert cache.store(key, compiled)
     assert cache.entries() == 1
 
     # Memory tier: the very object back.
-    assert cache.load(key) is compiled
+    assert cache.load(key, mesh) is compiled
 
     # Disk tier: drop the memory map, deserialize, execute, compare.
     cache.clear_memory()
-    loaded = cache.load(key)
+    loaded = cache.load(key, mesh)
     assert loaded is not None and loaded is not compiled
     x = np.arange(8, dtype=np.float32)
     np.testing.assert_allclose(np.asarray(loaded(x)),
@@ -87,14 +92,14 @@ def test_corrupted_entry_evicts_and_recompiles(tmp_path):
         header = f.readline()
         f.write(b"\x00garbage\x00")  # tear the payload, keep the header
     before = cache.misses.value(reason="corrupt")
-    assert cache.load(key) is None
+    assert cache.load(key, mesh) is None
     assert cache.misses.value(reason="corrupt") == before + 1
     import os
     assert not os.path.exists(path), "corrupt entry must be evicted"
     # and the slot is clean for a fresh store
     assert cache.store(key, compiled)
     cache.clear_memory()
-    assert cache.load(key) is not None
+    assert cache.load(key, mesh) is not None
 
 
 def test_stale_fingerprint_evicts(tmp_path):
@@ -112,7 +117,7 @@ def test_stale_fingerprint_evicts(tmp_path):
     # and must refuse the bytes.
     reader = CompileCache(str(tmp_path), fingerprint="bbbb333344445555")
     before = reader.misses.value(reason="stale")
-    assert reader.load(key) is None
+    assert reader.load(key, mesh) is None
     assert reader.misses.value(reason="stale") == before + 1
     assert reader.entries() == 0
 

@@ -8,6 +8,7 @@ FakeCluster.
 import io
 import json
 import os
+import subprocess
 import sys
 import time
 
@@ -133,11 +134,13 @@ class TestTrainerExec:
     def test_start_trainer_sets_persistent_compile_cache(self, tmp_path,
                                                          monkeypatch):
         """Warm restarts re-run the same XLA program; the launcher points
-        the entry at a pod-local persistent compile cache so the rescale
-        budget pays the compile once. Explicit env (incl. empty = opt out)
-        wins."""
+        the entry at a persistent compile cache so the rescale budget pays
+        the compile once. The directory is part of the cache's key, so it
+        is ONE fixed path inside the checkout — never derived from the
+        workspace, the job name or a temporary directory. Explicit env
+        (incl. empty = opt out) wins."""
         from edl_tpu.coordinator.server import CoordinatorServer
-        from edl_tpu.launcher.launch import start_trainer
+        from edl_tpu.launcher.launch import jax_cache_dir, start_trainer
 
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         with CoordinatorServer() as server:
@@ -150,12 +153,29 @@ class TestTrainerExec:
                 termination_log=str(tmp_path / "term"),
             )
             assert start_trainer(ctx) == 0
-            cache_dir = out.read_text()
-            assert cache_dir == str(tmp_path / "edl-xla-cache-cachejob")
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert out.read_text() == os.path.join(repo, ".jax_cache")
+            assert out.read_text() == jax_cache_dir()
 
             monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
             assert start_trainer(ctx) == 0
             assert out.read_text() == ""  # explicit opt-out respected
+
+            # placed from outside: that directory and no other
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+            assert start_trainer(ctx) == 0
+            assert out.read_text() == str(tmp_path / "c") == jax_cache_dir()
+
+    def test_launcher_process_never_loads_jax(self):
+        """One process per chip: the launcher starts the entry as a child
+        that needs the chip, so the launcher itself must never initialise a
+        JAX backend. It does not even import jax."""
+        code = ("import sys; import edl_tpu.launcher.launch; "
+                "import edl_tpu.launcher.discovery; "
+                "sys.exit(1 if 'jax' in sys.modules else 0)")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert subprocess.run([sys.executable, "-c", code],
+                              cwd=repo).returncode == 0
 
 
 def _nodes(n=2):

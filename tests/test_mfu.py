@@ -40,7 +40,7 @@ def test_transformer_flops_track_config():
     assert big - small == pytest.approx(3 * 2 * per_layer_fwd * cfg.seq_len * 4)
 
 
-def test_peak_table_and_override(monkeypatch):
+def test_peak_table():
     v4 = types.SimpleNamespace(device_kind="TPU v4", platform="tpu")
     assert peak_tflops_per_chip(v4) == 275.0
     v6 = types.SimpleNamespace(device_kind="TPU v6e", platform="tpu")
@@ -52,8 +52,15 @@ def test_peak_table_and_override(monkeypatch):
     assert peak_tflops_per_chip(v6l) == 918.0
     cpu = types.SimpleNamespace(device_kind="cpu", platform="cpu")
     assert peak_tflops_per_chip(cpu) is None
-    monkeypatch.setenv("EDL_TPU_PEAK_TFLOPS", "123.5")
-    assert peak_tflops_per_chip(cpu) == 123.5
+
+
+def test_unknown_accelerator_is_an_error_not_a_default():
+    unknown = types.SimpleNamespace(device_kind="TPU v9x", platform="tpu")
+    with pytest.raises(ValueError, match="v9x"):
+        peak_tflops_per_chip(unknown)
+    model = transformer.make_model(d_model=64, n_layers=1, n_heads=2, d_ff=64)
+    with pytest.raises(ValueError, match="v9x"):
+        mfu_fields(model, 8, steps_per_sec=1.0, device=unknown)
 
 
 def test_mfu_fields_analytic():

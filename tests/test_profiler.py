@@ -201,12 +201,10 @@ def test_device_memory_stats_shape():
             assert isinstance(v, int)
 
 
-def test_summary_reports_mfu_when_model_given(monkeypatch):
+def test_summary_reports_mfu_when_model_given():
     from edl_tpu.models import fit_a_line
     from edl_tpu.tools.profiler import StepProfiler
 
-    # pin the no-peak path: the env override would add an mfu key
-    monkeypatch.delenv("EDL_TPU_PEAK_TFLOPS", raising=False)
     prof = StepProfiler(warmup=0, model=fit_a_line.MODEL)
     prof.start()
     for _ in range(3):

@@ -1,0 +1,176 @@
+"""Compile-only guards for the TPU: the main path's programs, at real widths,
+compiled by the TPU's own compiler for a DESCRIBED v5e:2x2 topology.
+
+No chip is attached and nothing runs — these say nothing about results or
+times. They catch what interpret mode cannot: a block shape Mosaic refuses,
+a kernel that does not lower, a program that silently takes the Pallas
+interpreter or the dense path. The platform is chosen by the shardings of
+the abstract arguments (devices of the described topology), which is what
+`flash_attention`'s platform dispatch keys on; the process's default backend
+stays the CPU.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may load the TPU's library, so nothing here may touch it
+at import or at collection time (see the on-chip-measurement guide, §2).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from edl_tpu.models import transformer
+from edl_tpu.ops import flash_attention
+from edl_tpu.parallel import MeshSpec, build_mesh
+from edl_tpu.parallel.collective import zero_shard_spec
+from edl_tpu.runtime.train_loop import Trainer, TrainerConfig, TrainState
+
+#: GPT-2-medium widths (chip_smoke.py's); depth is cut to keep compiles short
+WIDTHS = dict(vocab_size=50257, d_model=1024, n_heads=16, d_ff=4096,
+              seq_len=1024)
+N_LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to JAX's persistent cache
+    # but cannot be read back without the chip: keep the cache out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _qkv(sharding, seq):
+    return (jax.ShapeDtypeStruct((2, seq, 16, 64), jnp.bfloat16,
+                                 sharding=sharding),) * 3
+
+
+def _compile_flash(fn, args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    assert "flash_attention_interpreted" not in text
+
+
+def test_flash_forward(one_chip):
+    _compile_flash(lambda q, k, v: flash_attention(q, k, v, causal=True),
+                   _qkv(one_chip, 1024))
+
+
+def test_flash_backward(one_chip):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    _compile_flash(jax.grad(loss, argnums=(0, 1, 2)), _qkv(one_chip, 1024))
+
+
+def test_flash_with_lse_as_the_ring_calls_it(one_chip):
+    """`_ring_flash_local`'s hop engine: global offsets, f32 partial output
+    and a differentiable logsumexp."""
+
+    def hop(q, k, v):
+        out, lse = flash_attention(q, k, v, causal=True, return_lse=True,
+                                   q_offset=1024, k_offset=0)
+        return out.sum() + lse.sum()
+
+    _compile_flash(jax.value_and_grad(hop, argnums=(0, 1, 2)),
+                   _qkv(one_chip, 1024))
+
+
+@pytest.mark.parametrize("seq", [200, 12])
+def test_flash_short_sequence_pads_to_the_block(one_chip, seq):
+    """Sequence lengths that are no multiple of the 128-row tile: the
+    padding branch, forward and backward."""
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                   _qkv(one_chip, seq))
+
+
+def _param_avals(model, mesh):
+    """The model's parameters as shapes placed by its own `param_spec` (no
+    array can be put on a described device)."""
+    shapes = jax.eval_shape(lambda key: model.init(key, mesh),
+                            jax.random.PRNGKey(0))
+    return jax.tree_util.tree_map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, model.param_spec(mesh),
+    )
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_serving_step_at_one_bucket(topo, phase):
+    """`LMServingReplica._compile_all`'s programs at batch bucket 4, seq
+    bucket 1024."""
+    mesh = build_mesh(MeshSpec({"data": 1}), list(topo.devices)[:1])
+    model = transformer.make_model(**WIDTHS, n_layers=N_LAYERS)
+    cfg = model.config
+    params = _param_avals(model, mesh)
+    rep = NamedSharding(mesh, P())
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
+    if phase == "prefill":
+        step, args = transformer.make_prefill_step(cfg), (
+            params, i32(4, 1024), i32(4))
+    else:
+        cache = jax.ShapeDtypeStruct(
+            (N_LAYERS, 4, 1024, cfg.n_heads, cfg.head_dim), jnp.bfloat16,
+            sharding=rep)
+        step, args = transformer.make_decode_step(cfg), (
+            params, cache, cache, i32(4), i32(4))
+    compiled = jax.jit(step).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2**30
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_train_step(topo, chips):
+    """One whole `Trainer.train_step` program: flash attention under
+    `shard_map`, per-block remat, Adam; on four chips with ZeRO-1, whose
+    collectives must show in the program."""
+    mesh = build_mesh(MeshSpec({"data": chips}), list(topo.devices)[:chips])
+    model = transformer.make_model(**WIDTHS, n_layers=N_LAYERS, remat=True)
+    zero1 = chips > 1
+    trainer = Trainer(model, mesh, TrainerConfig(optimizer="adam",
+                                                 shard_opt_state=zero1))
+    params = _param_avals(model, mesh)
+
+    def moment(x):
+        spec = (zero_shard_spec(x.shape, mesh, "data")
+                if zero1 and x.ndim else None)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=NamedSharding(mesh, spec or P()))
+
+    state = TrainState(
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
+        params,
+        jax.tree_util.tree_map(moment,
+                               jax.eval_shape(trainer.opt.init, params)),
+    )
+    tokens = jax.ShapeDtypeStruct((8, 1024), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    compiled = trainer._jit_step.lower(
+        state, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the train step took the dense path"
+    assert "flash_attention_interpreted" not in text
+    if zero1:
+        assert "all-reduce" in text and "all-gather" in text
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15 * 2**30
