@@ -400,30 +400,36 @@ def _block(cfg: TransformerConfig, mesh: Mesh, n_sp: int, x: jax.Array, bp: dict
     """One decoder block on local shards. x: (Bl, Sl, D) bf16."""
     Dh = cfg.head_dim
     B, S, D = x.shape
+    # The scopes name the block's parts in every operation's `op_name`, so
+    # a device trace can tell attention from the matmuls around it.
     h = _rmsnorm(x, bp["ln1"])
-    qkv = (
-        jnp.einsum(
-            "bsd,dthe->bsthe", h, bp["wqkv"].astype(jnp.bfloat16)
-        )
-        + bp["bqkv"].astype(jnp.bfloat16)
-    )  # (Bl, Sl, 3, Hl, Dh)
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-    attn = _ring_attention_local(
-        q, k, v, seq_axis=cfg.seq_axis, n_shards=n_sp, causal=True,
-        scale=1.0 / math.sqrt(Dh), flash=cfg.flash,
-    )  # (Bl, Sl, Hl, Dh)
-    out = jnp.einsum("bshe,hed->bsd", attn, bp["wo"].astype(jnp.bfloat16))
-    out = _maybe_psum(out.astype(jnp.float32), mesh, cfg.tp_axis) + bp["bo"]
+    with jax.named_scope("attn_proj"):
+        qkv = (
+            jnp.einsum(
+                "bsd,dthe->bsthe", h, bp["wqkv"].astype(jnp.bfloat16)
+            )
+            + bp["bqkv"].astype(jnp.bfloat16)
+        )  # (Bl, Sl, 3, Hl, Dh)
+        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    with jax.named_scope("attn_core"):
+        attn = _ring_attention_local(
+            q, k, v, seq_axis=cfg.seq_axis, n_shards=n_sp, causal=True,
+            scale=1.0 / math.sqrt(Dh), flash=cfg.flash,
+        )  # (Bl, Sl, Hl, Dh)
+    with jax.named_scope("attn_proj"):
+        out = jnp.einsum("bshe,hed->bsd", attn, bp["wo"].astype(jnp.bfloat16))
+        out = _maybe_psum(out.astype(jnp.float32), mesh, cfg.tp_axis) + bp["bo"]
     x = x + out.astype(jnp.bfloat16)
     h = _rmsnorm(x, bp["ln2"])
     aux = jnp.zeros((), jnp.float32)
-    if cfg.moe_experts > 0:
-        o, aux = _moe_ffn(cfg, mesh, h, bp)
-    else:
-        f = jnp.einsum("bsd,df->bsf", h, bp["win"].astype(jnp.bfloat16))
-        f = jax.nn.gelu(f + bp["bin"].astype(jnp.bfloat16))
-        o = jnp.einsum("bsf,fd->bsd", f, bp["wout"].astype(jnp.bfloat16))
-        o = _maybe_psum(o.astype(jnp.float32), mesh, cfg.tp_axis) + bp["bout"]
+    with jax.named_scope("mlp"):
+        if cfg.moe_experts > 0:
+            o, aux = _moe_ffn(cfg, mesh, h, bp)
+        else:
+            f = jnp.einsum("bsd,df->bsf", h, bp["win"].astype(jnp.bfloat16))
+            f = jax.nn.gelu(f + bp["bin"].astype(jnp.bfloat16))
+            o = jnp.einsum("bsf,fd->bsd", f, bp["wout"].astype(jnp.bfloat16))
+            o = _maybe_psum(o.astype(jnp.float32), mesh, cfg.tp_axis) + bp["bout"]
     return x + o.astype(jnp.bfloat16), aux
 
 

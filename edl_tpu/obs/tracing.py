@@ -16,16 +16,26 @@ IS the shared id. The controller's actuator learns the new epoch from
 phase-attributed recovery breakdown (drain -> checkpoint -> warm_compile ->
 restore -> first_step) that ``bench_rescale.py`` commits as
 ``RESCALE_TIMELINE.json``.
+
+One clock with the profiler: every context-managed span
+(:meth:`Tracer.span`) also opens a ``jax.profiler.TraceAnnotation`` of the
+same name, so that while a profiler session runs the span is on a host line
+of the ``.xplane.pb``, beside the device's operations and on their clock.
+This package stays stdlib-only and never imports JAX: the annotation is
+looked up in ``sys.modules``, so a process that has not imported JAX (the
+controller) records to the ring alone, and with no session active an
+annotation is a flag check.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterable, List, Optional, TextIO, Union
+from typing import (Any, Callable, ContextManager, Dict, Iterable, List,
+                    Optional, TextIO, Union)
 
 __all__ = [
     "Span",
@@ -59,7 +69,10 @@ def rescale_trace_id(epoch: int) -> str:
 @dataclass
 class Span:
     """One named interval. ``start``/``end`` are epoch seconds (wall clock:
-    spans from different processes must land on one timeline)."""
+    spans from different processes must land on one timeline); a
+    context-managed span measures its length on ``time.perf_counter`` and
+    sets ``end = start + length``, so a step of the wall clock cannot turn
+    a 0.3 ms span negative."""
 
     name: str
     start: float
@@ -67,6 +80,13 @@ class Span:
     trace_id: str = ""
     component: str = ""
     attrs: Dict[str, Any] = field(default_factory=dict)
+    #: the span that caused this one: the enclosing :meth:`Tracer.span` of
+    #: the same thread (None at the top, and for recorded intervals)
+    parent: Optional["Span"] = field(default=None, repr=False, compare=False)
+    #: cleared inside the ``with`` by a caller that finds the work was not
+    #: there (the wait that met the end of its input): the span then stays
+    #: out of the ring, so that it cannot pass for a short item
+    keep: bool = field(default=True, repr=False, compare=False)
 
     @property
     def seconds(self) -> float:
@@ -84,7 +104,53 @@ class Span:
         }
         if self.attrs:
             d["attrs"] = self.attrs
+        if self.parent is not None:
+            d["parent"] = self.parent.name
         return d
+
+
+def profiler_annotation() -> Optional[Callable[[str], ContextManager]]:
+    """``jax.profiler.TraceAnnotation`` where this process has imported JAX,
+    else None. Never imports JAX itself."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
+class _OpenSpan:
+    """The context manager behind :meth:`Tracer.span`."""
+
+    __slots__ = ("tracer", "span", "mirror", "t0")
+
+    def __init__(self, tracer: "Tracer", span: Span):
+        self.tracer = tracer
+        self.span = span
+
+    def __enter__(self) -> Span:
+        tracer, span = self.tracer, self.span
+        stack = tracer._open_spans()
+        if stack:
+            span.parent = stack[-1]
+        stack.append(span)
+        annotation = tracer.annotation or profiler_annotation()
+        self.mirror = annotation(span.name) if annotation else None
+        if self.mirror is not None:
+            self.mirror.__enter__()
+        span.start = time.time()
+        self.t0 = time.perf_counter()
+        return span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        seconds = time.perf_counter() - self.t0
+        span = self.span
+        if self.mirror is not None:
+            self.mirror.__exit__(exc_type, exc, tb)
+        self.tracer._open_spans().pop()
+        if exc_type is not None:  # a failed phase is still a phase
+            span.attrs["error"] = exc_type.__name__
+        span.end = span.start + seconds
+        if span.keep:
+            self.tracer._append(span)
+        return False
 
 
 class Tracer:
@@ -96,12 +162,25 @@ class Tracer:
     """
 
     def __init__(self, component: str = "", sink: Optional[TextIO] = None,
-                 window: int = 50_000):
+                 window: int = 50_000,
+                 annotation: Optional[Callable[[str], ContextManager]] = None):
         self.component = component
         self.sink = sink
         self.window = window
+        #: what :meth:`span` mirrors through: ``name -> context manager``.
+        #: None (every caller but the tests) is :func:`profiler_annotation`.
+        self.annotation = annotation
         self.spans: List[Span] = []
         self._lock = threading.Lock()
+        self._local = threading.local()
+
+    def _open_spans(self) -> List[Span]:
+        """This thread's stack of open context-managed spans."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     # -- recording -------------------------------------------------------------
 
@@ -114,11 +193,18 @@ class Tracer:
         round down to "it took no time". A microsecond, not a nanosecond:
         these are epoch-seconds floats (~2e9), where double precision eats
         anything under ~2.4e-7 and a 1e-9 clamp silently rounds back to
-        zero length."""
-        if end <= start:
-            end = start + 1e-6
-        span = Span(name=name, start=start, end=end, trace_id=trace_id,
-                    component=component or self.component, attrs=dict(attrs))
+        zero length.
+
+        A recorded interval has no parent and is not mirrored into the
+        profiler's trace: it is already over when it is known, and an
+        annotation can only be opened now."""
+        return self._append(Span(
+            name=name, start=start, end=end, trace_id=trace_id,
+            component=component or self.component, attrs=dict(attrs)))
+
+    def _append(self, span: Span) -> Span:
+        if span.end <= span.start:
+            span.end = span.start + 1e-6
         sink = self.sink
         with self._lock:
             self.spans.append(span)
@@ -132,18 +218,18 @@ class Tracer:
                 pass
         return span
 
-    @contextlib.contextmanager
-    def span(self, name: str, trace_id: str = "", **attrs: Any):
+    def span(self, name: str, trace_id: str = "", component: str = "",
+             **attrs: Any) -> ContextManager[Span]:
         """Context-managed span; records on exit (also on exception, with
-        ``error`` attached — a failed phase is still a phase)."""
-        t0 = time.time()
-        try:
-            yield
-        except BaseException as e:
-            self.record(name, t0, time.time(), trace_id=trace_id,
-                        error=type(e).__name__, **attrs)
-            raise
-        self.record(name, t0, time.time(), trace_id=trace_id, **attrs)
+        ``error`` attached — a failed phase is still a phase). The ``with``
+        yields the :class:`Span`, so the caller can add what it learns
+        inside (``span.attrs["task"] = ...``) and read ``span.seconds``
+        afterwards. ``parent`` is the enclosing span of the same thread, and
+        the span is mirrored into the profiler's trace (module docstring):
+        this is the one place a program span opens an annotation."""
+        return _OpenSpan(self, Span(
+            name=name, start=0.0, end=0.0, trace_id=trace_id,
+            component=component or self.component, attrs=attrs))
 
     def event(self, name: str, trace_id: str = "", **attrs: Any) -> Span:
         """Point-in-time marker (epoch observation, decision taken)."""

@@ -51,7 +51,7 @@ _NEG_INF = -1e30
 
 
 
-def _pallas_call(kernel, **kwargs):
+def _pallas_call(kernel, name, **kwargs):
     """`pl.pallas_call` whose execution mode follows the platform the
     program is LOWERED for, never the process's default backend: a program
     lowered for a TPU (attached, or a described topology) gets the Mosaic
@@ -59,16 +59,24 @@ def _pallas_call(kernel, **kwargs):
     no setting can route a TPU program through the interpreter. The
     interpreted branch is named, so `compiled.as_text()` shows
     ``flash_attention_interpreted`` where it was taken and
-    ``tpu_custom_call`` where it was not."""
-    mosaic = pl.pallas_call(kernel, **kwargs)
-    interpreted = pl.pallas_call(kernel, interpret=True, **kwargs)
+    ``tpu_custom_call`` where it was not.
+
+    ``name`` (``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv``) is the
+    kernel's stable name: Mosaic's kernel name, which names the custom call
+    in a device trace, and a named scope in the `op_name` of whatever
+    implements the call, so a reduction finds the kernel after a refactor
+    renumbers the program's instructions."""
+    mosaic = pl.pallas_call(kernel, name=name, **kwargs)
+    interpreted = pl.pallas_call(kernel, name=name, interpret=True, **kwargs)
 
     def on_cpu(*args):
         with jax.named_scope("flash_attention_interpreted"):
             return interpreted(*args)
 
     def call(*args):
-        return jax.lax.platform_dependent(*args, cpu=on_cpu, default=mosaic)
+        with jax.named_scope(name):
+            return jax.lax.platform_dependent(
+                *args, cpu=on_cpu, default=mosaic)
 
     return call
 
@@ -186,7 +194,7 @@ def _fwd(q3, k3, v3, qo, ko, kl, *, scale, causal, blk_q, blk_k,
     )
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
     return _pallas_call(
-        kernel,
+        kernel, "flash_fwd",
         grid=(BH, Sq // blk_q, Sk // blk_k),
         in_specs=[
             scalar, scalar, scalar,
@@ -326,6 +334,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
     dq = _pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k),
+        "flash_bwd_dq",
         grid=(BH, Sq // blk_q, Sk // blk_k),
         in_specs=[scalar, scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
@@ -341,6 +350,7 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
     dk, dv = _pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           blk_q=blk_q, blk_k=blk_k),
+        "flash_bwd_dkv",
         grid=(BH, Sk // blk_k, Sq // blk_q),
         in_specs=[scalar, scalar, scalar,
                   q_spec_t, k_spec_t, k_spec_t, q_spec_t,

@@ -35,6 +35,7 @@ from typing import Callable, Dict, Iterator, List, Mapping, Optional
 import numpy as np
 
 from edl_tpu.models.base import Model
+from edl_tpu.obs.tracing import Tracer, get_tracer
 
 
 def shard_names(prefix: str, count: int) -> List[str]:
@@ -261,9 +262,13 @@ class LeaseReader:
         defer_completion: bool = False,
         prefetch: bool = False,
         soft_stop_check: Optional[Callable[[], bool]] = None,
+        tracer: Optional[Tracer] = None,
     ):
         self.client = client
         self.source = source
+        #: the synchronous path's ``lease`` and ``read_shard`` spans land
+        #: here, on whichever thread iterates (the elastic worker's pump)
+        self.tracer = tracer if tracer is not None else get_tracer()
         self.stop_check = stop_check or (lambda: False)
         #: polled at shard BOUNDARIES only: a soft stop finishes (and
         #: completes) the in-flight shard, then stops leasing — the
@@ -320,13 +325,20 @@ class LeaseReader:
             if self.soft_stop_check():
                 self.drained = True
                 return
-            reply = self.client.acquire()
-            task = reply.get("task")
+            with self.tracer.span("lease") as lease:
+                reply = self.client.acquire()
+                task = lease.attrs["task"] = reply.get("task")
             if task is None:
                 self.exhausted = bool(reply.get("exhausted"))
                 return
             self.current = task
-            for batch in self.source.read(task):
+            batches = iter(self.source.read(task))
+            while True:
+                with self.tracer.span("read_shard", task=task) as read:
+                    batch = next(batches, None)
+                    read.keep = batch is not None  # not the look past the end
+                if batch is None:
+                    break
                 if self.stop_check():
                     # Rescale signal mid-shard: give the lease back for a
                     # deterministic replay on the new mesh.

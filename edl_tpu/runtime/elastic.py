@@ -48,6 +48,7 @@ from edl_tpu.runtime.ft_policy import (
 )
 from edl_tpu.runtime.train_loop import Trainer, TrainerConfig, TrainState
 from edl_tpu.runtime.wire import WireRestartRequired
+from edl_tpu.tools.profiler import annotate_step
 
 #: coordinator KV key a worker publishes its live policy state under;
 #: `edl-tpu status` enumerates members and reads these back.
@@ -920,20 +921,22 @@ class ElasticWorker:
         per-pass step attribution follows the batch, not whatever shard the
         reader has moved on to by step time; ``place_bound`` snapshots the
         step callable for the same reason (codec widening in flight).
+        ``place_seconds`` is the length of the batch's ``place`` span.
         """
         depth = self.config.pipeline_depth
 
         def place(batch):
             self._note_batch_avals(batch)
-            placed, step_fn = trainer.place_bound(batch)
-            return placed, step_fn, reader.current
+            task = reader.current
+            with self.tracer.span("place", task=task) as span:
+                placed, step_fn = trainer.place_bound(batch)
+            return placed, step_fn, task, span
 
         if depth <= 0:
             for batch in reader:
                 samples = len(next(iter(batch.values())))
-                t0 = time.perf_counter()
-                payload = place(batch)
-                yield (*payload, samples, time.perf_counter() - t0)
+                *payload, span = place(batch)
+                yield (*payload, samples, span.seconds)
             return
         from edl_tpu.runtime.pipeline import DevicePrefetcher
 
@@ -941,7 +944,8 @@ class ElasticWorker:
             reader, place, depth=depth, thread_name="edl-elastic-place-pump"
         ) as pf:
             for item in pf:
-                yield (*item.payload, item.samples, item.place_seconds)
+                *payload, span = item.payload
+                yield (*payload, item.samples, span.seconds)
 
     def _checkpoint(self, state: TrainState, block: bool = False) -> None:
         self.ckpt.save(int(state.step), state)
@@ -1090,9 +1094,6 @@ class ElasticWorker:
             trainer = Trainer(self.model, mesh, self._trainer_config(),
                               codec_channel=codec_channel,
                               compile_cache=self.compile_cache)
-            # Live re-step pricing: every completed step feeds its wall
-            # seconds to the policy's EMA (train_loop cost hook).
-            trainer.step_cost_cb = self.policy.note_step
             if self.profiler is not None:
                 # The first step on a fresh mesh recompiles (20-40 s on TPU);
                 # keep it out of steady-state summaries.
@@ -1138,7 +1139,9 @@ class ElasticWorker:
             # lazy compile remainder, the first batch's lease + placement).
             mesh_ready = time.time()
             first_step_done = False
-            last_ckpt_step = int(state.step)
+            #: the optimizer step, counted on the host: ``_step`` adds one a
+            #: call, and reading ``state.step`` back would wait for the device
+            step = last_ckpt_step = int(state.step)
             rescale = False
             finished = False
 
@@ -1150,59 +1153,88 @@ class ElasticWorker:
                     defer_completion=True,
                     prefetch=self.config.prefetch,
                     soft_stop_check=lambda: self._soft_drain,
+                    tracer=self.tracer,
                 )
                 if self.profiler is not None:
                     self.profiler.start()
+                batches = self._dispatched(reader, trainer)
                 try:
-                    for placed, step_fn, task, samples, place_dt in \
-                            self._dispatched(reader, trainer):
-                        state, loss = step_fn(state, placed)
-                        if self.profiler is not None:
-                            self.profiler.step(samples, place_seconds=place_dt)
-                        if not first_step_done:
-                            first_step_done = True
-                            recovery = time.perf_counter() - rescale_t0
-                            self.tracer.record(
-                                "first_step", mesh_ready, time.time(),
-                                trace_id=rid, component="worker",
-                                step=int(state.step), world=world,
-                            )
-                            if self.steps_done:  # a rescale, not cold start
-                                self.obs.rescales.inc()
-                                self.rescales.append(
-                                    RescaleEvent(
-                                        at_step=int(state.step),
-                                        from_world=self._prev_world,
-                                        to_world=world,
-                                        recovery_seconds=recovery,
-                                        compile_seconds=compile_seconds,
-                                        compile_cache=trainer.last_compile_cache,
-                                        layout={str(k): int(v) for k, v
-                                                in mesh.shape.items()},
-                                    )
+                    while True:
+                        with annotate_step(step + 1), self.tracer.span(
+                                "worker_step", step=step + 1) as ws:
+                            with self.tracer.span("input_wait") as wait:
+                                item = next(batches, None)
+                                # the wait that meets the end of the input
+                                # is neither a step nor a step's wait
+                                ws.keep = wait.keep = item is not None
+                            if item is None:
+                                break
+                            step += 1
+                            placed, step_fn, task, samples, place_dt = item
+                            with self.tracer.span("step_dispatch",
+                                                  step=step) as dispatch:
+                                state, loss = step_fn(state, placed)
+                            with self.tracer.span("loss_sync",
+                                                  step=step) as sync:
+                                # Wait on the buffer's ready event, THEN
+                                # copy: `float()` on a loss still being
+                                # computed waits on the copy instead, and on
+                                # the chip that wait outlasted a finished
+                                # step by 0.8 to 4.7 s in a third of the
+                                # runs (PERF.md, PR 25).
+                                loss = float(jax.block_until_ready(loss))
+                            # Live re-step pricing: every completed step
+                            # feeds its wall seconds to the policy's EMA.
+                            self.policy.note_step(
+                                dispatch.seconds + sync.seconds)
+                            if self.profiler is not None:
+                                self.profiler.step(samples,
+                                                   place_seconds=place_dt)
+                            if not first_step_done:
+                                first_step_done = True
+                                recovery = time.perf_counter() - rescale_t0
+                                self.tracer.record(
+                                    "first_step", mesh_ready, time.time(),
+                                    trace_id=rid, component="worker",
+                                    step=step, world=world,
                                 )
-                        self.steps_done += 1
-                        self.obs.steps.inc()
-                        self.losses.append(float(loss))
-                        if task is not None:
-                            p = split_pass(task)[1]
-                            self.pass_steps[p] = self.pass_steps.get(p, 0) + 1
-                        step = int(state.step)
-                        if self.config.step_callback is not None:
-                            self.config.step_callback(step, state)
-                        if step - last_ckpt_step >= self.config.checkpoint_interval:
-                            self._checkpoint_and_commit(state, reader, block=False)
-                            last_ckpt_step = step
-                        elif self._pending_commit and not self.ckpt.saving():
-                            # The in-flight save landed: its shards are
-                            # durable now — complete them immediately rather
-                            # than holding leases until the next save
-                            # initiation. (`done_task`, NOT `task`: the
-                            # enclosing loop's `task` is live for per-pass
-                            # step attribution below.)
-                            for done_task in self._pending_commit:
-                                self.client.complete_task(done_task)
-                            self._pending_commit = []
+                                if self.steps_done:  # a rescale, not cold start
+                                    self.obs.rescales.inc()
+                                    self.rescales.append(
+                                        RescaleEvent(
+                                            at_step=step,
+                                            from_world=self._prev_world,
+                                            to_world=world,
+                                            recovery_seconds=recovery,
+                                            compile_seconds=compile_seconds,
+                                            compile_cache=trainer.last_compile_cache,
+                                            layout={str(k): int(v) for k, v
+                                                    in mesh.shape.items()},
+                                        )
+                                    )
+                            self.steps_done += 1
+                            self.obs.steps.inc()
+                            self.losses.append(loss)
+                            if task is not None:
+                                p = split_pass(task)[1]
+                                self.pass_steps[p] = self.pass_steps.get(p, 0) + 1
+                            if self.config.step_callback is not None:
+                                with self.tracer.span("step_callback",
+                                                      step=step):
+                                    self.config.step_callback(step, state)
+                            if step - last_ckpt_step >= self.config.checkpoint_interval:
+                                self._checkpoint_and_commit(state, reader, block=False)
+                                last_ckpt_step = step
+                            elif self._pending_commit and not self.ckpt.saving():
+                                # The in-flight save landed: its shards are
+                                # durable now — complete them immediately
+                                # rather than holding leases until the next
+                                # save initiation. (`done_task`, NOT `task`:
+                                # the enclosing loop's `task` is live for
+                                # per-pass step attribution above.)
+                                for done_task in self._pending_commit:
+                                    self.client.complete_task(done_task)
+                                self._pending_commit = []
                 except WireRestartRequired as e:
                     # Multi-process wire-codec overflow (only raised when
                     # jax.process_count() > 1): the widened floor is already
@@ -1214,6 +1246,7 @@ class ElasticWorker:
                     # restart_on_rescale.
                     from edl_tpu.launcher.launch import RESCALE_EXIT_CODE
 
+                    batches.close()  # stop the pump before counting its reads
                     self._carry_consumed.extend(reader.take_consumed())
                     self._checkpoint_and_commit(state, None, block=True)
                     log.warning("wire codec overflow (%s); exiting %d for "

@@ -688,9 +688,6 @@ class MultiHostWorker:
         trainer = Trainer(self.model, mesh, self._trainer_config(),
                           codec_channel=codec_channel,
                           compile_cache=self.compile_cache)
-        # Live re-step pricing for the policy's park break-even
-        # (train_loop cost hook).
-        trainer.step_cost_cb = self.policy.note_step
         if self.profiler is not None:
             self.profiler.mark_warmup()
         t_restore0 = time.monotonic()
@@ -759,11 +756,18 @@ class MultiHostWorker:
 
             def _train_one(placed, step_fn, samples, place_dt) -> None:
                 nonlocal state, ran_steps
+                t0 = time.perf_counter()
                 state, loss = step_fn(state, placed)
+                # the wait for the device: on the ready event, then the
+                # copy (see ElasticWorker's `loss_sync`)
+                loss = float(jax.block_until_ready(loss))
+                # Live re-step pricing for the policy's park break-even:
+                # every completed step feeds its wall seconds to the EMA.
+                self.policy.note_step(time.perf_counter() - t0)
                 ran_steps += 1
                 self.steps_done += 1
                 self.obs.steps.inc()
-                self.losses.append(float(loss))
+                self.losses.append(loss)
                 if self.profiler is not None:
                     self.profiler.step(samples, place_seconds=place_dt)
                 if self.config.step_callback is not None:

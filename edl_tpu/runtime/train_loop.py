@@ -185,11 +185,6 @@ class Trainer:
         #: must jit the identical decode program, so the codec is negotiated
         #: through the coordinator KV instead of inferred per-process.
         self.codec_channel = codec_channel
-        #: optional per-step cost feed, called with the measured wall
-        #: seconds of each completed step (device sync included). The
-        #: fault-tolerance policy (`runtime.ft_policy`) prices its re-step
-        #: cost from this; None keeps the hot loop unwrapped.
-        self.step_cost_cb: Optional[Callable[[float], None]] = None
 
         if cfg.grad_sync not in ("auto", "psum", "reduce_scatter"):
             raise ValueError(
@@ -273,10 +268,18 @@ class Trainer:
             return grads, jnp.mean(losses)
 
         def _step(state: TrainState, batch: Dict[str, jax.Array]) -> Tuple[TrainState, jax.Array]:
-            if cfg.grad_accum_microbatches > 1:
-                grads, loss = _accumulate(state.params, batch)
-            else:
-                grads, loss = _grads_and_loss(state.params, batch)
+            # The two scopes name the step's phases in every operation's
+            # `op_name`, so a device trace splits the step (metadata only).
+            with jax.named_scope("fwd_bwd"):
+                if cfg.grad_accum_microbatches > 1:
+                    grads, loss = _accumulate(state.params, batch)
+                else:
+                    grads, loss = _grads_and_loss(state.params, batch)
+            with jax.named_scope("optimizer"):
+                params, opt_state = _update(state, grads)
+            return TrainState(state.step + 1, params, opt_state), loss
+
+        def _update(state: TrainState, grads):
             updates, opt_state = self.opt.update(grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
             if self.config.shard_opt_state and model.param_spec is not None:
@@ -298,7 +301,7 @@ class Trainer:
                     model.param_spec(mesh),
                     is_leaf=lambda x: isinstance(x, P),
                 )
-            return TrainState(state.step + 1, params, opt_state), loss
+            return params, opt_state
 
         # Input shardings flow from the state/batch placements; XLA SPMD
         # inserts the data-axis psum for grads. Donation reuses HBM buffers.
@@ -602,19 +605,7 @@ class Trainer:
         batch paired with the codec generation that encoded it.
         """
         placed = self.place_batch(batch)
-        fn = self._step_callable(placed)
-        cb = self.step_cost_cb
-        if cb is None:
-            return placed, fn
-
-        def timed(state: TrainState, b: Dict[str, Any]):
-            t0 = time.perf_counter()
-            out_state, loss = fn(state, b)
-            jax.block_until_ready(loss)
-            cb(time.perf_counter() - t0)
-            return out_state, loss
-
-        return placed, timed
+        return placed, self._step_callable(placed)
 
     def train_step(self, state: TrainState, batch: Dict[str, Any]) -> Tuple[TrainState, jax.Array]:
         return self._step_callable(batch)(state, batch)

@@ -488,14 +488,28 @@ class LMServingReplica:
         return True
 
     def _prefill_chunk(self, chunk: List[_Stream], seq_bucket: int) -> None:
-        import jax
-
         n = len(chunk)
         bucket = pick_bucket(n, self.config.batch_buckets)
         tokens, lengths = pad_token_rows(
             [s.prompt for s in chunk], bucket, seq_bucket
         )
-        t0 = time.time()
+        # one span per chunk: the device call and the commit of its K/V
+        # into each stream's own cache
+        with self.tracer.span("lm_prefill", component="serving",
+                              batch_size=n, bucket=bucket,
+                              seq_bucket=seq_bucket):
+            finished = self._prefill_commit(chunk, bucket, seq_bucket,
+                                            tokens, lengths)
+        for s, outcome in finished:
+            self._retire(s, outcome)
+
+    def _prefill_commit(self, chunk: List[_Stream], bucket: int,
+                        seq_bucket: int, tokens, lengths
+                        ) -> List[Tuple[_Stream, str]]:
+        """Run the chunk's prefill and commit it; the streams it finished."""
+        import jax
+
+        n = len(chunk)
         try:
             with self._lock:
                 params = self._art.params
@@ -511,7 +525,7 @@ class LMServingReplica:
                 self.pool.release(s.id)
                 self.instruments.streams.inc(outcome="error")
                 s.future.set_exception(e)
-            return
+            return []
         self.instruments.prefill_batch.observe(float(n))
         L, H, Dh = k_cache.shape[0], k_cache.shape[3], k_cache.shape[4]
         finished: List[Tuple[_Stream, str]] = []
@@ -539,11 +553,7 @@ class LMServingReplica:
             self.instruments.active_streams.set(float(len(self._active)))
         for s in owned:
             self.pool.note_tokens(s.id, s.length)
-            self.tracer.record("lm_prefill", t0, time.time(),
-                               component="serving", stream=s.id,
-                               bucket=bucket, seq_bucket=seq_bucket)
-        for s, outcome in finished:
-            self._retire(s, outcome)
+        return finished
 
     # -- decode phase ----------------------------------------------------------
 
@@ -560,21 +570,38 @@ class LMServingReplica:
         return True
 
     def _decode_chunk(self, chunk: List[_Stream], capacity: int) -> None:
+        n = len(chunk)
+        bucket = pick_bucket(n, self.config.batch_buckets)
+        attrs = dict(component="serving", batch_size=n, bucket=bucket,
+                     seq_bucket=capacity)
+        # host staging: a zeroed cache of the whole bucket, and a copy of
+        # every member stream's K/V into it
+        with self.tracer.span("lm_stage", **attrs):
+            L, C, H, Dh = chunk[0].k.shape[0], capacity, *chunk[0].k.shape[2:]
+            k_batch = np.zeros((L, bucket, C, H, Dh), dtype=chunk[0].k.dtype)
+            v_batch = np.zeros_like(k_batch)
+            tokens = np.zeros((bucket,), dtype=np.int32)
+            lengths = np.zeros((bucket,), dtype=np.int32)
+            for i, s in enumerate(chunk):
+                k_batch[:, i] = s.k
+                v_batch[:, i] = s.v
+                tokens[i] = s.generated[-1]
+                lengths[i] = s.length
+        # transfer in, device step, device_get, and the commit
+        with self.tracer.span("lm_decode_step", **attrs):
+            finished = self._decode_commit(chunk, bucket, capacity, k_batch,
+                                           v_batch, tokens, lengths)
+        for s, outcome in finished:
+            self._retire(s, outcome)
+
+    def _decode_commit(self, chunk: List[_Stream], bucket: int, capacity: int,
+                       k_batch, v_batch, tokens, lengths
+                       ) -> List[Tuple[_Stream, str]]:
+        """Run one decode step on the staged batch and commit it; the
+        streams it finished."""
         import jax
 
         n = len(chunk)
-        bucket = pick_bucket(n, self.config.batch_buckets)
-        L, C, H, Dh = chunk[0].k.shape[0], capacity, *chunk[0].k.shape[2:]
-        k_batch = np.zeros((L, bucket, C, H, Dh), dtype=chunk[0].k.dtype)
-        v_batch = np.zeros_like(k_batch)
-        tokens = np.zeros((bucket,), dtype=np.int32)
-        lengths = np.zeros((bucket,), dtype=np.int32)
-        for i, s in enumerate(chunk):
-            k_batch[:, i] = s.k
-            v_batch[:, i] = s.v
-            tokens[i] = s.generated[-1]
-            lengths[i] = s.length
-        t0 = time.time()
         try:
             with self._lock:
                 params = self._art.params
@@ -594,7 +621,7 @@ class LMServingReplica:
                 self.pool.release(s.id)
                 self.instruments.streams.inc(outcome="error")
                 s.future.set_exception(e)
-            return
+            return []
         self.instruments.decode_batch.observe(float(n))
         self.instruments.decode_steps.inc(bucket=str(bucket),
                                           seq_bucket=str(capacity))
@@ -613,11 +640,7 @@ class LMServingReplica:
             self.instruments.active_streams.set(float(len(self._active)))
         for s in chunk:
             self.pool.note_tokens(s.id, s.length)
-        self.tracer.record("lm_decode_step", t0, time.time(),
-                           component="serving", batch_size=n,
-                           bucket=bucket, seq_bucket=capacity)
-        for s, outcome in finished:
-            self._retire(s, outcome)
+        return finished
 
     # -- stream lifecycle ------------------------------------------------------
 

@@ -5,7 +5,6 @@ from edl_tpu.tools.profiler import (
     StepProfiler,
     StepRecord,
     annotate_step,
-    annotation,
     device_memory_stats,
     trace,
 )
@@ -16,7 +15,6 @@ __all__ = [
     "StepProfiler",
     "StepRecord",
     "annotate_step",
-    "annotation",
     "device_memory_stats",
     "trace",
 ]

@@ -12,8 +12,10 @@ Three pieces:
   loop via :meth:`StepProfiler.step` or wrap an iterator.
 - :func:`trace` — context manager around ``jax.profiler`` that captures an
   XLA/TPU trace (TensorBoard-loadable) for the enclosed steps.
-- :func:`annotate_step` / :func:`annotation` — named trace spans so the hot
-  loop's phases (place_batch / train_step / checkpoint) are visible in traces.
+- :func:`annotate_step` — the step marker the elastic worker puts around one
+  loop iteration, so a captured trace groups its events by the worker's step
+  number. The hot loop's phases reach the trace as spans of
+  ``edl_tpu.obs.tracing.Tracer``, which mirrors each into the profiler.
 
 Device memory introspection (:func:`device_memory_stats`) reports per-device
 HBM in-use/limit where the backend exposes it (TPU does; CPU returns {}).
@@ -36,7 +38,6 @@ __all__ = [
     "StepProfiler",
     "StepRecord",
     "trace",
-    "annotation",
     "annotate_step",
     "device_memory_stats",
 ]
@@ -300,13 +301,9 @@ def trace(logdir: str):
                 pass
 
 
-def annotation(name: str):
-    """Named span visible in captured traces (host + device timeline)."""
-    return jax.profiler.TraceAnnotation(name)
-
-
 def annotate_step(step: int):
-    """Step marker that lets TensorBoard group device ops per training step."""
+    """Step marker that lets a trace viewer group events per training step.
+    A flag check while no profiler session runs."""
     return jax.profiler.StepTraceAnnotation("train", step_num=step)
 
 

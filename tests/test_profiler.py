@@ -11,7 +11,8 @@ import numpy as np
 from edl_tpu.models import fit_a_line
 from edl_tpu.parallel import local_mesh
 from edl_tpu.runtime import Trainer, TrainerConfig
-from edl_tpu.tools import StepProfiler, annotate_step, annotation, device_memory_stats, trace
+from edl_tpu.obs.tracing import Tracer
+from edl_tpu.tools import StepProfiler, annotate_step, device_memory_stats, trace
 
 
 def test_step_profiler_records_and_summarizes():
@@ -177,10 +178,14 @@ def test_trainer_run_fills_data_plane():
 
 
 def test_annotations_are_usable_contexts():
-    with annotation("edl/test-span"):
-        pass
+    """The one path into the profiler's trace: the worker's step marker
+    around `Tracer` spans, which mirror themselves as annotations (what a
+    captured trace then holds is `test_tracing_clock.py`'s to check)."""
+    tracer = Tracer()
     with annotate_step(3):
-        pass
+        with tracer.span("edl/test-span", step=3) as span:
+            pass
+    assert tracer.spans == [span] and span.attrs == {"step": 3}
 
 
 def test_trace_captures_to_logdir(tmp_path):
