@@ -1,25 +1,43 @@
-"""Flash attention: a Pallas TPU kernel for blockwise-online attention.
+"""Flash attention: three Pallas TPU kernels for blockwise-online attention.
 
 The transformer's hot op. The plain path (`parallel.ring_attention.
 dense_attention`) materializes the (S, S) score matrix per head — O(S^2)
-HBM traffic and memory; this kernel streams K/V blocks through VMEM with
-the online-softmax recurrence (running max / numerator / denominator), so
+HBM traffic and memory; these kernels stream K/V through VMEM with the
+online-softmax recurrence (running max / numerator / denominator), so
 scores never leave on-chip memory and the sequence-length memory cost is
-O(S) per head. The matmuls hit the MXU with f32 accumulation
-(``preferred_element_type``); the elementwise recurrence rides the VPU.
+O(S) per head.
 
-Causality uses GLOBAL positions (``q_offset`` / ``k_offset``), so the ring
-layer can hand the kernel any (query block, key block) pair with the same
-masking semantics as `_ring_attention_local`'s compare — the kernel is the
-within-block engine; `ppermute` stays the between-device engine.
+Tiling (see "tiling" below): a grid step holds one block of the operand
+that owns the accumulator and a SPAN of up to `_MAX_SPAN` rows of the one
+that streams past it; a loop inside the step walks the span in TILES of
+(block_q x block_k) scores and stops at the causal diagonal, so a call is
+some hundreds to a few thousand grid steps whatever the sequence, and a
+tile no query can see costs neither a step nor a DMA. The tile is `_TILE`
+square, clamped to the sequence.
+
+Arithmetic: every matmul feeds the MXU operands of the INPUT's dtype (P,
+P^T and dS are rounded to it at their matmul, as the dense oracle rounds P)
+and accumulates in f32 (``preferred_element_type``); scores, exp, the
+running statistics, the accumulators, lse and delta are f32 on the VPU.
+`flash_fwd` and `flash_bwd_dkv` work on TRANSPOSED tiles (keys on sublanes,
+queries on lanes), so per-query statistics are dense lane rows and the
+reductions over keys run down the sublanes; `flash_bwd_dq` works on (query,
+key) tiles and turns its two statistics rows into columns once a step.
+
+Causality uses GLOBAL positions (``q_offset`` / ``k_offset``, traced
+scalars in SMEM), so the ring layer can hand the kernel any (query block,
+key block) pair with the same masking semantics as `_ring_attention_local`'s
+compare — the kernel is the within-block engine; `ppermute` stays the
+between-device engine. Every causal bound in a kernel is computed from them
+at run time.
 
 Backward is the standard two-kernel flash recipe: forward also emits the
 per-row logsumexp ``L = m + log(den)``; backward recomputes ``P = exp(S -
-L)`` blockwise (never storing it) with ``delta = rowsum(dO * O)`` folded
+L)`` tile by tile (never storing it) with ``delta = rowsum(dO * O)`` folded
 in: dS = P * (dP - delta) * scale, dQ = dS K, dK = dS^T Q, dV = P^T dO.
 
-Shapes follow the models' convention: q/k/v are (B, S, H, D). Blocks are
-multiples of 128 rows; unaligned sequence lengths pad up to the block size:
+Shapes follow the models' convention: q/k/v are (B, S, H, D). Tiles are
+multiples of 128 rows; unaligned sequence lengths pad up to the tile:
 padded KEY rows are masked by a valid-length compare; padded QUERY rows
 produce unobserved garbage and are sliced away.
 
@@ -30,12 +48,13 @@ mesh) the same kernel bodies run in the Pallas interpreter, under a named
 scope that shows in the program's text. The interpreter checks the
 kernels' arithmetic; only the TPU's compiler checks that they lower
 (`tests/test_tpu_compile.py`) and only the chip that they are right there
-(`chip_smoke.py`).
+(`chip_smoke.py`) and how long they take (`onchip_flash_sweep.py`).
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import math
 from typing import Optional
 
@@ -46,9 +65,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention"]
 
+_log = logging.getLogger(__name__)
+
 #: finite "masked" score: exp() is exactly 0.0 without nan risk
 _NEG_INF = -1e30
-
 
 
 def _pallas_call(kernel, name, **kwargs):
@@ -88,34 +108,98 @@ def _pad_seq(x: jax.Array, mult: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
 
 
-def _positions(start, shape, dim):
-    return start + jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+# -- tiling --------------------------------------------------------------------
+#
+# Two extents, chosen apart. A TILE (blk_q x blk_k) is what one pass of the
+# arithmetic holds: a scores matrix that size, in f32. A SPAN is what one
+# grid step holds of the operand that streams past the tile's owner (keys and
+# values past a query block in `flash_fwd` and `flash_bwd_dq`, queries and
+# dO past a key block in `flash_bwd_dkv`): the step's DMA moves a whole span
+# and a loop inside the step walks its tiles, stopping where causality or
+# the keys' valid length says no later tile can count. A grid step costs
+# about 0.35 us before it does anything, more than the arithmetic of a
+# 128 x 128 tile; a loop trip costs a few scalar instructions, and a tile
+# the loop never reaches costs neither a step nor a DMA.
+
+#: Most rows of the streamed operand one grid step takes. A span that holds
+#: the whole sequence is fetched once a head and not once a block of the
+#: owner, and leaves no grid step in the causal future: at S = 4096 spans of
+#: 1024, 2048 and 4096 rows took the forward 8.31, 7.81 and 6.33 ms (chip
+#: sweep, PR 26). 4096 rows of K and V, double-buffered, are 2 MiB of VMEM
+#: at D = 64 in bf16 and 16 MiB at D = 128 in f32; longer was not measured.
+_MAX_SPAN = 4096
 
 
-def _masked_scores(q, k, *, q_start, k_start, k_origin, k_len, scale,
-                   causal, blk_q, blk_k, transposed=False):
-    """Shared by all three kernels: f32 scores with invalid entries at the
-    ``_NEG_INF`` sentinel, plus the validity mask itself. ``transposed``
-    gives both as (blk_k, blk_q) — keys on sublanes, queries on lanes —
-    which is the orientation the backward kernels work in.
+def _span(rows: int, blk: int) -> int:
+    """Rows of the streamed operand a grid step takes: the most whole tiles
+    that divide ``rows`` (already a multiple of ``blk``) evenly and stay
+    within `_MAX_SPAN`, one tile at least."""
+    tiles = rows // blk
+    most = max(1, _MAX_SPAN // blk)
+    return blk * max(d for d in range(1, most + 1) if tiles % d == 0)
 
-    Callers must mask their exp() THROUGH ``valid`` (``where(valid,
-    exp(...), 0)``), never infer it back from the scores: a fully-masked
-    row's running max / lse lands exactly on the sentinel, so
-    ``exp(s - m)`` would be 1 there, not 0."""
-    lhs, rhs = (k, q) if transposed else (q, k)
-    shape = (blk_k, blk_q) if transposed else (blk_q, blk_k)
-    q_dim, k_dim = (1, 0) if transposed else (0, 1)
-    s = jax.lax.dot_general(
-        lhs, rhs, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    k_pos = _positions(k_start, shape, k_dim)
-    valid = k_pos - k_origin < k_len  # mask padded key rows
+
+def _params(blk_q, blk_k, span_q, span_k, head_dim):
+    """Compiler parameters of one kernel, from its extents. The scoped-VMEM
+    request counts every block a step may hold (two operands on each side,
+    double-buffered, at four bytes an element) and eight f32 temporaries of
+    a tile, doubled for what the compiler adds; 32 MiB at least (v5e's
+    default scope is 16 of its 128) and 96 at most."""
+    blocks = 2 * 2 * 4 * head_dim * (blk_q + blk_k + span_q + span_k)
+    tiles = 8 * 4 * blk_q * blk_k
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=min(max(2 * (blocks + tiles), 32 * 2**20),
+                             96 * 2**20))
+
+
+def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
+    """Which (query, key) pairs of one tile count, or None where all do.
+    ``k_dim`` is the axis of ``shape`` the keys lie on. ``diag`` is the
+    global position of the tile's first query less that of its first key
+    (a pair is causal-visible when its key index less its query index is at
+    most that); ``k_left`` is how many of the tile's key rows hold real keys
+    and not padding (looked at only where ``ragged`` says the key length is
+    no multiple of the tile). Every tile of a causal call is masked, those
+    below the diagonal too: a loop of their own without the mask was
+    measured and bought nothing (PERF.md, PR 26), the VPU is not the
+    limit."""
+    if not (causal or ragged):
+        return None
+    k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, k_dim)
+    valid = k_idx < k_left if ragged else None
     if causal:
-        q_pos = _positions(q_start, shape, q_dim)
-        valid = jnp.logical_and(valid, k_pos <= q_pos)
-    return jnp.where(valid, s, _NEG_INF), valid
+        q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - k_dim)
+        seen = k_idx - q_idx <= diag
+        valid = seen if valid is None else jnp.logical_and(valid, seen)
+    return valid
+
+
+def _nt(a, b):
+    """a @ b.T on the MXU with f32 accumulation: (m, D), (n, D) -> (m, n)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _column(row):
+    """(1, n) -> (n, 1): a statistics row as it crosses HBM, turned once a
+    grid step into the column a (query, key)-oriented tile broadcasts. The
+    row fills a (128, n) tile's sublanes, the XLU transposes it, and every
+    lane of the result is the column."""
+    return jnp.broadcast_to(row, (128, row.shape[1])).T[:, :1]
+
+
+def _live_key_tiles(*, q_first, k_first, k_left, causal, blk_q, blk_k,
+                    span_k):
+    """How many of a span's key tiles, from its first, hold a key that some
+    query of the block sees. ``q_first``/``k_first`` are the global positions
+    of the block's first query and the span's first key, ``k_left`` the
+    span's count of real keys; all traced, so a ring hop in the causal
+    future comes out as 0."""
+    stop = k_left
+    if causal:  # keys at or before the block's last query
+        stop = jnp.minimum(stop, q_first + blk_q - k_first)
+    return (jnp.clip(stop, 0, span_k) + (blk_k - 1)) // blk_k
 
 
 # -- forward -------------------------------------------------------------------
@@ -125,240 +209,247 @@ def _masked_scores(q, k, *, q_start, k_start, k_origin, k_len, scale,
 # a block's last two dims divisible by (8, 128) or equal to the array's;
 # (1, blk_q) over (1, Sq) is, (1, blk_q) over (BH, Sq) is not, and a
 # (blk_q, 1) column would pad every value to a 128-lane row in HBM.
+#
+# The forward works on TRANSPOSED tiles, (blk_k, blk_q), and accumulates
+# O^T: the statistics of a query are then one lane of a dense (1, blk_q)
+# row, the reductions over keys run down the sublanes on the VPU, and lse
+# leaves as the row it is stored as. On (blk_q, blk_k) tiles each statistic
+# was a (blk_q, 128) array rewritten for every tile and each reduction a
+# lane reduction on the XLU per 8 rows per tile, which cost more than the
+# tile's matmuls (PERF.md, PR 26). O^T turns once a query block, at the end.
 
 
-def _fwd_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref,
+def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
                 o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale, causal, blk_q, blk_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    n_k = pl.num_programs(2)
+                *, scale, causal, k_len, blk_q, blk_k):
+    qi, si = pl.program_id(1), pl.program_id(2)
+    n_s = pl.num_programs(2)
+    span_k = k_ref.shape[1]
 
-    @pl.when(ki == 0)
+    @pl.when(si == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = qo_ref[0] + qi * blk_q  # global position of this block's row 0
-    k_start = ko_ref[0] + ki * blk_k
+    q_first = qo_ref[0] + qi * blk_q  # global position of the block's row 0
+    k_first = ko_ref[0] + si * span_k
+    k_left = k_len - si * span_k
+    q = q_ref[0]  # (blk_q, D)
 
-    # Skip K blocks entirely in this Q block's causal future.
-    live = (not causal) or (k_start <= q_start + (blk_q - 1))
-
-    @pl.when(live)
-    def _block():
-        q = q_ref[0]  # (blk_q, D)
-        k = k_ref[0]  # (blk_k, D)
-        v = v_ref[0]
-        s, valid = _masked_scores(
-            q, k, q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
-            k_len=kl_ref[0], scale=scale, causal=causal,
-            blk_q=blk_q, blk_k=blk_k,
-        )
-        m_prev = m_ref[:, :1]  # (blk_q, 1)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    def tile(j, _):
+        at = pl.multiple_of(j * blk_k, blk_k)
+        k = k_ref[0, pl.ds(at, blk_k), :]  # (blk_k, D)
+        v = v_ref[0, pl.ds(at, blk_k), :]
+        s_t = _nt(k, q) * scale  # (blk_k, blk_q) f32
+        valid = _visible(s_t.shape, 0, diag=q_first - (k_first + at),
+                         k_left=k_left - at, causal=causal,
+                         ragged=k_len % blk_k != 0)
+        if valid is not None:
+            s_t = jnp.where(valid, s_t, _NEG_INF)
+        m_prev = m_ref[...]  # (1, blk_q)
+        m_new = jnp.maximum(m_prev, s_t.max(axis=0, keepdims=True))
+        # A query that has seen no key yet keeps its max ON the sentinel,
+        # where exp(s - m) of a masked score would be exp(0): shift such a
+        # query by 0, and every masked score gives exactly 0 without a
+        # second select.
+        p_t = jnp.exp(s_t - jnp.where(m_new > _NEG_INF, m_new, 0.0))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)  # (blk_q, blk_k) f32
-        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        l_ref[...] = l_ref[...] * alpha + p_t.sum(axis=0, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # V^T P^T: (D, blk_q)
+        m_ref[...] = m_new
 
-    @pl.when(ki == n_k - 1)
+    jax.lax.fori_loop(0, _live_key_tiles(
+        q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
+        blk_q=blk_q, blk_k=blk_k, span_k=span_k), tile, None)
+
+    @pl.when(si == n_s - 1)
     def _emit():
-        l = l_ref[...]  # (blk_q, 128), every lane the same value
-        # fully-masked (padded) query rows: den 0 -> emit 0, lse -inf
+        l = l_ref[...]
+        # queries that saw no key (padding, a hop in the future): den 0 ->
+        # emit 0, lse at the sentinel
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe[:, :1]).astype(o_ref.dtype)
-        lse = jnp.where(l > 0, m_ref[...] + jnp.log(safe), _NEG_INF)
-        # column -> row: the lane-broadcast tile transposes on the XLU to
-        # (128, blk_q), whose every sublane is the row we want.
-        lse_ref[0] = lse.T[:1]
+        o_ref[0] = (acc_ref[...] / safe).T.astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe), _NEG_INF)
 
 
-def _row_spec(blk_q, index_map):
-    return pl.BlockSpec((1, 1, blk_q), index_map)
-
-
-def _fwd(q3, k3, v3, qo, ko, kl, *, scale, causal, blk_q, blk_k,
+def _fwd(q3, k3, v3, qo, ko, *, scale, causal, k_len, blk_q, blk_k,
          out_dtype):
     """q3: (BH, Sq, D); k3/v3: (BH, Sk, D) -> (o3, lse (BH, 1, Sq) f32)."""
     BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k
-    )
+    span_k = _span(k3.shape[1], blk_k)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
+    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, s: (b, i, 0))
+    k_spec = pl.BlockSpec((1, span_k, D), lambda b, i, s: (b, s, 0))
     return _pallas_call(
-        kernel, "flash_fwd",
-        grid=(BH, Sq // blk_q, Sk // blk_k),
-        in_specs=[
-            scalar, scalar, scalar,
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-        ],
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          k_len=k_len, blk_q=blk_q, blk_k=blk_k),
+        "flash_fwd",
+        grid=(BH, Sq // blk_q, k3.shape[1] // span_k),
+        in_specs=[scalar, scalar, q_spec, k_spec, k_spec],
         out_specs=[
-            pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-            _row_spec(blk_q, lambda b, i, j: (b, 0, i)),
+            q_spec,
+            pl.BlockSpec((1, 1, blk_q), lambda b, i, s: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sq, D), out_dtype),
             jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_q, 128), jnp.float32),  # running max m
-            pltpu.VMEM((blk_q, 128), jnp.float32),  # running denominator l
-            pltpu.VMEM((blk_q, D), jnp.float32),  # output accumulator
+            pltpu.VMEM((1, blk_q), jnp.float32),  # running max m
+            pltpu.VMEM((1, blk_q), jnp.float32),  # running denominator l
+            pltpu.VMEM((D, blk_q), jnp.float32),  # output accumulator, O^T
         ],
-    )(qo, ko, kl, q3, k3, v3)
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, D),
+    )(qo, ko, q3, k3, v3)
 
 
 # -- backward ------------------------------------------------------------------
 #
-# Both kernels work on TRANSPOSED tiles, (blk_k, blk_q): the per-query
-# lse/delta rows then broadcast down the sublanes as they arrive, and
-# dV = P^T dO and dK = dS^T Q become plain matmuls.
+# `flash_bwd_dq` owns a query block and walks key tiles like the forward,
+# on (blk_q, blk_k) tiles; its lse/delta rows become columns once a step.
+# `flash_bwd_dkv` owns a key block and walks QUERY tiles on TRANSPOSED
+# (blk_k, blk_q) tiles: the rows of lse/delta then broadcast down the
+# sublanes as they arrive, and dV = P^T dO and dK = dS^T Q are plain
+# matmuls. Neither masks the scores: exp(s - lse) of an unseen pair may be
+# anything, inf included, and the one select on P discards it. The factor
+# ``scale`` of dS = P (dP - delta) scale is applied to the f32 sums at the
+# end, not to every tile.
 
 
-def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *, q_start,
-              k_start, k_origin, k_len, scale, causal, blk_q, blk_k):
-    """(P^T, dS^T, dO) for one (key block, query block) pair, f32."""
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    do = do_ref[0].astype(jnp.float32)  # (blk_q, D)
-    s_t, valid = _masked_scores(
-        q, k, q_start=q_start, k_start=k_start, k_origin=k_origin,
-        k_len=k_len, scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k,
-        transposed=True,
-    )
-    p_t = jnp.where(valid, jnp.exp(s_t - lse_ref[0]), 0.0)  # (blk_k, blk_q)
-    dp_t = jax.lax.dot_general(
-        v.astype(jnp.float32), do, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds_t = p_t * (dp_t - delta_ref[0]) * scale
-    return p_t, ds_t, do
-
-
-def _bwd_dq_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref, do_ref,
+def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, acc_ref,
-                   *, scale, causal, blk_q, blk_k):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    n_k = pl.num_programs(2)
+                   *, scale, causal, k_len, blk_q, blk_k):
+    qi, si = pl.program_id(1), pl.program_id(2)
+    n_s = pl.num_programs(2)
+    span_k = k_ref.shape[1]
 
-    @pl.when(ki == 0)
+    @pl.when(si == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_start = qo_ref[0] + qi * blk_q
-    k_start = ko_ref[0] + ki * blk_k
-    live = (not causal) or (k_start <= q_start + (blk_q - 1))
+    q_first = qo_ref[0] + qi * blk_q
+    k_first = ko_ref[0] + si * span_k
+    k_left = k_len - si * span_k
+    q = q_ref[0]  # (blk_q, D)
+    do = do_ref[0]
+    lse = _column(lse_ref[0])  # (blk_q, 1)
+    delta = _column(delta_ref[0])
 
-    @pl.when(live)
-    def _block():
-        _, ds_t, _ = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
-            k_len=kl_ref[0], scale=scale, causal=causal,
-            blk_q=blk_q, blk_k=blk_k,
-        )
-        acc_ref[...] += jax.lax.dot_general(
-            ds_t, k_ref[0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # dS K: (blk_q, D)
+    def tile(j, _):
+        at = pl.multiple_of(j * blk_k, blk_k)
+        k = k_ref[0, pl.ds(at, blk_k), :]
+        v = v_ref[0, pl.ds(at, blk_k), :]
+        p = jnp.exp(_nt(q, k) * scale - lse)  # (blk_q, blk_k) f32
+        valid = _visible(p.shape, 1, diag=q_first - (k_first + at),
+                         k_left=k_left - at, causal=causal,
+                         ragged=k_len % blk_k != 0)
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)
+        ds = p * (_nt(do, v) - delta)
+        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
+                                preferred_element_type=jnp.float32)  # dS K
 
-    @pl.when(ki == n_k - 1)
+    jax.lax.fori_loop(0, _live_key_tiles(
+        q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
+        blk_q=blk_q, blk_k=blk_k, span_k=span_k), tile, None)
+
+    @pl.when(si == n_s - 1)
     def _emit():
-        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(qo_ref, ko_ref, kl_ref, q_ref, k_ref, v_ref, do_ref,
+def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, blk_q, blk_k):
-    ki, qi = pl.program_id(1), pl.program_id(2)  # note: K outer, Q inner
-    n_q = pl.num_programs(2)
+                    *, scale, causal, k_len, blk_q, blk_k):
+    ki, si = pl.program_id(1), pl.program_id(2)  # note: K outer, Q streams
+    n_s = pl.num_programs(2)
+    span_q = q_ref.shape[1]
 
-    @pl.when(qi == 0)
+    @pl.when(si == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = qo_ref[0] + qi * blk_q
-    k_start = ko_ref[0] + ki * blk_k
-    live = (not causal) or (k_start <= q_start + (blk_q - 1))
+    q_first = qo_ref[0] + si * span_q
+    k_first = ko_ref[0] + ki * blk_k
+    k = k_ref[0]  # (blk_k, D)
+    v = v_ref[0]
 
-    @pl.when(live)
-    def _block():
-        p_t, ds_t, do = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-            q_start=q_start, k_start=k_start, k_origin=ko_ref[0],
-            k_len=kl_ref[0], scale=scale, causal=causal,
-            blk_q=blk_q, blk_k=blk_k,
-        )
-        dv_acc[...] += jnp.dot(
-            p_t, do, preferred_element_type=jnp.float32
-        )  # P^T dO: (blk_k, D)
-        dk_acc[...] += jnp.dot(
-            ds_t, q_ref[0].astype(jnp.float32),
-            preferred_element_type=jnp.float32,
-        )  # dS^T Q: (blk_k, D)
+    def tile(j, _):
+        at = pl.multiple_of(j * blk_q, blk_q)
+        q = q_ref[0, pl.ds(at, blk_q), :]  # (blk_q, D)
+        do = do_ref[0, pl.ds(at, blk_q), :]
+        lse = lse_ref[0, :, pl.ds(at, blk_q)]  # (1, blk_q)
+        delta = delta_ref[0, :, pl.ds(at, blk_q)]
+        p_t = jnp.exp(_nt(k, q) * scale - lse)  # (blk_k, blk_q) f32
+        valid = _visible(p_t.shape, 0, diag=q_first + at - k_first,
+                         k_left=k_len - ki * blk_k, causal=causal,
+                         ragged=k_len % blk_k != 0)
+        if valid is not None:
+            p_t = jnp.where(valid, p_t, 0.0)
+        ds_t = p_t * (_nt(v, do) - delta)
+        dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
+                               preferred_element_type=jnp.float32)  # P^T dO
+        dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
+                               preferred_element_type=jnp.float32)  # dS^T Q
 
-    @pl.when(qi == n_q - 1)
+    # The first query tile whose LAST row is at or after the block's first
+    # key; every tile before it lies wholly in the keys' past.
+    first = 0
+    if causal:
+        first = jnp.clip(k_first - q_first, 0, span_q) // blk_q
+    jax.lax.fori_loop(first, span_q // blk_q, tile, None)
+
+    @pl.when(si == n_s - 1)
     def _emit():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
+def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, *, scale, causal, k_len,
          blk_q, blk_k):
     BH, Sq, D = q3.shape
     Sk = k3.shape[1]
+    span_q, span_k = _span(Sq, blk_q), _span(Sk, blk_k)
     # dL/ds_ij = p_ij (dp_ij - delta_i) for the out path PLUS p_ij * dlse_i
     # for the lse path (dlse/ds = softmax row) — the lse cotangent folds
     # into delta with a sign flip. dlse is zeros when lse wasn't consumed.
     delta = jnp.sum(
         do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
     )[:, None, :] - dlse.astype(jnp.float32)  # (BH, 1, Sq)
-
+    kernel_kw = dict(scale=scale, causal=causal, k_len=k_len,
+                     blk_q=blk_q, blk_k=blk_k)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0))
-    row_spec = _row_spec(blk_q, lambda b, i, j: (b, 0, i))
-    k_spec = pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0))
 
+    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, s: (b, i, 0))
+    row_spec = pl.BlockSpec((1, 1, blk_q), lambda b, i, s: (b, 0, i))
+    k_spec = pl.BlockSpec((1, span_k, D), lambda b, i, s: (b, s, 0))
     dq = _pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k),
+        functools.partial(_bwd_dq_kernel, **kernel_kw),
         "flash_bwd_dq",
-        grid=(BH, Sq // blk_q, Sk // blk_k),
-        in_specs=[scalar, scalar, scalar,
+        grid=(BH, Sq // blk_q, Sk // span_k),
+        in_specs=[scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-    )(qo, ko, kl, q3, k3, v3, do3, lse, delta)
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, D),
+    )(qo, ko, q3, k3, v3, do3, lse, delta)
 
-    # K outer / Q inner: the accumulators belong to the K block.
-    q_spec_t = pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, i, 0))
-    row_spec_t = _row_spec(blk_q, lambda b, j, i: (b, 0, i))
-    k_spec_t = pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0))
+    # K outer / Q streams: the accumulators belong to the K block.
+    q_spec = pl.BlockSpec((1, span_q, D), lambda b, j, s: (b, s, 0))
+    row_spec = pl.BlockSpec((1, 1, span_q), lambda b, j, s: (b, 0, s))
+    k_spec = pl.BlockSpec((1, blk_k, D), lambda b, j, s: (b, j, 0))
     dk, dv = _pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          blk_q=blk_q, blk_k=blk_k),
+        functools.partial(_bwd_dkv_kernel, **kernel_kw),
         "flash_bwd_dkv",
-        grid=(BH, Sk // blk_k, Sq // blk_q),
-        in_specs=[scalar, scalar, scalar,
-                  q_spec_t, k_spec_t, k_spec_t, q_spec_t,
-                  row_spec_t, row_spec_t],
-        out_specs=[
-            pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
-        ],
+        grid=(BH, Sk // blk_k, Sq // span_q),
+        in_specs=[scalar, scalar,
+                  q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sk, D), k3.dtype),
             jax.ShapeDtypeStruct((BH, Sk, D), v3.dtype),
@@ -367,39 +458,51 @@ def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl, *, scale, causal,
             pltpu.VMEM((blk_k, D), jnp.float32),
             pltpu.VMEM((blk_k, D), jnp.float32),
         ],
-    )(qo, ko, kl, q3, k3, v3, do3, lse, delta)
+        compiler_params=_params(blk_q, blk_k, span_q, blk_k, D),
+    )(qo, ko, q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 
 
 # -- public entrypoint ---------------------------------------------------------
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
-)
-def _flash(q3, k3, v3, offsets, kl, scale, causal, blk_q, blk_k, out_dtype):
-    qo, ko = offsets
-    return _fwd(q3, k3, v3, qo, ko, kl, scale=scale, causal=causal,
-                blk_q=blk_q, blk_k=blk_k, out_dtype=out_dtype)
-
-
-def _flash_fwd(q3, k3, v3, offsets, kl, scale, causal, blk_q, blk_k,
+def _flash_fwd(q3, k3, v3, offsets, scale, causal, k_len, blk_q, blk_k,
                out_dtype):
-    qo, ko = offsets
-    o3, lse = _fwd(q3, k3, v3, qo, ko, kl, scale=scale, causal=causal,
-                   blk_q=blk_q, blk_k=blk_k, out_dtype=out_dtype)
-    return (o3, lse), (q3, k3, v3, o3, lse, qo, ko, kl)
+    o3, lse = _fwd(q3, k3, v3, *offsets, scale=scale, causal=causal,
+                   k_len=k_len, blk_q=blk_q, blk_k=blk_k, out_dtype=out_dtype)
+    return (o3, lse), (q3, k3, v3, o3, lse, offsets)
 
 
-def _flash_bwd(scale, causal, blk_q, blk_k, out_dtype, res, cts):
-    q3, k3, v3, o3, lse, qo, ko, kl = res
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash(*args):
+    return _flash_fwd(*args)[0]
+
+
+def _flash_bwd(scale, causal, k_len, blk_q, blk_k, out_dtype, res, cts):
+    q3, k3, v3, o3, lse, (qo, ko) = res
     do3, dlse = cts
-    dq, dk, dv = _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, kl,
-                      scale=scale, causal=causal, blk_q=blk_q, blk_k=blk_k)
-    return dq, dk, dv, None, None
+    dq, dk, dv = _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, scale=scale,
+                      causal=causal, k_len=k_len, blk_q=blk_q, blk_k=blk_k)
+    return dq, dk, dv, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _round_up(n: int, m: int) -> int:
+    return ((n + m - 1) // m) * m
+
+
+#: The tile, for every call: rows of queries by rows of keys in one pass of
+#: the arithmetic. One number because the chip sweep of PR 26
+#: (`onchip_flash_sweep.py`, bf16, causal, v5e; PERF.md, Findings) found one:
+#: 512 x 512 was the quickest tile for each of the three kernels at (S, D) =
+#: (1024, 64), (2048, 64) and (1024, 128) and within 6% of the quickest
+#: (1024 x 1024) at (4096, 64). Smaller tiles waste less of the causal
+#: diagonal but pay a loop trip's fill and drain more often; larger ones
+#: compute more of the future. A sequence shorter than the tile gets one
+#: tile of its own length, in whole 128-row MXU tiles.
+_TILE = 512
 
 
 def flash_attention(
@@ -427,29 +530,20 @@ def flash_attention(
     final merge; lse is (B, H, Sq) f32, with rows that see no keys at the
     finite ``_NEG_INF`` sentinel) — the ring layer merges per-hop
     (out, lse) pairs associatively and gradients flow through both.
+
+    ``block_q``/``block_k`` pin the tile (multiples of 128); left out it is
+    `_TILE`, or the sequence's length where that is shorter.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
-    # Unpinned blocks resolve through the on-chip-swept tuning table
-    # (ops/flash_tuning.py); 128x128 wherever the table is silent.
-    if block_q is None or block_k is None:
-        from edl_tpu.ops import flash_tuning
-
-        tq, tk = flash_tuning.lookup(Sk, D, q.dtype)
-        block_q = block_q if block_q is not None else tq
-        block_k = block_k if block_k is not None else tk
-
-    def round_up(n, m):
-        return ((n + m - 1) // m) * m
-
     # Tile alignment: both extents are multiples of 128 (scores and their
     # transposes are whole MXU tiles, statistics rows are whole lane rows);
-    # short sequences shrink the block to one tile and pad up to it, with
-    # padded keys masked via the valid-length compare.
-    blk_q = min(block_q, round_up(Sq, 128))
-    blk_k = min(block_k, round_up(Sk, 128))
+    # short sequences shrink the tile to one of 128 rows and pad up to it,
+    # with padded keys masked via the valid-length compare.
+    blk_q = min(block_q or _TILE, _round_up(Sq, 128))
+    blk_k = min(block_k or _TILE, _round_up(Sk, 128))
 
     def to3(x):  # (B, S, H, D) -> (B*H, S, D)
         Bx, Sx, Hx, Dx = x.shape
@@ -458,17 +552,24 @@ def flash_attention(
     q3 = _pad_seq(to3(q), blk_q)
     k3 = _pad_seq(to3(k), blk_k)
     v3 = _pad_seq(to3(v), blk_k)
+    # once a trace, for whoever reads a profile: which tiling was built
+    span_q, span_k = _span(q3.shape[1], blk_q), _span(k3.shape[1], blk_k)
+    _log.debug(
+        "flash_attention q%s k%s %s: tile %dx%d; grid steps a call: "
+        "flash_fwd and flash_bwd_dq %d (key span %d), flash_bwd_dkv %d "
+        "(query span %d)", q.shape, k.shape, q.dtype, blk_q, blk_k,
+        B * H * (q3.shape[1] // blk_q) * (k3.shape[1] // span_k), span_k,
+        B * H * (k3.shape[1] // blk_k) * (q3.shape[1] // span_q), span_q)
 
-    qo = jnp.asarray([q_offset], jnp.int32)
-    ko = jnp.asarray([k_offset], jnp.int32)
-    kl = jnp.asarray([Sk], jnp.int32)  # valid key length (pre-padding)
+    offsets = (jnp.asarray([q_offset], jnp.int32),
+               jnp.asarray([k_offset], jnp.int32))
 
     # With lse (the ring's hop engine) the partial output stays f32: hops
     # merge at accumulator precision and the CALLER downcasts once after
     # the final merge — the same discipline the einsum ring engine had.
     out_dtype = jnp.float32 if return_lse else q.dtype
-    o3, lse3 = _flash(q3, k3, v3, (qo, ko), kl, scale, causal,
-                      blk_q, blk_k, jnp.dtype(out_dtype))
+    o3, lse3 = _flash(q3, k3, v3, offsets, scale, causal, Sk, blk_q, blk_k,
+                      jnp.dtype(out_dtype))
     out = o3[:, :Sq].reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
     if not return_lse:
         return out

@@ -1,133 +1,100 @@
-"""On-chip flash block-size sweep: find and persist the fastest VMEM tiles.
+"""On-chip tile sweep of the three flash kernels: the measurement behind
+`edl_tpu.ops.flash_attention._blocks`.
 
-Sweeps ``block_q`` x ``block_k`` over {128, 256, 512}^2 for each
-benchmark shape (fwd+bwd, the training direction), on the chip only —
-interpret mode has no VMEM and its timings are meaningless. The
-winners land in two places:
+For each shape (B, S, H, D), bf16 and causal, and each tile (block_q,
+block_k) it times `flash_fwd`, `flash_bwd_dq` and `flash_bwd_dkv` apart (a
+jit that returns only dQ leaves XLA nothing of the dK/dV kernel to run, and
+the other way round) and prints one JSON line a point; the same lines go to
+``chiprun_out/flash_sweep.jsonl``. A tile may name a third number, the most
+rows of the streamed operand a grid step takes (`_MAX_SPAN`): ``[128, 128,
+128]`` is the grid the kernels had before the retiling, one tile a step.
+Only a chip gives these times: the script exits non-zero without a TPU.
 
-- ``FLASH_SWEEP.json`` — the full grid with per-config ms/step (artifact);
-- ``edl_tpu/ops/flash_blocks.json`` — the tuning table the kernel's
-  default path consults (`ops/flash_tuning.lookup`); commit both.
-
-Configs whose VMEM demand exceeds the chip fail to lower — recorded as
-such and skipped (that's the graceful-fallback evidence, not an error).
-Timing within one process on one shape: the ranking is what is read,
-kernels dominate and transfers are constant across configs.
-
-Usage: `python onchip_flash_sweep.py` on the chip; EDL_SWEEP_SHAPES /
-EDL_SWEEP_BLOCKS override the grid.
+Usage: `python onchip_flash_sweep.py [SHAPES_JSON [TILES_JSON]]`.
 """
 
 from __future__ import annotations
 
+import importlib
 import itertools
 import json
 import os
-import statistics
+import sys
 import time
 
-#: (B, S, H, D) — the bench_flash shapes plus the LM-bench attention shape
-_DEFAULT_SHAPES = [
-    [4, 1024, 8, 64],
-    [4, 2048, 8, 64],
-    [2, 4096, 8, 64],
-    [1, 8192, 8, 128],
-]
-_DEFAULT_BLOCKS = [128, 256, 512]
+#: the benchmark cell's attention shape, a ring hop's and a longer
+#: sequence at the same token count, and a wider head
+SHAPES = [[32, 1024, 16, 64], [16, 2048, 16, 64], [8, 4096, 16, 64],
+          [16, 1024, 16, 128]]
+TILES = [[128, 128, 128]] + [
+    list(t) for t in itertools.product([128, 256, 512, 1024], repeat=2)]
+REPS = 10
 
 
-def main() -> None:
+def _ms(fn, *args) -> float:
+    import jax
+
+    jax.block_until_ready(fn(*args))  # compile, and Mosaic's verdict
+    t0 = time.perf_counter()
+    for _ in range(REPS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round(1e3 * (time.perf_counter() - t0) / REPS, 4)
+
+
+def main(argv) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from bench import require_devices
+    # the module, not the function `edl_tpu.ops` exports under its name
+    fa = importlib.import_module("edl_tpu.ops.flash_attention")
 
-    devices = require_devices()
-    backend = devices[0].platform
-    if backend == "cpu" and os.environ.get("EDL_SWEEP_ALLOW_CPU") != "1":
-        print(json.dumps({
-            "metric": "flash_block_sweep",
-            "error": "refusing to tune VMEM tiles in interpret mode on CPU "
-                     "(timings meaningless); EDL_SWEEP_ALLOW_CPU=1 to force "
-                     "a harness smoke",
-        }))
-        return
-
-    from edl_tpu.ops import flash_attention, flash_tuning
-
-    shapes = json.loads(os.environ.get("EDL_SWEEP_SHAPES", "null")) \
-        or _DEFAULT_SHAPES
-    grid = json.loads(os.environ.get("EDL_SWEEP_BLOCKS", "null")) \
-        or _DEFAULT_BLOCKS
-    steps = max(1, int(os.environ.get("EDL_BENCH_STEPS", "10")))
-    reps = max(1, int(os.environ.get("EDL_BENCH_WINDOWS", "3")))
-
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        print(json.dumps({"error": f"no TPU: {device.platform}"}))
+        return 1
+    shapes = json.loads(argv[1]) if len(argv) > 1 else SHAPES
+    tiles = json.loads(argv[2]) if len(argv) > 2 else TILES
+    os.makedirs("chiprun_out", exist_ok=True)
     rng = np.random.default_rng(0)
-    records = []
-    winners = {}
-    for B, S, H, D in shapes:
-        q = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-        k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-        v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.bfloat16)
-        best = None
-        for bq, bk in itertools.product(grid, grid):
-            if bq > S or bk > S:
-                continue
-            rec = {"shape_BSHD": [B, S, H, D], "block_q": bq, "block_k": bk}
-            try:
-                step = jax.jit(jax.grad(
-                    lambda q: jnp.sum(flash_attention(
-                        q, k, v, block_q=bq, block_k=bk) ** 2)
-                ))
-                step(q).block_until_ready()  # compile + lowering check
-                times = []
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    for _ in range(steps):
-                        g = step(q)
-                    jax.block_until_ready(g)
-                    times.append((time.perf_counter() - t0) / steps)
-                ms = 1e3 * statistics.median(times)
-                rec["ms_per_step"] = round(ms, 3)
-                if best is None or ms < best[0]:
-                    best = (ms, bq, bk)
-            except Exception as e:  # noqa: BLE001 — VMEM overflow is data
-                rec["error"] = str(e)[:300]
-            records.append(rec)
-            print(json.dumps(rec), flush=True)
-        if best is not None:
-            key = flash_tuning._key(flash_tuning._bucket(S), D, "bfloat16")
-            # keep the better winner if two shapes share a bucket
-            if key not in winners or best[0] < winners[key][0]:
-                winners[key] = best
-
-    meta = {
-        "backend": backend,
-        "device_kind": str(getattr(devices[0], "device_kind", "")),
-        "steps": steps,
-        "reps": reps,
-        "note": "fwd+bwd ms/step medians; see FLASH_SWEEP.json for the grid",
-    }
-    here = os.path.dirname(os.path.abspath(__file__))
-    with open(os.path.join(here, "FLASH_SWEEP.json"), "w") as f:
-        json.dump({"metric": "flash_block_sweep", "meta": meta,
-                   "grid": records,
-                   "winners": {k: {"ms_per_step": round(v[0], 3),
-                                   "blocks": [v[1], v[2]]}
-                               for k, v in winners.items()}}, f, indent=1)
-    if backend != "cpu":
-        flash_tuning.save_table(
-            {k: (v[1], v[2]) for k, v in winners.items()}, meta
-        )
-    print(json.dumps({
-        "metric": "flash_block_sweep",
-        "winners": {k: [v[1], v[2]] for k, v in winners.items()},
-        "configs_timed": sum(1 for r in records if "ms_per_step" in r),
-        "configs_failed": sum(1 for r in records if "error" in r),
-        "table_written": backend != "cpu",
-    }))
+    span_default = fa._MAX_SPAN
+    with open("chiprun_out/flash_sweep.jsonl", "a") as sink:
+        for B, S, H, D in shapes:
+            q3, k3, v3, do3 = (
+                jnp.asarray(rng.standard_normal((B * H, S, D)), jnp.bfloat16)
+                for _ in range(4))
+            zero = jnp.zeros((1,), jnp.int32)
+            for blk_q, blk_k, *span in tiles:
+                if max(blk_q, blk_k) > S:
+                    continue
+                fa._MAX_SPAN = span[0] if span else span_default
+                kw = dict(scale=D ** -0.5, causal=True, k_len=S,
+                          blk_q=blk_q, blk_k=blk_k)
+                rec = {"shape_BSHD": [B, S, H, D], "tile": [blk_q, blk_k],
+                       "max_span": fa._MAX_SPAN,
+                       "device_kind": device.device_kind}
+                fwd = jax.jit(lambda q, k, v: fa._fwd(
+                    q, k, v, zero, zero, out_dtype=jnp.bfloat16, **kw))
+                try:
+                    o3, lse = fwd(q3, k3, v3)
+                    rec["fwd_ms"] = _ms(fwd, q3, k3, v3)
+                    bwd = lambda pick: jax.jit(lambda q, k, v, o, l, do: pick(
+                        fa._bwd(q, k, v, o, l, do, jnp.zeros_like(l), zero,
+                                zero, **kw)))
+                    args = (q3, k3, v3, o3, lse, do3)
+                    rec["dq_ms"] = _ms(bwd(lambda g: g[0]), *args)
+                    rec["dkv_ms"] = _ms(bwd(lambda g: g[1:]), *args)
+                    rec["sum_ms"] = round(2 * rec["fwd_ms"] + rec["dq_ms"]
+                                          + rec["dkv_ms"], 4)
+                except Exception as e:  # noqa: BLE001 -- a refusal is data
+                    rec["error"] = str(e)[:300]
+                line = json.dumps(rec)
+                print(line, flush=True)
+                sink.write(line + "\n")
+    fa._MAX_SPAN = span_default
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main(sys.argv))

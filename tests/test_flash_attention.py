@@ -5,6 +5,9 @@ identical program the TPU compiles, executed by the interpreter — so these
 tests validate the kernel logic itself, not a CPU reimplementation.
 """
 
+import importlib
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +15,9 @@ import pytest
 
 from edl_tpu.ops import flash_attention
 from edl_tpu.parallel.ring_attention import dense_attention
+
+#: the module; `edl_tpu.ops` exports the function under the same name
+fa = importlib.import_module("edl_tpu.ops.flash_attention")
 
 
 def rand_qkv(rng, B, S, H, D, dtype=jnp.float32, Sk=None):
@@ -223,3 +229,159 @@ def test_randomized_shapes_and_offsets_property():
             np.asarray(gf), np.asarray(gd), rtol=3e-4, atol=3e-4,
             err_msg=f"grad trial {trial}",
         )
+
+
+# -- tiles and spans -----------------------------------------------------------
+#
+# A grid step holds a span of several tiles of the streamed operand and a
+# loop inside it stops at the causal diagonal or at the keys' valid length
+# (flash_attention.py, "tiling"). The cases below put more than one tile in
+# a span, more than one span in a sequence, the diagonal inside a tile, and
+# padding inside the last tile, at B=1, H=2, D=16 to keep the interpreter
+# quick.
+
+
+def positioned_oracle(q, k, v, *, causal, q_off=0, k_off=0):
+    """Dense attention over globally positioned scores -> (out, lse). A row
+    that sees no key gives zeros and the kernel's finite sentinel."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(q.shape[-1])
+    seen = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        q_pos = q_off + jnp.arange(q.shape[1])
+        k_pos = k_off + jnp.arange(k.shape[1])
+        seen = k_pos[None, :] <= q_pos[:, None]
+    s = jnp.where(seen, s, -1e30)
+    p = jnp.where(seen, jax.nn.softmax(s, axis=-1), 0.0)
+    lse = jnp.where(seen.any(-1), jax.nn.logsumexp(s, axis=-1), -1e30)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+    return out, lse
+
+
+def _grads(fn, q, k, v):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_matches_dense(q, k, v, *, causal, block_q, block_k):
+    """Output and the three gradients against `dense_attention`."""
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+    dense = lambda q, k, v: dense_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, b in zip("qkv", _grads(flash, q, k, v),
+                          _grads(dense, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=f"d{name}")
+
+
+TILE_CASES = [
+    # (S, D, block_q, block_k, causal)
+    (384, 16, 256, 256, True),    # pads to 512: padding inside the last tile
+    (384, 16, 256, 256, False),
+    (640, 16, 256, 256, True),    # three tiles a side, the last ragged
+    (640, 16, 512, 512, True),    # two tiles, 384 rows of padding
+    (640, 16, 256, 512, False),
+    (640, 16, 128, 256, True),
+    (1024, 16, 256, 256, True),   # aligned: no key mask, 4 x 4 tiles
+    (1024, 16, 512, 512, True),
+    (1024, 16, 512, 256, False),  # aligned and not causal: no mask at all
+    (1024, 16, 256, 512, True),
+    # was test_flash_tuning's test_kernel_correct_with_explicit_nondefault_blocks
+    (512, 64, 256, 128, True),
+    (512, 64, 128, 256, True),
+    (512, 64, 256, 256, True),
+]
+
+
+@pytest.mark.parametrize("S,D,block_q,block_k,causal", TILE_CASES)
+def test_tiles_match_dense_oracle(S, D, block_q, block_k, causal):
+    rng = np.random.default_rng(S + block_q + block_k)
+    q, k, v = rand_qkv(rng, 1, S, 2, D)
+    _assert_matches_dense(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_spans_shorter_than_the_sequence(monkeypatch, causal):
+    """Several grid steps along the streamed axis, as sequences past
+    `_MAX_SPAN` rows get: statistics and accumulators carry across steps,
+    and steps wholly in the causal future add nothing."""
+    monkeypatch.setattr(fa, "_MAX_SPAN", 256)
+    assert fa._span(768, 128) == 256  # three spans of two tiles
+    rng = np.random.default_rng(7)
+    q, k, v = rand_qkv(rng, 1, 700, 2, 16)
+    _assert_matches_dense(q, k, v, causal=causal, block_q=128, block_k=128)
+
+
+@pytest.mark.parametrize("q_off,k_off", [
+    (512, 0),    # a hop wholly in the past: every tile live, none masked
+    (0, 513),    # a hop wholly in the future (k_offset > q_offset + Sq)
+    (100, 300),  # partly dead: the first 200 queries see no key
+    (300, 100),  # the diagonal crosses the tiles off their corners
+])
+def test_ring_hops_at_multi_tile_blocks(q_off, k_off):
+    """What `_ring_flash_local` asks of one hop, at a block of 2 x 2 tiles:
+    out and lse against the positioned oracle, and gradients through BOTH
+    (the lse cotangent folds into delta). A dead hop is zeros, the
+    sentinel, and zero gradients."""
+    rng = np.random.default_rng(q_off + k_off)
+    q, k, v = rand_qkv(rng, 1, 512, 2, 16)
+    w = jnp.asarray(rng.standard_normal((1, 2, 512)), jnp.float32)
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            # the sentinel is a constant: it carries no gradient
+            return (jnp.sum(out ** 2)
+                    + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * w))
+        return f
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, q_offset=q_off, k_offset=k_off,
+        block_q=256, block_k=256, return_lse=True)
+    oracle = lambda q, k, v: positioned_oracle(
+        q, k, v, causal=True, q_off=q_off, k_off=k_off)
+    out, lse = flash(q, k, v)
+    want_out, want_lse = oracle(q, k, v)
+    assert out.dtype == jnp.float32 and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(oracle), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert bool(np.isfinite(np.asarray(a)).all()), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=f"d{name}")
+    if k_off > q_off + 512:
+        assert not np.asarray(out).any() and (np.asarray(lse) == -1e30).all()
+        assert not any(np.asarray(g).any() for g in got)
+
+
+def test_bfloat16_gradients_within_an_ulp_of_the_tensor():
+    """bf16 in, bf16 on the MXU: P (forward), P^T and dS (backward) are
+    rounded to bf16 at their matmuls and the gradients leave as bf16, while
+    the statistics and the sums stay f32. Against the f32 oracle on the
+    same (bf16-valued) inputs an element of a gradient is a sum of terms
+    that each carry half a bf16 ulp (2^-9) of their own size, so the sum's
+    error rides the TERMS' magnitude, not the element's: the bound is one
+    bf16 ulp (2^-8) of the tensor's largest entry, as in test_pipeline's
+    and test_collective's tolerance tests, plus the same relative."""
+    rng = np.random.default_rng(8)
+    q, k, v = rand_qkv(rng, 1, 640, 2, 16, dtype=jnp.bfloat16)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=256, block_k=256)
+    oracle = lambda q, k, v: positioned_oracle(q, k, v, causal=True)[0]
+    got = _grads(flash, q, k, v)
+    want = _grads(oracle, *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, a, b in zip("qkv", got, want):
+        assert a.dtype == jnp.bfloat16
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), b, rtol=2.0 ** -8,
+            atol=float(np.abs(b).max()) * 2.0 ** -8, err_msg=f"d{name}")

@@ -56,8 +56,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _qkv(sharding, seq):
-    return (jax.ShapeDtypeStruct((2, seq, 16, 64), jnp.bfloat16,
+def _qkv(sharding, seq, head_dim=64):
+    return (jax.ShapeDtypeStruct((2, seq, 16, head_dim), jnp.bfloat16,
                                  sharding=sharding),) * 3
 
 
@@ -67,29 +67,38 @@ def _compile_flash(fn, args):
     assert "flash_attention_interpreted" not in text
 
 
-def test_flash_forward(one_chip):
-    _compile_flash(lambda q, k, v: flash_attention(q, k, v, causal=True),
-                   _qkv(one_chip, 1024))
+#: (S, D) the tile was swept at (onchip_flash_sweep.py): the benchmark cell's,
+#: a ring hop's, the longest one span holds, a wider head; and one past
+#: `_MAX_SPAN`, which takes two spans a sequence
+RULE_SHAPES = [(1024, 64), (2048, 64), (4096, 64), (1024, 128), (8192, 64)]
 
 
-def test_flash_backward(one_chip):
+@pytest.mark.parametrize("seq,head_dim", RULE_SHAPES)
+def test_flash_forward_and_backward_at_the_rules_tiles(one_chip, seq,
+                                                       head_dim):
+    """The tile and spans the kernels take unasked, as Mosaic sees them: a
+    tile it refuses, a slice it cannot align or a kernel over its VMEM limit
+    fails here."""
+
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 
-    _compile_flash(jax.grad(loss, argnums=(0, 1, 2)), _qkv(one_chip, 1024))
+    _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                   _qkv(one_chip, seq, head_dim))
 
 
-def test_flash_with_lse_as_the_ring_calls_it(one_chip):
+@pytest.mark.parametrize("seq,head_dim", RULE_SHAPES)
+def test_flash_with_lse_as_the_ring_calls_it(one_chip, seq, head_dim):
     """`_ring_flash_local`'s hop engine: global offsets, f32 partial output
     and a differentiable logsumexp."""
 
     def hop(q, k, v):
         out, lse = flash_attention(q, k, v, causal=True, return_lse=True,
-                                   q_offset=1024, k_offset=0)
+                                   q_offset=seq, k_offset=0)
         return out.sum() + lse.sum()
 
     _compile_flash(jax.value_and_grad(hop, argnums=(0, 1, 2)),
-                   _qkv(one_chip, 1024))
+                   _qkv(one_chip, seq, head_dim))
 
 
 @pytest.mark.parametrize("seq", [200, 12])
@@ -102,6 +111,33 @@ def test_flash_short_sequence_pads_to_the_block(one_chip, seq):
 
     _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
                    _qkv(one_chip, seq))
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def test_flash_grid_at_the_benchmark_shape_is_a_few_thousand_steps():
+    """A grid step costs about 0.35 us before it computes: at 128 x 128
+    tiles, one a step, the cell's (32, 1024, 16, 64) call was 32,768 steps
+    and 16 ms (PERF.md, PR 26). A fall back to that must not pass unseen:
+    every kernel of the call, forward and backward, stays under 4,096."""
+    qkv = (jax.ShapeDtypeStruct((32, 1024, 16, 64), jnp.bfloat16),) * 3
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*qkv)
+    steps = {}
+    for eqn in _pallas_calls(jaxpr.jaxpr):
+        grid = eqn.params["grid_mapping"].grid
+        steps[eqn.params["name"]] = grid[0] * grid[1] * grid[2]
+    assert set(steps) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert max(steps.values()) <= 4096, steps
 
 
 def _param_avals(model, mesh):
