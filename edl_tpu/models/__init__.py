@@ -12,6 +12,9 @@ Reference examples (SURVEY C14-C18) and their equivalents here:
   -> ``ctr`` — the flagship; its sparse tables are row-sharded over the mesh
   (`edl_tpu.parallel.ShardedEmbedding`) instead of living on C++ pservers
 - ResNet-50 (BASELINE.json config list) -> ``resnet``
+- no reference analog: ``transformer`` (dense decoder LM, every mesh axis) and
+  ``hybrid`` (a decoder whose stack is a pattern of Mamba-2, grouped-query
+  attention and sparse-expert layers; training only)
 
 Every model follows the same functional convention (``models.base.Model``):
 pure ``init``/``loss_fn`` plus sharding specs, so the elastic runtime can
@@ -24,7 +27,8 @@ data values.
 """
 
 from edl_tpu.models.base import Model
-from edl_tpu.models import fit_a_line, mnist, word2vec, ctr, resnet, transformer
+from edl_tpu.models import (fit_a_line, mnist, word2vec, ctr, resnet,
+                            transformer, hybrid)
 
 
 _MODULES = {
@@ -34,6 +38,7 @@ _MODULES = {
     "ctr": ctr,
     "resnet": resnet,
     "transformer": transformer,
+    "hybrid": hybrid,
 }
 
 #: default instances, keyed by each model's own name (module name and
@@ -64,5 +69,14 @@ def resolve(ref: str, config=None) -> Model:
     return mod.make_model(**config)
 
 
-__all__ = ["Model", "ctr", "fit_a_line", "get", "mnist", "resnet", "resolve",
-           "transformer", "word2vec"]
+def serving_refusal(ref: str):
+    """Why the zoo module named ``ref``, or the one whose model is named so,
+    cannot be exported or served (its ``NOT_SERVABLE``), or None where it
+    can."""
+    mod = _MODULES.get(ref) or next(
+        (m for m in _MODULES.values() if m.MODEL.name == ref), None)
+    return getattr(mod, "NOT_SERVABLE", None)
+
+
+__all__ = ["Model", "ctr", "fit_a_line", "get", "hybrid", "mnist", "resnet",
+           "resolve", "serving_refusal", "transformer", "word2vec"]

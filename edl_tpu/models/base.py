@@ -61,3 +61,8 @@ class Model:
     #: comparable to published ones. `edl_tpu.tools.mfu` falls back to XLA
     #: cost analysis when absent.
     flops_per_step: Optional[Callable] = None
+    #: optional (params, batch) -> {layer: {made, held, per_expert, dropped}}
+    #: for models with routed experts: the router's decisions on one batch as
+    #: host numbers (and into the metrics registry). A forward pass of its
+    #: own, for a caller outside the timed step (`models/hybrid.py`).
+    routing_stats: Optional[Callable] = None

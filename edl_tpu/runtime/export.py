@@ -319,6 +319,18 @@ def _write_versioned(directory, model_ref, host_flat, config, step) -> None:
                 pass  # already gone or being replaced
 
 
+def _refuse_unservable(model_ref: str) -> None:
+    """A zoo module that states why it cannot be served (its
+    ``NOT_SERVABLE``) is refused here, by that reason, and not inside a
+    trace of a predict or decode program it does not have."""
+    from edl_tpu import models as zoo
+
+    reason = zoo.serving_refusal(model_ref)
+    if reason:
+        raise NotImplementedError(
+            f"model {model_ref!r} has no inference artifact: {reason}")
+
+
 def save_inference_model(
     directory: str,
     model_ref: str,
@@ -338,6 +350,7 @@ def save_inference_model(
     export to its own ``v<step>`` subdirectory and atomically advances the
     ``LATEST`` pointer (the layout the serving tier's swap watcher needs).
     """
+    _refuse_unservable(model_ref)
     host_flat = _gather_host(params)
     if write:
         writer = _write_versioned if versioned else _write_artifact
@@ -451,6 +464,7 @@ def load_inference_model(
         manifest = json.load(f)
     if manifest.get("format") != _FORMAT:
         raise ValueError(f"unknown artifact format {manifest.get('format')!r}")
+    _refuse_unservable(manifest["model"])
     npz = np.load(os.path.join(directory, manifest["weights"]))
     pairs = []
     for i, entry in enumerate(manifest["leaves"]):

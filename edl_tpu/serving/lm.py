@@ -69,6 +69,24 @@ __all__ = ["LMServingConfig", "LMServingReplica", "LMStreamHandle"]
 log = logging.getLogger("edl_tpu.serving.lm")
 
 
+def require_lm_servable(model) -> None:
+    """The engine serves dense transformer artifacts: K/V is the only state
+    its cache manager, prefill and decode programs know. A zoo module that
+    states why it cannot be served (``NOT_SERVABLE``) is refused by that
+    reason; any other model without a transformer config by its lack."""
+    from edl_tpu import models as zoo
+
+    reason = zoo.serving_refusal(model.name)
+    if reason:
+        raise NotImplementedError(
+            f"model {model.name!r} cannot be served: {reason}")
+    if not hasattr(getattr(model, "config", None), "n_layers"):
+        raise TypeError(
+            f"model {model.name!r} carries no transformer config — "
+            f"the LM serving path needs a transformer artifact"
+        )
+
+
 @dataclass
 class LMServingConfig:
     """Knobs for one LM serving replica."""
@@ -201,12 +219,8 @@ class LMServingReplica:
 
         cfg = self.config
         art = load_inference_model(cfg.model_dir)
-        mcfg = getattr(art.model, "config", None)
-        if mcfg is None or not hasattr(mcfg, "n_layers"):
-            raise TypeError(
-                f"model {art.model.name!r} carries no transformer config — "
-                f"the LM serving path needs a transformer artifact"
-            )
+        require_lm_servable(art.model)
+        mcfg = art.model.config
         if cfg.seq_buckets[-1] > mcfg.seq_len:
             raise ValueError(
                 f"largest seq bucket {cfg.seq_buckets[-1]} exceeds the "

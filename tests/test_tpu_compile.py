@@ -210,3 +210,53 @@ def test_train_step(topo, chips):
         assert "all-reduce" in text and "all-gather" in text
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15 * 2**30
+
+
+def test_hybrid_train_step_at_the_cell(topo):
+    """`train_nemotron3nano_1chip`'s step: the configuration file's widths
+    (nine layers `MEMEM*EME`, 8 of 128 experts held, 16,384 rows of the
+    vocabulary) at the cell's 2 x 8,192 tokens, per-layer remat, Adam. It
+    compiles for the described v5e (the flash kernels at (64, 8192, 128) and
+    the grouped product through Mosaic) and its temporaries and arguments fit
+    the chip's 15.75 GiB; the numbers are copied into the cell file's
+    ``sizing`` (PR 27: temp 7.65 GiB + arguments 7.45 GiB)."""
+    import json
+    import os
+
+    from edl_tpu.models import resolve
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "nemotron-3-nano-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "fixed_b2_s8192.json")) as f:
+        traffic = json.load(f)
+    sizes = {ours: config[theirs]
+             for theirs, ours in config["maps_to"].items()}
+    mesh = build_mesh(MeshSpec({"data": 1}), list(topo.devices)[:1])
+    model = resolve(config["model"], dict(sizes, seq_len=traffic["seq_len"],
+                                          remat=True))
+    trainer = Trainer(model, mesh, TrainerConfig(optimizer="adam"))
+    params = _param_avals(model, mesh)
+    rep = NamedSharding(mesh, P())
+    state = TrainState(
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), params,
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            jax.eval_shape(trainer.opt.init, params)))
+    tokens = jax.ShapeDtypeStruct(
+        (traffic["batch"], traffic["seq_len"]), jnp.int32,
+        sharding=NamedSharding(mesh, P("data")))
+    compiled = trainer._jit_step.lower(
+        state, {"tokens": tokens, "targets": tokens}).compile()
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "tpu_custom_call" in text
+    assert "flash_attention_interpreted" not in text
+    mem = compiled.memory_analysis()
+    print(f"hybrid step for the described v5e: temp "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
+    assert mem.argument_size_in_bytes > 7.4 * 2**30  # the 8.00 GB of state
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        < 15.75 * 2**30

@@ -377,9 +377,11 @@ def test_the_new_entries_are_additions_and_valid():
     names = [m["name"] for m in bench["per_layer"]]
     assert names[:4] == ["step_ms_p50.train", "mfu_pct.train",
                          "flash_time_pct.train", "device_idle_pct.train"]
-    assert len(names) == 16 and len(set(names)) == 16
-    for m in bench["per_layer"][4:]:
-        assert m["workloads"] == ["train_gpt2m_1chip"]
+    # PR 25's twelve follow PR 24's four; later PRs append after them and
+    # append their cells to a metric's `workloads`
+    assert len(names) >= 16 and len(set(names)) == len(names)
+    for m in bench["per_layer"][4:16]:
+        assert m["workloads"][0] == "train_gpt2m_1chip"
         assert m["moves"] == "train_tokens_per_s"
     stage = run.load_json(BENCH, "layer_metrics",
                           "decode_stage_ms_p50.serve.json")
