@@ -9,7 +9,7 @@ vocabulary held. The kinds (the family of NVIDIA's Nemotron-H / Nemotron 3
 hybrids; equations in each function's docstring):
 
 - ``M``  a Mamba-2 mixer: causal depthwise conv, the SSD chunked scan and
-  its backward (plain XLA einsums), a grouped gated RMS norm;
+  its backward (the Pallas kernels of `ops.ssd`), a grouped gated RMS norm;
 - ``*``  grouped-query causal attention without positional encoding, through
   `ops.flash_attention` with K and V repeated to the query heads outside it;
 - ``E``  an expert layer: sigmoid router with a selection bias over ALL the
@@ -268,60 +268,30 @@ def _relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
 
 
-def _ssd(cfg: HybridConfig, x, dt, A, Bm, Cm):
+def _ssd(cfg: HybridConfig, x, dt, A, Bm, Cm, D):
     """The Mamba-2 recurrence by the SSD chunked scan. Per head (group
     ``g = head // (H/G)``): ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
-    ``y_t = S_t C_t``. x (B, S, H, P), dt (B, S, H) > 0, A (H,) < 0, Bm and
-    Cm (B, S, G, N); returns y (B, S, H, P) float32.
+    ``y_t = S_t C_t + D x_t`` (the skip is folded in). x (B, S, H, P), dt
+    (B, S, H) > 0, A (H,) < 0, Bm and Cm (B, S, G, N), D (H,); returns y
+    (B, S, H, P) float32.
 
-    Within a chunk of Q positions the masked product ``C B^T`` weighted by
-    the decay between the two positions; between chunks the state each chunk
-    leaves, carried by a `lax.scan`; a chunk's output adds what the carried
-    state gives its positions. Decay sums, ``exp`` and the state are float32;
-    the four products feed the MXU bf16 with float32 accumulation."""
-    Bz, S, H, Pd = x.shape
-    G, N, Q = cfg.mamba_groups, cfg.state_size, cfg.chunk_size
-    R = H // G
-    pad = (-S) % Q
-    if pad:  # dt = 0 there: no decay, no input; the rows are cut off again
-        x, dt, Bm, Cm = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
-                                 (a.ndim - 2)) for a in (x, dt, Bm, Cm))
-    nc = (S + pad) // Q
-    a = (dt * A).reshape(Bz, nc, Q, G, R)            # log-decay of each step
-    a_cum = jnp.cumsum(a, axis=2)                     # inclusive, within chunk
-    xdt = (x * dt[..., None]).reshape(Bz, nc, Q, G, R, Pd)
-    Bc = Bm.reshape(Bz, nc, Q, G, N)
-    Cc = Cm.reshape(Bz, nc, Q, G, N)
+    The Pallas kernels of `ops/ssd.py` (`ssd_fwd`, `ssd_bwd`): within a chunk
+    of Q positions the masked product ``C B^T`` weighted by the decay between
+    the two positions; between chunks the state each chunk leaves, carried in
+    VMEM; a chunk's output adds what the carried state gives its positions.
+    Decay sums, ``exp`` and the state are float32; the products feed the MXU
+    bf16 with float32 accumulation. Lowered for a TPU, Q, N and the group's
+    R x P must be multiples of 128; for the CPU the same bodies run in the
+    interpreter at any shape."""
+    from edl_tpu.ops.ssd import ssd_scan
 
-    # within the chunk: position q reads k <= q at exp(sum of a over (k, q])
-    cb = _mm("bcqgn,bckgn->bcgqk", Cc, Bc)            # (B, nc, G, Q, Q)
-    at = a_cum.transpose(0, 1, 3, 4, 2)               # (B, nc, G, R, Q)
-    seg = at[..., :, None] - at[..., None, :]
-    seen = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.exp(jnp.where(seen, seg, -jnp.inf))   # 0 above the diagonal
-    y = _mm("bcgrqk,bckgrp->bcqgrp", cb[:, :, :, None] * decay, xdt)
-
-    # the state each chunk leaves, and the scan that carries it on
-    to_end = jnp.exp(a_cum[:, :, -1:] - a_cum)        # (B, nc, Q, G, R)
-    left = _mm("bckgn,bckgrp->bcgrpn", Bc, xdt * to_end[..., None])
-    chunk_decay = jnp.exp(a_cum[:, :, -1])            # (B, nc, G, R)
-
-    def carry_on(state, chunk):
-        adds, decays = chunk
-        return state * decays[..., None, None] + adds, state
-
-    _, entering = jax.lax.scan(
-        carry_on, jnp.zeros((Bz, G, R, Pd, N), jnp.float32),
-        (left.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)))
-    y = y + _mm("bcqgn,cbgrpn->bcqgrp", Cc, entering) \
-        * jnp.exp(a_cum)[..., None]
-    return y.reshape(Bz, nc * Q, H, Pd)[:, :S]
+    return ssd_scan(x, dt, A, Bm, Cm, D, chunk=cfg.chunk_size)
 
 
 def _mamba(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
     """``[z | xBC | dt] = h W_in``; ``xBC = silu(conv1d_causal_depthwise(xBC)
     + b)`` split into x (H x P), B and C (G x N each); ``dt = softplus(dt +
-    dt_bias)``, ``A = -exp(A_log)``; the recurrence of `_ssd` plus the skip
+    dt_bias)``, ``A = -exp(A_log)``; the recurrence of `_ssd` with the skip
     ``D x``; ``y = group_rmsnorm(y * silu(z); w)`` over groups of inner/G
     (the gate before the norm); ``out = y W_out``. h (B, S, D) bf16."""
     Bz, S, _ = h.shape
@@ -343,8 +313,7 @@ def _mamba(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
         Cm = xBC[..., inner + G * N:].reshape(Bz, S, G, N)
     with jax.named_scope("ssd_core"):
         dt = jax.nn.softplus(dt + p["dt_bias"])
-        y = _ssd(cfg, x, dt, -jnp.exp(p["A_log"]), Bm, Cm)
-        y = y + p["D"][:, None] * x.astype(jnp.float32)
+        y = _ssd(cfg, x, dt, -jnp.exp(p["A_log"]), Bm, Cm, p["D"])
     with jax.named_scope("mamba_norm"):
         y = y.reshape(Bz, S, G, inner // G) \
             * jax.nn.silu(z.astype(jnp.float32)).reshape(Bz, S, G, inner // G)

@@ -124,8 +124,13 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     for e in bench["configs"] + bench["workloads"]:
         assert len(e["why"]) <= 200 and "\n" not in e["why"]
     reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)]
-    assert len(reports) == 22 and "mfu_pct.train" not in reports \
+    assert len(reports) == 23 and "mfu_pct.train" not in reports \
         and "attn_roofline_pct.train" not in reports
+    # PR 28's counter that the SSD kernels ran: data on a reader that was there
+    assert reports[-1] == "ssd_kernel_time_pct.train"
+    how = run.load_json(BENCH, "layer_metrics", "ssd_kernel_time_pct.train.json")
+    assert how["reader"] == "trace_scope_time_share"
+    assert how["args"] == {"scopes": ["ssd_fwd", "ssd_bwd"]}
     # every metric that was there still lists the cell it listed
     for m in bench["per_layer"]:
         if not m["name"].endswith(("train_hybrid",)) \

@@ -100,42 +100,72 @@ def test_the_references_blocks_change_nothing(mesh, monkeypatch):
         close(b, a, 1e-4)
 
 
-def test_chunked_scan_is_the_literal_recurrence():
-    """`_ssd` over several chunks (so a non-zero state is carried from chunk
-    to chunk) and a length that is no multiple of the chunk, against one
-    position at a time."""
-    cfg = hybrid.HybridConfig()
-    H, P, G, N, length = 4, 16, 2, 16, 29
-    ks = jax.random.split(jax.random.PRNGKey(1), 5)
-    x = jax.random.normal(ks[0], (2, length, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (2, length, H)))
-    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
-    Bm = jax.random.normal(ks[3], (2, length, G, N))
-    Cm = jax.random.normal(ks[4], (2, length, G, N))
+#: the scan's cases: batch, length, heads, head width P, groups, state N,
+#: chunk Q, the inputs' dtype, and whether only the first chunk has input
+SCAN_CASES = {
+    # today's tiny shape: four chunks of 8, the last ragged
+    "tiny_ragged": (2, 29, 4, 16, 2, 16, 8, jnp.float32, False),
+    # the cell's widths cut in extent: one group of 8 heads of 64, N 128,
+    # chunks of 128 (what Mosaic tiles), three chunks with a ragged tail
+    "lane_aligned_ragged": (1, 293, 8, 64, 1, 128, 128, jnp.float32, False),
+    # input in the first chunk alone: whatever the four later chunks give
+    # is the state carried from chunk to chunk, and nothing else
+    "state_carried_over_chunks": (2, 40, 4, 16, 2, 16, 8, jnp.float32, True),
+    "bf16_inputs": (2, 29, 4, 16, 2, 16, 8, jnp.bfloat16, False),
+}
 
-    def literal(x, dt, Bm, Cm):
+
+@pytest.mark.parametrize("case", list(SCAN_CASES))
+def test_chunked_scan_is_the_literal_recurrence(case):
+    """`_ssd` (the kernels of `ops/ssd.py`, through the interpreter) over
+    several chunks, so a non-zero state is carried from chunk to chunk,
+    against one position at a time: the value, and the gradients of a random
+    projection of it with respect to x, dt, A, B, C and the skip's D."""
+    Bz, length, H, P, G, N, Q, dtype, first_chunk_only = SCAN_CASES[case]
+    cfg = hybrid.HybridConfig(chunk_size=Q)
+    ks = jax.random.split(jax.random.PRNGKey(1), 6)
+    x = jax.random.normal(ks[0], (Bz, length, H, P)).astype(dtype)
+    # steps of the published initialiser's size, so a chunk's decay is mild
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (Bz, length, H)) - 2.0)
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (Bz, length, G, N)).astype(dtype)
+    Cm = jax.random.normal(ks[4], (Bz, length, G, N)).astype(dtype)
+    D = jax.random.normal(ks[5], (H,))
+    if first_chunk_only:
+        x = x.at[:, Q:].set(0)
+
+    def literal(x, dt, A, Bm, Cm, D):
+        x, Bm, Cm = (a.astype(jnp.float32) for a in (x, Bm, Cm))
         Bh, Ch = (jnp.repeat(a, H // G, axis=2) for a in (Bm, Cm))
 
         def step(state, t):
             x_t, dt_t, B_t, C_t = t
             state = jnp.exp(dt_t * A)[..., None, None] * state \
                 + (dt_t[..., None] * x_t)[..., None] * B_t[..., None, :]
-            return state, jnp.einsum("bhpn,bhn->bhp", state, C_t)
+            return state, jnp.einsum("bhpn,bhn->bhp", state, C_t,
+                                    precision="highest")
 
-        _, y = jax.lax.scan(step, jnp.zeros((2, H, P, N)), tuple(
+        _, y = jax.lax.scan(step, jnp.zeros((Bz, H, P, N)), tuple(
             a.swapaxes(0, 1) for a in (x, dt, Bh, Ch)))
-        return y.swapaxes(0, 1)
+        return y.swapaxes(0, 1) + D[:, None] * x
 
-    def chunked(x, dt, Bm, Cm):
-        return hybrid._ssd(cfg, x, dt, A, Bm, Cm)
+    def chunked(x, dt, A, Bm, Cm, D):
+        return hybrid._ssd(cfg, x, dt, A, Bm, Cm, D)
 
     probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
-    args = (x, dt, Bm, Cm)
-    close(jax.jit(chunked)(*args), jax.jit(literal)(*args), VALUE_TOL)
+    args = (x, dt, A, Bm, Cm, D)
+    got, want = jax.jit(chunked)(*args), jax.jit(literal)(*args)
+    assert got.dtype == jnp.float32
+    close(got, want, VALUE_TOL)
+    if first_chunk_only:  # the later chunks read the carried state alone
+        later = np.asarray(want - D[:, None] * x.astype(jnp.float32))[:, 3 * Q:]
+        assert np.abs(later).max() > 1e-2
+        close(got[:, 3 * Q:], want[:, 3 * Q:], VALUE_TOL)
     got, want = (jax.jit(jax.grad(lambda *a: jnp.sum(fn(*a) * probe),
-                                  (0, 1, 2, 3)))(*args)
+                                  tuple(range(6))))(*args)
                  for fn in (chunked, literal))
     for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
         close(g, w, GRAD_TOL)
 
 

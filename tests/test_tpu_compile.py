@@ -217,9 +217,11 @@ def test_hybrid_train_step_at_the_cell(topo):
     (nine layers `MEMEM*EME`, 8 of 128 experts held, 16,384 rows of the
     vocabulary) at the cell's 2 x 8,192 tokens, per-layer remat, Adam. It
     compiles for the described v5e (the flash kernels at (64, 8192, 128) and
-    the grouped product through Mosaic) and its temporaries and arguments fit
-    the chip's 15.75 GiB; the numbers are copied into the cell file's
-    ``sizing`` (PR 27: temp 7.65 GiB + arguments 7.45 GiB)."""
+    the grouped product through Mosaic, and since PR 28 the SSD scan's two
+    kernels `ssd_fwd` and `ssd_bwd` at chunks of 128 with 8 heads of 64 a
+    group) and its temporaries and arguments fit the chip's 15.75 GiB, the
+    temporaries in no more than PR 27's 7.65 GiB (the cell file's ``sizing``
+    holds PR 27's reading; PERF.md, Sizing, the newest)."""
     import json
     import os
 
@@ -252,11 +254,15 @@ def test_hybrid_train_step_at_the_cell(topo):
         state, {"tokens": tokens, "targets": tokens}).compile()
     text = compiled.as_text()
     assert "flash_fwd" in text and "tpu_custom_call" in text
+    assert "ssd_fwd" in text and "ssd_bwd" in text, \
+        "the SSD scan's kernels are not in the step"
+    # `_pallas_call` names its interpreted branch so for every kernel
     assert "flash_attention_interpreted" not in text
     mem = compiled.memory_analysis()
     print(f"hybrid step for the described v5e: temp "
           f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
           f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
     assert mem.argument_size_in_bytes > 7.4 * 2**30  # the 8.00 GB of state
+    assert mem.temp_size_in_bytes <= 7.65 * 2**30
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         < 15.75 * 2**30
