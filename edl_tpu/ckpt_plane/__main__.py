@@ -35,7 +35,7 @@ from edl_tpu.models import fit_a_line
 from edl_tpu.parallel import MeshSpec, build_mesh
 from edl_tpu.runtime.checkpoint import (Checkpointer, abstract_like,
                                         live_state_specs)
-from edl_tpu.runtime.train_loop import Trainer, TrainerConfig
+from edl_tpu.runtime.train_loop import Trainer, TrainerConfig, loss_value
 
 TOTAL_STEPS = 6
 KILL_AFTER = 3
@@ -59,7 +59,7 @@ def main() -> int:
         for i in range(lo, hi):
             state, loss = trainer.train_step(state,
                                              trainer.place_batch(batches[i]))
-        return state, float(loss)
+        return state, loss_value(loss)
 
     # 1) twin: straight through
     trainer = Trainer(model, mesh, tcfg)

@@ -15,13 +15,13 @@ global mesh (ICI in-slice, DCN across hosts):
   coordinator KV (the etcd-role subset); peers block on the key.
 
 ``jax.distributed`` world size is fixed at init — that is WHY elastic
-rescale is checkpoint-restore (`edl_tpu.runtime.elastic`). Single-host jobs
-rescale in-process (the device planner re-slices local devices). Multi-host
-jobs set ``ElasticConfig.restart_on_rescale``: on an epoch change the worker
-checkpoints and exits with ``RESCALE_EXIT_CODE``; the pod launcher
-(`edl_tpu.launcher.launch.start_trainer`) relaunches the entry, which calls
-``distributed_init`` again and comes up at the new world size, restoring
-from the durable checkpoint.
+rescale is checkpoint-restore. Single-host jobs (`runtime.elastic`,
+`ElasticWorker`) rescale in-process: the device planner re-slices local
+devices. Multi-host jobs run `runtime.multihost.MultiHostWorker`: on an epoch
+change every process of the gang exits with ``RESCALE_EXIT_CODE``; the pod
+launcher (`edl_tpu.launcher.launch.start_trainer`) relaunches the entry,
+which calls ``distributed_init`` again and comes up at the new world size,
+restoring from the last collective checkpoint.
 
 Bring-up protocol (per process):
 

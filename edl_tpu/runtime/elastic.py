@@ -26,164 +26,32 @@ from __future__ import annotations
 import dataclasses
 import json
 import logging
-import random
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
 
-from edl_tpu.coordinator.outbox import OutboxClient
-from edl_tpu.coordinator.watch import make_epoch_watch
 from edl_tpu.models.base import Model
-from edl_tpu.obs.instruments import PreemptInstruments, WorkerInstruments
-from edl_tpu.obs.tracing import Tracer, get_tracer, rescale_trace_id
-from edl_tpu.parallel.mesh import MeshSpec, build_hierarchical_mesh, build_mesh
+from edl_tpu.obs.tracing import Tracer, rescale_trace_id
 from edl_tpu.parallel.planner import Plan
-from edl_tpu.runtime.checkpoint import Checkpointer, abstract_like, live_state_specs
 from edl_tpu.runtime.data import LeaseReader, split_pass
-from edl_tpu.runtime.ft_policy import (
-    DRAIN_SHRINK, MODE_CODES, PARK, RIDE_OUT, FTPolicy, FTPolicyConfig,
-)
-from edl_tpu.runtime.train_loop import Trainer, TrainerConfig, TrainState
+from edl_tpu.runtime.ft_policy import DRAIN_SHRINK, MODE_CODES, PARK, RIDE_OUT
+from edl_tpu.runtime.train_loop import Trainer, TrainState
 from edl_tpu.runtime.wire import WireRestartRequired
+from edl_tpu.runtime.worker_base import (
+    ElasticConfig, WorkerBase, heartbeat_schedule,
+)
 from edl_tpu.tools.profiler import annotate_step
 
 #: coordinator KV key a worker publishes its live policy state under;
 #: `edl-tpu status` enumerates members and reads these back.
 FT_POLICY_KEY = "edl/ft_policy/{worker}"
 
+__all__ = ["ElasticConfig", "ElasticWorker", "FT_POLICY_KEY", "RescaleEvent",
+           "default_device_planner", "heartbeat_schedule"]
+
 log = logging.getLogger("edl_tpu.runtime.elastic")
-
-
-@dataclass
-class ElasticConfig:
-    checkpoint_dir: str = ""
-    checkpoint_interval: int = 100  # steps between periodic async saves
-    heartbeat_interval: float = 1.0  # seconds between coordinator heartbeats
-    #: fractional jitter (±) applied per beat to the heartbeat interval,
-    #: seeded by worker name: 10k workers launched from one template would
-    #: otherwise phase-lock into synchronized heartbeat storms that turn
-    #: the coordinator's load spiky (see doc/performance.md, control plane).
-    heartbeat_jitter: float = 0.2
-    #: how epoch changes reach this worker: ``"watch"`` subscribes to the
-    #: coordinator's push stream (a rescale arrives in one RTT instead of a
-    #: heartbeat period) and treats a dead subscription as an error to
-    #: surface; ``"pull"`` keeps the pre-watch heartbeat-only discovery;
-    #: ``"auto"`` (default) subscribes when the transport supports it and
-    #: degrades silently to pull when it doesn't. Pull stays on as the
-    #: liveness fallback in every mode — the watch only *adds* latency
-    #: headroom and suppresses redundant dedicated pulls while healthy.
-    epoch_discovery: str = "auto"
-    #: max wait for survivors at the rescale barrier; on timeout we proceed
-    #: (the checkpoint is already durable, latecomers restore from it).
-    rescale_barrier_timeout: float = 60.0
-    batch_axis: str = "data"
-    #: optional per-step hook (step, state) -> None — e.g. a
-    #: `runtime.export.PeriodicExporter` writing the serving artifact the
-    #: way the reference's trainer 0 does (`ctr/train.py:169-180`).
-    step_callback: Optional[Callable[[int, TrainState], None]] = None
-    #: multi-host mode: on a membership change, checkpoint durably and exit
-    #: the process with RESCALE_EXIT_CODE instead of rebuilding in-process.
-    #: jax.distributed's world size is fixed at initialize, so a multi-host
-    #: worker must restart to join the new world; the pod launcher
-    #: (launcher.launch.start_trainer) relaunches the entry, which re-runs
-    #: distributed_init and restores from the checkpoint. Single-host jobs
-    #: (the default) re-slice local devices without restarting.
-    restart_on_rescale: bool = False
-    #: pipeline the data path: the next shard loads on a background thread
-    #: while the current shard's batches feed training (costs one extra held
-    #: lease + up to two shards of host RAM). See LeaseReader.
-    prefetch: bool = False
-    #: device-side input pipelining: > 0 runs wire encode + H2D batch
-    #: placement on a pump thread (`runtime.pipeline.DevicePrefetcher`),
-    #: up to this many placed batches ahead of step dispatch. 0 places
-    #: synchronously. The lease RPCs move to the pump thread with the
-    #: reader; CoordinatorClient serializes per-request, so heartbeats and
-    #: checkpoint commits on the main thread interleave safely.
-    pipeline_depth: int = 2
-    #: AOT-compile the step for the new mesh on a background thread during
-    #: the rescale restore window, so the first post-rescale step dispatches
-    #: a ready executable instead of paying XLA inside the recovery budget.
-    warm_compile: bool = True
-    #: coordinator-outage budget, seconds: while the coordinator is
-    #: unreachable the worker keeps stepping batches already leased (the
-    #: compute never depended on the control plane) and buffers
-    #: completions in an outbox; past this budget it checkpoints durably
-    #: and parks, polling for the coordinator's return. See
-    #: doc/robustness.md for the full failure model.
-    outage_budget: float = 60.0
-    #: fault-tolerance policy mode: ``adaptive`` sizes the park decision
-    #: per incident from live outage statistics and measured recovery
-    #: costs (`runtime.ft_policy`); ``static`` pins it to the fixed
-    #: ``outage_budget`` threshold above — the pre-policy semantics.
-    policy: str = "adaptive"
-    #: full policy knobs; None derives FTPolicyConfig(policy=policy,
-    #: outage_budget=outage_budget) with the documented defaults.
-    ft_policy: Optional[FTPolicyConfig] = None
-    #: serve ``/metrics`` + ``/healthz`` + ``/spans`` from this worker
-    #: process on the given port (0 = ephemeral); None disables. The
-    #: endpoint also bridges the coordinator's status counters, so one
-    #: scrape of any worker sees control plane and data plane together.
-    metrics_port: Optional[int] = None
-    #: memory-resident checkpoint plane (``edl_tpu.ckpt_plane``): > 0
-    #: replicates each worker's ZeRO-1 state shard to this many ring peers
-    #: through the coordinator at every checkpoint, and restores assemble
-    #: from peers in memory (zero blob reads) with the blob store as the
-    #: group-death fallback. 0 (the default) disables the plane entirely —
-    #: restores read the blob store exactly as before.
-    peer_replicas: int = 0
-    #: persistent AOT compile cache directory (``runtime.compile_cache``):
-    #: non-empty stores every warm-compiled step executable on disk keyed by
-    #: (topology, program, avals, code fingerprint), so revisiting a layout
-    #: — including after a RESCALE_EXIT_CODE restart — costs zero compiles.
-    #: "" (the default) disables persistence; warm-compile behaves as before.
-    compile_cache_dir: str = ""
-    trainer: TrainerConfig = field(default_factory=TrainerConfig)
-
-    def __post_init__(self) -> None:
-        # Fail at construction, not an hour into the job: a negative
-        # outage_budget silently turned every blip into a park, a negative
-        # heartbeat interval spins the beat loop hot — both were accepted
-        # without complaint before this check.
-        if self.heartbeat_interval < 0:
-            raise ValueError(
-                f"ElasticConfig.heartbeat_interval must be >= 0 seconds "
-                f"(0 beats every loop iteration), got {self.heartbeat_interval!r}")
-        if not 0.0 <= self.heartbeat_jitter <= 1.0:
-            raise ValueError(
-                f"ElasticConfig.heartbeat_jitter is a ± fraction of the "
-                f"interval and must be in [0, 1], got {self.heartbeat_jitter!r}")
-        if self.outage_budget <= 0:
-            raise ValueError(
-                f"ElasticConfig.outage_budget must be > 0 seconds (it is "
-                f"the park threshold ceiling), got {self.outage_budget!r}")
-        if self.rescale_barrier_timeout <= 0:
-            raise ValueError(
-                f"ElasticConfig.rescale_barrier_timeout must be > 0 "
-                f"seconds, got {self.rescale_barrier_timeout!r}")
-        if self.checkpoint_interval < 1:
-            raise ValueError(
-                f"ElasticConfig.checkpoint_interval must be >= 1 step, "
-                f"got {self.checkpoint_interval!r}")
-        if self.pipeline_depth < 0:
-            raise ValueError(
-                f"ElasticConfig.pipeline_depth must be >= 0 "
-                f"(0 places synchronously), got {self.pipeline_depth!r}")
-        if self.policy not in ("adaptive", "static"):
-            raise ValueError(
-                f"ElasticConfig.policy must be 'adaptive' or 'static', "
-                f"got {self.policy!r}")
-        if self.epoch_discovery not in ("watch", "pull", "auto"):
-            raise ValueError(
-                f"ElasticConfig.epoch_discovery must be 'watch', 'pull' or "
-                f"'auto', got {self.epoch_discovery!r}")
-        if self.peer_replicas < 0:
-            raise ValueError(
-                f"ElasticConfig.peer_replicas must be >= 0 "
-                f"(0 disables the checkpoint plane), got "
-                f"{self.peer_replicas!r}")
 
 
 def default_device_planner(chips_per_trainer: int) -> Callable[[int], Sequence[jax.Device]]:
@@ -197,20 +65,6 @@ def default_device_planner(chips_per_trainer: int) -> Callable[[int], Sequence[j
         return devs[:want]
 
     return plan
-
-
-def heartbeat_schedule(worker: str, base: float, jitter: float,
-                       n: int) -> List[float]:
-    """First ``n`` heartbeat intervals for ``worker``: ``base`` ± ``jitter``
-    fraction, drawn from an RNG seeded by the worker's name. This is the
-    exact sequence ElasticWorker/MultiHostWorker sleep between beats —
-    deterministic per name (str seeds hash stably in ``random.Random``),
-    different across names, so a fleet de-correlates without coordination.
-    Exposed for tests and capacity planning.
-    """
-    rng = random.Random(f"edl-hb:{worker}")  # edl: noqa[EDL008] heartbeat jitter, not training state — per-worker decorrelation is the point
-    return [max(0.0, base * (1.0 + jitter * (2.0 * rng.random() - 1.0)))
-            for _ in range(n)]
 
 
 @dataclass
@@ -232,8 +86,10 @@ class RescaleEvent:
     layout: Dict[str, int] = field(default_factory=dict)
 
 
-class ElasticWorker:
-    """One trainer process's elastic loop."""
+class ElasticWorker(WorkerBase):
+    """One trainer process's elastic loop: independent shard leases, and a
+    membership change rebuilds the mesh in this process. What it shares with
+    `MultiHostWorker` is `WorkerBase`'s."""
 
     def __init__(
         self,
@@ -248,110 +104,21 @@ class ElasticWorker:
         layout_planner: Optional[
             Callable[[int, Sequence[jax.Device]], Optional[Plan]]] = None,
     ):
-        if not config.checkpoint_dir:
-            raise ValueError("ElasticConfig.checkpoint_dir is required")
-        self.model = model
-        #: degraded-mode facade: mutations buffer during a coordinator
-        #: outage and replay idempotently on reconnect; reads fail soft.
-        self.client = client if isinstance(client, OutboxClient) \
-            else OutboxClient(client)
-        self.source = source
-        self.config = config
+        super().__init__(model, client, source, config, mesh_axes=mesh_axes,
+                         profiler=profiler, tracer=tracer,
+                         layout_planner=layout_planner)
         self.planner = device_planner or default_device_planner(4)
-        self.mesh_axes = mesh_axes  # extra non-data axes, sized per full mesh
-        #: hybrid-parallel replanner: ``(n_chips, devices) -> Plan | None``
-        #: (typically ``parallel.planner.plan_layout`` closed over a Topology
-        #: + ModelProfile). Called at every rescale; a returned Plan's mesh
-        #: axes and batch axis replace the static data-only resize, a None
-        #: falls back to it. Mutually exclusive with ``mesh_axes`` — the
-        #: plan owns the whole layout.
-        self.layout_planner = layout_planner
-        if layout_planner is not None and mesh_axes:
-            raise ValueError(
-                "pass either mesh_axes (static layout) or layout_planner "
-                "(searched layout), not both")
-        #: the Plan adopted at the last mesh build (None on the data-only
-        #: path) — replan-span attribution and `edl-tpu status` style debugging.
-        self.last_plan: Optional[Plan] = None
-        #: persistent AOT executable store shared by every Trainer this
-        #: worker builds across rescales (None when disabled).
-        if config.compile_cache_dir:
-            from edl_tpu.runtime.compile_cache import CompileCache
-
-            self.compile_cache: Optional[CompileCache] = CompileCache(
-                config.compile_cache_dir)
-        else:
-            self.compile_cache = None
-        self.profiler = profiler
-        #: rescale lifecycle spans land here (shared process tracer unless a
-        #: test/bench passes its own); correlated cross-process via the
-        #: membership epoch (obs.tracing.rescale_trace_id).
-        self.tracer = tracer if tracer is not None else get_tracer()
-        self.obs = WorkerInstruments()
-        #: per-incident recovery-mode selector (doc/robustness.md, policy
-        #: layer): replaces the fixed outage_budget comparison with a
-        #: threshold computed from the live outage distribution and
-        #: measured checkpoint/restore/re-step costs. ``policy="static"``
-        #: pins it back to the old semantics.
-        self.policy = FTPolicy(
-            config.ft_policy if config.ft_policy is not None
-            else FTPolicyConfig(policy=config.policy,
-                                outage_budget=config.outage_budget),
-            worker=self.client.worker,
-            tracer=self.tracer,
-        )
         #: transport retry policy at construction — the regime baseline the
         #: storm deadline override is computed from and restored to.
         self._default_retry = None
-        self.client.on_outage_close = self._on_outage_close
-        self.ckpt = Checkpointer(config.checkpoint_dir)
-        #: memory-resident checkpoint plane (None when disabled): peer-
-        #: replicated ZeRO shards pushed at every checkpoint, assembled in
-        #: memory on restore, blob store as the group-death fallback.
-        if config.peer_replicas > 0:
-            from edl_tpu.ckpt_plane import CkptPlane
-
-            self.ckpt_plane: Optional[CkptPlane] = CkptPlane(
-                self.client, replicas=config.peer_replicas,
-                tracer=self.tracer)
-        else:
-            self.ckpt_plane = None
-        #: what the last _restore_or_init was served from — the restore
-        #: span's source/bytes attribution (peer | blob | init).
-        self._last_restore: Dict = {"source": "init", "bytes": 0}
         self.rescales: List[RescaleEvent] = []
-        self.steps_done = 0
-        self.losses: List[float] = []
-        self._epoch = -1
         self._world = 0
         self._prev_world = 0
         self._rank = -1
         self._last_heartbeat = 0.0
-        #: per-worker seeded jitter stream (satellite of the control-plane
-        #: scale work): each beat draws its own interval so the fleet's
-        #: heartbeats de-correlate instead of arriving in phase-locked waves.
-        self._hb_rng = random.Random(f"edl-hb:{self.client.worker}")  # edl: noqa[EDL008] control-plane timing jitter, never touches model/optimizer state
-        self._hb_interval = self._next_hb_interval()
-        #: heartbeats satisfied from a piggybacked membership observation
-        #: (no dedicated RPC issued).
-        self.hb_coalesced = 0
-        # Piggyback heartbeats onto in-flight calls when the transport
-        # supports it: lease/kv traffic then refreshes our TTL for free and
-        # most dedicated beats coalesce away entirely.
-        raw = getattr(self.client, "client", self.client)
-        if getattr(raw, "piggyback_heartbeat", None) == 0.0:
-            raw.piggyback_heartbeat = config.heartbeat_interval
-        #: push-based epoch discovery: a watch subscription on the raw
-        #: transport (None when epoch_discovery='pull' or the transport
-        #: supports neither flavor). Pull stays the liveness fallback.
-        self._watch = make_epoch_watch(self.client, config.epoch_discovery)
-        if config.epoch_discovery == "watch" and self._watch is None:
-            raise ValueError(
-                "epoch_discovery='watch' but the transport exposes neither "
-                "a wire endpoint nor a call surface to subscribe on")
-        #: dedicated pull rounds skipped because a healthy watch already
-        #: covered epoch discovery (mirrors the metric family).
-        self.pulls_suppressed = 0
+        #: the current beat's interval, redrawn from the jitter stream at
+        #: every beat.
+        self._hb_interval = self._jittered(config.heartbeat_interval)
         #: True between observing the coordinator unreachable and the next
         #: successful control-plane call — gates benign epoch adoption.
         self._outage_open = False
@@ -359,8 +126,6 @@ class ElasticWorker:
         #: span's start (signal -> step loop quiesced), 0.0 when no signal
         #: is pending.
         self._drain_signal_t = 0.0
-        #: preemption sensor suite (notices, notice-to-drained, evictions).
-        self.preempt_obs = PreemptInstruments()
         #: advance-notice revocation addressed to THIS worker, consumed
         #: from the watch stream and awaiting its drain: the notice dict
         #: (worker/notice_s/reason/seq/arrival/deadline) plus the policy's
@@ -380,21 +145,14 @@ class ElasticWorker:
         self._carry_consumed: List[str] = []
         #: per-pass step counts (multi-pass training; key = pass index).
         self.pass_steps: Dict[int, int] = {}
-        #: host-batch avals (shape/dtype) observed at first placement —
-        #: what rescale warm-compile specializes the new mesh's step
-        #: against. Written once from whichever thread places first.
-        self._batch_avals: Optional[Dict[str, jax.ShapeDtypeStruct]] = None
 
     # -- fault-tolerance policy plumbing ----------------------------------------
 
     def _on_outage_close(self, duration: float) -> None:
-        """OutboxClient callback: one outage incident ended. Feeds the
-        per-incident duration (the histogram the running-total gauge loses)
-        and the policy's history, then re-applies the regime's transport
-        deadline. Runs on whichever thread's guarded call observed
-        recovery — everything here is thread-safe and cheap."""
-        self.obs.outage_duration.observe(duration)
-        self.policy.note_outage_closed(duration)
+        """One outage incident ended (`WorkerBase._on_outage_close`): also
+        re-apply the regime's transport deadline and publish the policy's
+        state."""
+        super()._on_outage_close(duration)
         self._apply_retry_deadline()
         self._publish_policy_state()
 
@@ -428,19 +186,9 @@ class ElasticWorker:
     # -- membership ------------------------------------------------------------
 
     def _adopt(self, info: Dict) -> None:
-        self._epoch = info["epoch"]
         self._world = max(1, info["world"])
         self._rank = int(info.get("rank", -1))
-        if self._watch is not None \
-                and int(self._epoch) > self._watch.last_epoch:
-            # Prime the resume cursor: epochs adopted via register/pull must
-            # not replay as notifications on the next (re)subscribe.
-            self._watch.last_epoch = int(self._epoch)
-        self.obs.note_epoch(self._epoch)
-        if self.ckpt_plane is not None:
-            # New epoch = new rank numbering: publish the epoch's replica-
-            # placement map and invalidate the previous epoch's key.
-            self.ckpt_plane.on_epoch(self._epoch, self._world, self._rank)
+        self._adopt_epoch(info["epoch"], self._world, self._rank)
 
     def _sync_membership(self) -> None:
         # run() entry = incarnation boundary: a predecessor's leases (same
@@ -480,22 +228,7 @@ class ElasticWorker:
                             reply.get("error", "unreachable"))
             # Jittered: a coordinator restart otherwise gets the whole
             # parked fleet re-registering in phase-locked waves.
-            base = min(1.0, max(0.1, self.config.heartbeat_interval))
-            time.sleep(max(0.05, base * (1.0 + self.config.heartbeat_jitter
-                                         * (2.0 * self._hb_rng.random() - 1.0))))
-
-    def _next_hb_interval(self) -> float:
-        return max(0.0, self.config.heartbeat_interval
-                   * (1.0 + self.config.heartbeat_jitter
-                      * (2.0 * self._hb_rng.random() - 1.0)))
-
-    def _poll_pause(self, base: float = 0.2) -> None:
-        """Idle-poll sleep from the seeded per-worker jitter stream: a
-        fleet draining the same queue (or the same outage) would otherwise
-        re-poll the coordinator in phase-locked waves — the identical
-        hazard the heartbeat jitter exists for."""
-        time.sleep(max(0.05, base * (1.0 + self.config.heartbeat_jitter
-                                     * (2.0 * self._hb_rng.random() - 1.0))))
+            self._outage_pause()
 
     def _signal_drain(self) -> bool:
         """Mark the instant the interrupt decision was made (the drain
@@ -505,55 +238,34 @@ class ElasticWorker:
             self._drain_signal_t = time.time()
         return True
 
-    #: coalesce-window stretch while the watch is healthy: dedicated pulls
-    #: drop to 1/stretch cadence because discovery rides the push stream.
-    _WATCH_PULL_STRETCH = 3.0
 
     def _consume_watch(self) -> bool:
-        """Drain pushed epoch notifications (non-blocking) and report
-        whether one names an epoch beyond ours. Arrival -> consumption
-        delay feeds `edl_worker_epoch_notify_latency_seconds`. A dead
-        subscription is not an error here: poll() re-subscribes with
-        bounded backoff and the pull cadence stays the liveness fallback.
-        """
-        now = time.monotonic()
-        moved = False
-        for epoch, arrived in self._watch.poll():
-            self.obs.note_epoch_notify(now - arrived)
-            if epoch > self._epoch:
+        """Drain the watch (`WorkerBase._drain_watch`) and report whether
+        the step loop should interrupt mid-shard: an epoch beyond ours, or
+        a preempt notice whose budget is tight."""
+        moved, notices = self._drain_watch()
+        for notice in notices:
+            if self._handle_preempt(notice):
                 moved = True
-        take = getattr(self._watch, "take_preempts", None)
-        if callable(take):
-            for notice in take():
-                if self._handle_preempt(notice):
-                    moved = True
         return moved
 
     def _handle_preempt(self, notice: Dict) -> bool:
-        """One revocation notice addressed to this worker: run the policy's
-        notice-budget decision and report whether the step loop should
-        interrupt mid-shard. ``ride_out`` keeps stepping — the notice was
-        too short for even a checkpoint to pay off. ``drain_shrink`` (ample
-        budget) drains at the next SHARD boundary via the soft latch:
-        the in-flight shard finishes and completes, so NOTHING replays on
-        the survivors. ``park`` (tight budget) interrupts mid-shard — the
-        in-flight lease fails back (at-least-once replay accepted) to buy
-        checkpoint time before the deadline."""
-        now_mono = time.monotonic()
-        remaining = notice["deadline"] - now_mono
-        self.preempt_obs.notices.inc(reason=notice.get("reason", "preempt"))
-        self.preempt_obs.notice_remaining.set(remaining)
-        mode = self.policy.on_preempt_notice(remaining)
-        log.warning(
-            "preempt notice: %.1fs remaining (reason=%s seq=%s) -> %s",
-            remaining, notice.get("reason"), notice.get("seq"), mode)
+        """Act on the policy's verdict for one revocation notice
+        (`WorkerBase._decide_preempt`) and report whether the step loop
+        should interrupt mid-shard. ``ride_out`` keeps stepping.
+        ``drain_shrink`` (ample budget) drains at the next SHARD boundary
+        via the soft latch: the in-flight shard finishes and completes, so
+        NOTHING replays on the survivors. ``park`` (tight budget)
+        interrupts mid-shard — the in-flight lease fails back (at-least-
+        once replay accepted) to buy checkpoint time before the deadline."""
+        mode = self._decide_preempt(notice)
         if mode == RIDE_OUT:
             return False
         self._pending_preempt = {
             **notice, "mode": mode,
             # monotonic arrival -> wall clock, so the preempt_drain span
             # stitches onto the survivors' rescale timeline.
-            "wall_arrival": time.time() - (now_mono - notice["arrival"]),
+            "wall_arrival": time.time() - (time.monotonic() - notice["arrival"]),
         }
         if mode == DRAIN_SHRINK:
             self._soft_drain = True
@@ -612,21 +324,8 @@ class ElasticWorker:
             "notice (deadline %s, trigger=%s, steps_lost=0)",
             left_epoch, notice_to_drained, float(pd.get("notice_s", 0.0)),
             "met" if deadline_met else "MISSED", trigger)
-        outage = {f"outage_{k}": v for k, v in self.client.summary().items()}
-        outage["outage_parks"] = float(self.parks)
-        outage.update({f"policy_{m}": float(n)
-                       for m, n in self.policy.decisions.items()})
-        outage["policy_incidents"] = float(self.policy.incidents)
-        return {
-            **outage,
-            "steps": float(self.steps_done),
-            "final_loss": self.losses[-1] if self.losses else float("nan"),
-            "world": float(world),
-            "passes_trained": float(len(self.pass_steps)),
-            "rescales": float(len(self.rescales)),
-            "max_recovery_seconds": max(
-                (r.recovery_seconds for r in self.rescales), default=0.0),
-            "seconds": time.perf_counter() - t_start,
+        return self._summary(world, time.perf_counter() - t_start, {
+            **self._result_extra(),
             "preempted": 1.0,
             "preempt_mode_code": float(MODE_CODES[pd["mode"]]),
             "preempt_notice_s": float(pd.get("notice_s", 0.0)),
@@ -635,6 +334,16 @@ class ElasticWorker:
             # Every consumed shard was committed by the blocking checkpoint
             # above; the evacuated shards restore peer-side. Nothing replays.
             "steps_lost": 0.0,
+        })
+
+    def _result_extra(self) -> Dict[str, float]:
+        """This worker's own result keys, beside `WorkerBase._summary`'s."""
+        return {
+            "outage_parks": float(self.parks),
+            "passes_trained": float(len(self.pass_steps)),
+            "rescales": float(len(self.rescales)),
+            "max_recovery_seconds": max(
+                (r.recovery_seconds for r in self.rescales), default=0.0),
         }
 
     def _epoch_changed(self, force: bool = False) -> bool:
@@ -655,32 +364,14 @@ class ElasticWorker:
         if not force and now - self._last_heartbeat < self._hb_interval:
             return False
         self._last_heartbeat = now
-        self._hb_interval = self._next_hb_interval()
+        self._hb_interval = self._jittered(self.config.heartbeat_interval)
         # Coalesce: every coordinator reply carries the current epoch, and
         # membership-shaped replies (piggybacked heartbeats among them) are
         # recorded by the transport. A fresh observation — made within the
         # nominal interval, so the server-side TTL was refreshed then too —
         # answers this beat without a dedicated RPC.
-        lm = getattr(self.client, "last_membership", None)
-        lm_at = getattr(self.client, "last_membership_at", 0.0)
-        fresh_window = self.config.heartbeat_interval
-        if self._watch is not None and self._watch.connected:
-            # Watch healthy: epoch discovery rides the push stream, so the
-            # dedicated pull only backstops TTL refresh and liveness.
-            # Stretch the coalesce window (bounded — a fully idle transport
-            # still pulls at stretch x cadence, well inside the default TTL
-            # of ~10 intervals).
-            fresh_window *= self._WATCH_PULL_STRETCH
-        if not force and lm is not None and now - lm_at < fresh_window:
-            reply = dict(lm)
-            self.hb_coalesced += 1
-            self.obs.note_coalesced_heartbeat()
-            if now - lm_at >= self.config.heartbeat_interval:
-                # Only the stretched window made this round coalesce: a
-                # pull the watch genuinely suppressed.
-                self.pulls_suppressed += 1
-                self.obs.note_pull_suppressed()
-        else:
+        reply = None if force else self._coalesced_beat(now)
+        if reply is None:
             reply = self.obs.timed_heartbeat(self.client)
         self.obs.note_outage_state(self.client)
         if reply.get("unreachable"):
@@ -774,98 +465,7 @@ class ElasticWorker:
             return
         raise RuntimeError("rendezvous thrashed: membership never settled")
 
-    # -- mesh / state ----------------------------------------------------------
-
-    def _build_mesh(self, world: int) -> Mesh:
-        devices = list(self.planner(world))
-        self.last_plan = None
-        if self.layout_planner is not None:
-            plan = self.layout_planner(len(devices), devices)
-            if plan is not None:
-                self.last_plan = plan
-                spec = MeshSpec(dict(plan.mesh_axes))
-                if plan.hierarchical:
-                    # dcn outermost: the planner only emits a dcn axis when
-                    # the chips span slices, and the gradient psum over
-                    # ("dcn", "data") must lower to the hierarchical reduce.
-                    return build_hierarchical_mesh(spec, devices)
-                return build_mesh(spec, devices)
-        axes = dict(self.mesh_axes or {})
-        n = len(devices)
-        fixed = 1
-        for size in axes.values():
-            fixed *= size
-        if n % fixed != 0:
-            raise ValueError(f"{n} devices not divisible by fixed axes {axes}")
-        axes["data"] = n // fixed
-        return build_mesh(MeshSpec(axes), devices)
-
-    def _trainer_config(self) -> TrainerConfig:
-        """The trainer config for the CURRENT layout: a planned layout
-        re-points the batch axis (a hierarchical plan shards the batch over
-        ("dcn", "data")); the data-only path uses the static config as-is."""
-        if self.last_plan is None:
-            return self.config.trainer
-        if self.config.trainer.batch_axis == self.last_plan.batch_axis:
-            return self.config.trainer
-        return dataclasses.replace(
-            self.config.trainer, batch_axis=self.last_plan.batch_axis)
-
-    def _restore_or_init(
-        self, trainer: Trainer, fresh: Optional[TrainState] = None
-    ) -> TrainState:
-        if fresh is None:
-            fresh = trainer.init_state()
-        self._last_restore = {"source": "init", "bytes": 0}
-        blob_step = self.ckpt.latest_step()
-        if (self.ckpt_plane is not None
-                and self.policy.restore_source() == "peer"):
-            # Peer-first (the break-even above may demote to blob-first):
-            # assemble the state from the coordinator's memory-resident
-            # shards, re-sharded onto THIS mesh through the same spec
-            # machinery orbax uses. min_step pins the plane to at least the
-            # blob store's best — recovery never moves training backwards.
-            t0 = time.time()
-            got = self.ckpt_plane.restore(
-                fresh, trainer.mesh, live_state_specs(fresh),
-                min_step=blob_step,
-            )
-            if got is not None:
-                state, info = got
-                self.policy.note_peer_restore(time.time() - t0)
-                self._last_restore = {"source": "peer",
-                                      "bytes": int(info["bytes"])}
-                if "reshard_start" in info:
-                    # the device_put window peer_restore timed — the rescale
-                    # loop records it as the `reshard` phase.
-                    self._last_restore["reshard_start"] = info["reshard_start"]
-                    self._last_restore["reshard_end"] = info["reshard_end"]
-                log.info(
-                    "restored step=%s from %d peer shard(s) onto %d-device "
-                    "mesh (%d bytes in memory, zero blob reads)",
-                    info["step"], info["world_at_save"], trainer.mesh.size,
-                    info["bytes"])
-                return state
-        if blob_step is None:
-            return fresh
-        state = self.ckpt.restore(
-            abstract_like(fresh), trainer.mesh, live_state_specs(fresh)
-        )
-        self._last_restore = {"source": "blob", "bytes": 0}
-        if self.ckpt_plane is not None:
-            # The fallback rung actually taken — the restores-by-source
-            # audit is what proves a group death demoted cleanly.
-            self.ckpt_plane.obs.restores.inc(source="blob")
-        log.info("restored checkpoint step=%s onto %d-device mesh",
-                 self.ckpt.latest_step(), trainer.mesh.size)
-        return state
-
-    def _note_batch_avals(self, batch: Dict) -> None:
-        if self._batch_avals is None:
-            self._batch_avals = {
-                k: jax.ShapeDtypeStruct(v.shape, v.dtype)
-                for k, v in batch.items()
-            }
+    # -- state ----------------------------------------------------------------
 
     def _start_warm_compile(self, trainer: Trainer, fresh: TrainState,
                             trace_id: str = ""):
@@ -912,40 +512,6 @@ class ElasticWorker:
             return out["seconds"]
 
         return join
-
-    def _dispatched(self, reader: LeaseReader, trainer: Trainer):
-        """Yield ``(placed, step_fn, task, samples, place_seconds)`` per
-        batch, placement pipelined per ``config.pipeline_depth``.
-
-        The pump closure snapshots ``reader.current`` at placement time so
-        per-pass step attribution follows the batch, not whatever shard the
-        reader has moved on to by step time; ``place_bound`` snapshots the
-        step callable for the same reason (codec widening in flight).
-        ``place_seconds`` is the length of the batch's ``place`` span.
-        """
-        depth = self.config.pipeline_depth
-
-        def place(batch):
-            self._note_batch_avals(batch)
-            task = reader.current
-            with self.tracer.span("place", task=task) as span:
-                placed, step_fn = trainer.place_bound(batch)
-            return placed, step_fn, task, span
-
-        if depth <= 0:
-            for batch in reader:
-                samples = len(next(iter(batch.values())))
-                *payload, span = place(batch)
-                yield (*payload, samples, span.seconds)
-            return
-        from edl_tpu.runtime.pipeline import DevicePrefetcher
-
-        with DevicePrefetcher(
-            reader, place, depth=depth, thread_name="edl-elastic-place-pump"
-        ) as pf:
-            for item in pf:
-                *payload, span = item.payload
-                yield (*payload, item.samples, span.seconds)
 
     def _checkpoint(self, state: TrainState, block: bool = False) -> None:
         self.ckpt.save(int(state.step), state)
@@ -1068,7 +634,7 @@ class ElasticWorker:
             # either way so every rescale timeline carries the phase and a
             # missing planner shows up as a ~0 s replan, not a missing one).
             t_replan0 = time.time()
-            mesh = self._build_mesh(world)
+            mesh = self._build_mesh(self.planner(world))
             replan_attrs: Dict = {"layout": json.dumps(dict(mesh.shape))}
             if self.last_plan is not None:
                 replan_attrs.update(
@@ -1083,17 +649,7 @@ class ElasticWorker:
             self.tracer.record("replan", t_replan0, time.time(),
                                trace_id=rid, component="worker",
                                **replan_attrs)
-            codec_channel = None
-            if self.config.trainer.wire_transport:
-                from edl_tpu.runtime.wire import KVCodecChannel
-
-                # Single-host worker (one process): in-place widening is safe,
-                # but persisting the widen floor through the coordinator means
-                # a restarted incarnation never re-learns an old overflow.
-                codec_channel = KVCodecChannel(self.client, self._epoch)
-            trainer = Trainer(self.model, mesh, self._trainer_config(),
-                              codec_channel=codec_channel,
-                              compile_cache=self.compile_cache)
+            trainer = self._make_trainer(mesh, self._epoch)
             if self.profiler is not None:
                 # The first step on a fresh mesh recompiles (20-40 s on TPU);
                 # keep it out of steady-state summaries.
@@ -1157,7 +713,9 @@ class ElasticWorker:
                 )
                 if self.profiler is not None:
                     self.profiler.start()
-                batches = self._dispatched(reader, trainer)
+                batches = self._dispatched(
+                    reader, trainer, lambda: reader.current,
+                    thread_name="edl-elastic-place-pump")
                 try:
                     while True:
                         with annotate_step(step + 1), self.tracer.span(
@@ -1171,25 +729,9 @@ class ElasticWorker:
                                 break
                             step += 1
                             placed, step_fn, task, samples, place_dt = item
-                            with self.tracer.span("step_dispatch",
-                                                  step=step) as dispatch:
-                                state, loss = step_fn(state, placed)
-                            with self.tracer.span("loss_sync",
-                                                  step=step) as sync:
-                                # Wait on the buffer's ready event, THEN
-                                # copy: `float()` on a loss still being
-                                # computed waits on the copy instead, and on
-                                # the chip that wait outlasted a finished
-                                # step by 0.8 to 4.7 s in a third of the
-                                # runs (PERF.md, PR 25).
-                                loss = float(jax.block_until_ready(loss))
-                            # Live re-step pricing: every completed step
-                            # feeds its wall seconds to the policy's EMA.
-                            self.policy.note_step(
-                                dispatch.seconds + sync.seconds)
-                            if self.profiler is not None:
-                                self.profiler.step(samples,
-                                                   place_seconds=place_dt)
+                            state, loss = self._step_once(
+                                state, placed, step_fn, step, samples,
+                                place_dt)
                             if not first_step_done:
                                 first_step_done = True
                                 recovery = time.perf_counter() - rescale_t0
@@ -1212,16 +754,10 @@ class ElasticWorker:
                                                     in mesh.shape.items()},
                                         )
                                     )
-                            self.steps_done += 1
-                            self.obs.steps.inc()
-                            self.losses.append(loss)
                             if task is not None:
                                 p = split_pass(task)[1]
                                 self.pass_steps[p] = self.pass_steps.get(p, 0) + 1
-                            if self.config.step_callback is not None:
-                                with self.tracer.span("step_callback",
-                                                      step=step):
-                                    self.config.step_callback(step, state)
+                            self._record_step(step, state, loss)
                             if step - last_ckpt_step >= self.config.checkpoint_interval:
                                 self._checkpoint_and_commit(state, reader, block=False)
                                 last_ckpt_step = step
@@ -1241,9 +777,7 @@ class ElasticWorker:
                     # published, and renegotiation needs a fresh membership
                     # epoch — which an in-process rebuild cannot produce (the
                     # jax.distributed world is fixed at initialize). Flush
-                    # durable state and take the gang warm-restart exit, the
-                    # same path a rescale takes, regardless of
-                    # restart_on_rescale.
+                    # durable state and take the gang warm-restart exit.
                     from edl_tpu.launcher.launch import RESCALE_EXIT_CODE
 
                     batches.close()  # stop the pump before counting its reads
@@ -1279,7 +813,7 @@ class ElasticWorker:
                     if self._carry_consumed or self._pending_commit:
                         self._checkpoint_and_commit(state, None, block=True)
                         last_ckpt_step = int(state.step)
-                    self._poll_pause()
+                    self._pause()
                     if self._epoch_changed(force=True):
                         rescale = True
                         drain_t0 = self._drain_signal_t or time.time()
@@ -1300,14 +834,6 @@ class ElasticWorker:
                     # replan and shrink under the epoch our leave bumps.
                     return self._finish_preempt_drain(
                         state, drain_t0, ck_t0, ck_t1, world, t_start)
-                if self.config.restart_on_rescale:
-                    from edl_tpu.launcher.launch import RESCALE_EXIT_CODE
-
-                    log.info(
-                        "membership epoch moved; exiting %d for a warm "
-                        "restart into the new world", RESCALE_EXIT_CODE,
-                    )
-                    raise SystemExit(RESCALE_EXIT_CODE)
                 self._prev_world = world
                 info = self.client.register(takeover=False)
                 if not info.get("ok"):  # refresh observed epoch/world
@@ -1328,29 +854,8 @@ class ElasticWorker:
                 if len(self.client.outbox):
                     self.client.replay()
                 if len(self.client.outbox):
-                    self._poll_pause()
-            total = time.perf_counter() - t_start
-            if self.profiler is not None:
-                prof = {f"profile_{k}": v for k, v in self.profiler.summary().items()}
-            else:
-                prof = {}
+                    self._pause()
             if self.pass_steps:
                 log.info("per-pass steps: %s", dict(sorted(self.pass_steps.items())))
-            outage = {f"outage_{k}": v for k, v in self.client.summary().items()}
-            outage["outage_parks"] = float(self.parks)
-            outage.update({f"policy_{m}": float(n)
-                           for m, n in self.policy.decisions.items()})
-            outage["policy_incidents"] = float(self.policy.incidents)
-            return {
-                **prof,
-                **outage,
-                "steps": float(self.steps_done),
-                "final_loss": self.losses[-1] if self.losses else float("nan"),
-                "world": float(self._world),
-                "passes_trained": float(len(self.pass_steps)),
-                "rescales": float(len(self.rescales)),
-                "max_recovery_seconds": max(
-                    (r.recovery_seconds for r in self.rescales), default=0.0
-                ),
-                "seconds": total,
-            }
+            return self._summary(self._world, time.perf_counter() - t_start,
+                                 self._result_extra())

@@ -38,27 +38,20 @@ lockstep):
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
-import random
 import time
 from typing import Dict, List, Optional
 
 import jax
-from jax.sharding import Mesh
 
 from edl_tpu.coordinator.client import CoordinatorAuthError, CoordinatorError
-from edl_tpu.coordinator.outbox import OutboxClient
-from edl_tpu.coordinator.watch import make_epoch_watch
 from edl_tpu.models.base import Model
-from edl_tpu.obs.instruments import PreemptInstruments, WorkerInstruments
-from edl_tpu.parallel import MeshSpec, build_hierarchical_mesh, build_mesh
-from edl_tpu.runtime.checkpoint import Checkpointer, abstract_like, live_state_specs
-from edl_tpu.runtime.elastic import ElasticConfig
-from edl_tpu.runtime.ft_policy import (
-    RIDE_OUT, WARM_RESTART, FTPolicy, FTPolicyConfig,
-)
-from edl_tpu.runtime.train_loop import Trainer, TrainState
+from edl_tpu.obs.tracing import Tracer
+from edl_tpu.runtime.ft_policy import RIDE_OUT, WARM_RESTART
+from edl_tpu.runtime.train_loop import TrainState
+from edl_tpu.runtime.worker_base import ElasticConfig, WorkerBase
 
 log = logging.getLogger("edl_tpu.runtime.multihost")
 
@@ -66,13 +59,20 @@ log = logging.getLogger("edl_tpu.runtime.multihost")
 ROUND_KEY = "edl/mh_round/{epoch}/{round}"
 
 
-class MultiHostWorker:
-    """One process's share of a lockstep multi-host elastic job.
+class MultiHostWorker(WorkerBase):
+    """One process's share of a lockstep multi-host elastic job. What it
+    shares with `ElasticWorker` is `WorkerBase`'s; the rounds are its own.
 
     Requires ``jax.distributed`` to be initialized first
     (`edl_tpu.runtime.distributed.distributed_init`); ranks here are
     ``jax.process_index()``, which distributed_init derived from the same
     coordinator registration this worker holds.
+
+    The policy's escalation terminal for a lockstep gang is the warm
+    restart (one process cannot park alone: peers would hang in the next
+    collective); the wait/reconnect half of the ladder is the base's. The
+    checkpoint plane is multi-controller here: each process replicates
+    exactly its own rank's ZeRO slice — the plane's owner set IS the gang.
 
     Sizing note: uncommitted leases are not renewed, so if a checkpoint
     interval takes longer than the coordinator's task-lease time (16 s
@@ -91,72 +91,11 @@ class MultiHostWorker:
         mesh_axes: Optional[Dict[str, int]] = None,
         profiler=None,
         layout_planner=None,  # (n_chips, devices) -> parallel.planner.Plan | None
+        tracer: Optional[Tracer] = None,
     ):
-        if not config.checkpoint_dir:
-            raise ValueError("ElasticConfig.checkpoint_dir is required")
-        self.model = model
-        # Degraded-mode facade: a coordinator outage buffers completions
-        # (rank 0's checkpoint commits) instead of killing the gang; the
-        # round machinery below holds the gang on the current round while
-        # the outage lasts, up to ``config.outage_budget``.
-        if not isinstance(client, OutboxClient):
-            client = OutboxClient(client)
-        self.client = client
-        self.source = source
-        self.config = config
-        self.mesh_axes = mesh_axes
-        #: hybrid-parallel replanner (same contract as ElasticWorker's):
-        #: every warm-restart incarnation re-plans for the world it finds,
-        #: so the gang converges on the same layout from the same inputs
-        #: (plan_layout is deterministic — no cross-rank agreement needed).
-        self.layout_planner = layout_planner
-        if layout_planner is not None and mesh_axes:
-            raise ValueError(
-                "pass either mesh_axes (static layout) or layout_planner "
-                "(searched layout), not both")
-        self.last_plan = None
-        #: persistent AOT executable store (None when disabled) — the warm
-        #: restart is exactly the revisit it amortizes: the relaunched
-        #: process lands on the executable its predecessor compiled.
-        if config.compile_cache_dir:
-            from edl_tpu.runtime.compile_cache import CompileCache
-
-            self.compile_cache = CompileCache(config.compile_cache_dir)
-        else:
-            self.compile_cache = None
-        self.profiler = profiler
-        #: same metric families as ElasticWorker — dashboards don't care
-        #: which worker flavor a pod runs.
-        self.obs = WorkerInstruments()
-        #: per-incident recovery selector. The escalation terminal for a
-        #: lockstep gang is the warm restart (one process cannot park
-        #: alone: peers would hang in the next collective); the wait/
-        #: reconnect half of the ladder is identical to ElasticWorker's.
-        self.policy = FTPolicy(
-            config.ft_policy if config.ft_policy is not None
-            else FTPolicyConfig(policy=config.policy,
-                                outage_budget=config.outage_budget),
-            worker=self.client.worker,
-        )
-
-        def _outage_closed(duration: float) -> None:
-            self.obs.outage_duration.observe(duration)
-            self.policy.note_outage_closed(duration)
-
-        self.client.on_outage_close = _outage_closed
-        self.ckpt = Checkpointer(config.checkpoint_dir)
-        #: memory-resident checkpoint plane (None when disabled). Multi-
-        #: controller layout: each process replicates exactly its own rank's
-        #: ZeRO slice — the plane's owner set IS the gang.
-        if config.peer_replicas > 0:
-            from edl_tpu.ckpt_plane import CkptPlane
-
-            self.ckpt_plane: Optional[CkptPlane] = CkptPlane(
-                self.client, replicas=config.peer_replicas)
-        else:
-            self.ckpt_plane = None
-        self.steps_done = 0
-        self.losses: List[float] = []
+        super().__init__(model, client, source, config, mesh_axes=mesh_axes,
+                         profiler=profiler, tracer=tracer,
+                         layout_planner=layout_planner)
         #: rank 0 only: shards consumed since the last durable checkpoint —
         #: their leases are held open until a checkpoint covers them.
         self._uncommitted: List[str] = []
@@ -172,50 +111,16 @@ class MultiHostWorker:
         #: delete a plan a straggler still needs (the round-plan GC race).
         self._plan_rounds: List[int] = []
         self._collective_hwm: int = -1
-        #: seeded per-worker jitter stream: heartbeat/backoff cadence draws
-        #: from it so a gang of 10k processes sharing one config template
-        #: de-correlates instead of hammering the coordinator in phase
-        #: (same scheme as ElasticWorker — see elastic.heartbeat_schedule).
-        self._hb_rng = random.Random(f"edl-hb:{self.client.worker}")  # edl: noqa[EDL008] control-plane timing jitter, never touches model/optimizer state
         self._next_hb = 0.0
-        #: heartbeats satisfied from a piggybacked membership observation.
-        self.hb_coalesced = 0
-        raw = getattr(self.client, "client", self.client)
-        if getattr(raw, "piggyback_heartbeat", None) == 0.0:
-            raw.piggyback_heartbeat = config.heartbeat_interval
-        #: push-based epoch discovery (same knob/semantics as ElasticWorker):
         #: a notified epoch move is latched and consumed at the next round
         #: boundary — a lockstep gang cannot react mid-collective.
-        self._watch = make_epoch_watch(self.client, config.epoch_discovery)
-        if config.epoch_discovery == "watch" and self._watch is None:
-            raise ValueError(
-                "epoch_discovery='watch' but the transport exposes neither "
-                "a wire endpoint nor a call surface to subscribe on")
-        self._epoch = -1
         self._watch_moved = False
         #: advance-notice revocation (spot reclaim / straggler eviction):
         #: a pushed preempt frame latches here and is consumed at the next
-        #: round boundary — same rule as epoch moves, a lockstep gang
-        #: cannot abandon a collective mid-flight.
-        self.preempt_obs = PreemptInstruments()
+        #: round boundary — same rule as epoch moves.
         self._preempt_notice: Optional[Dict] = None
-        #: dedicated pull rounds skipped because a healthy watch already
-        #: covered epoch discovery (mirrors the metric family).
-        self.pulls_suppressed = 0
 
     # -- plumbing --------------------------------------------------------------
-
-    def _jittered(self, base: float) -> float:
-        """``base`` ± config.heartbeat_jitter fraction, from the seeded
-        per-worker stream."""
-        j = getattr(self.config, "heartbeat_jitter", 0.0)
-        return max(0.0, base * (1.0 + j * (2.0 * self._hb_rng.random() - 1.0)))
-
-    def _hb_sleep(self) -> None:
-        """Outage/backoff pause at heartbeat cadence, jittered so retry
-        storms from a whole gang spread out instead of arriving in waves."""
-        time.sleep(self._jittered(
-            min(1.0, max(0.1, self.config.heartbeat_interval))))
 
     def _maybe_heartbeat(self) -> None:
         """Beat at the jittered heartbeat interval — not per poll iteration.
@@ -231,122 +136,35 @@ class MultiHostWorker:
         if now < self._next_hb:
             return
         self._next_hb = now + self._jittered(self.config.heartbeat_interval)
-        lm = getattr(self.client, "last_membership", None)
-        lm_at = getattr(self.client, "last_membership_at", 0.0)
-        fresh_window = self.config.heartbeat_interval
-        if self._watch is not None and self._watch.connected:
-            # Watch healthy: epoch discovery rides the push stream, so the
-            # dedicated pull only backstops TTL refresh and liveness
-            # (same stretch as ElasticWorker._WATCH_PULL_STRETCH).
-            fresh_window *= 3.0
-        if lm is not None and now - lm_at < fresh_window:
-            self.hb_coalesced += 1
-            self.obs.note_coalesced_heartbeat()
-            if now - lm_at >= self.config.heartbeat_interval:
-                self.pulls_suppressed += 1
-                self.obs.note_pull_suppressed()
+        if self._coalesced_beat(now) is not None:
             return
         self.obs.timed_heartbeat(self.client)  # fails soft under OutboxClient
         self.obs.note_outage_state(self.client)
 
     def _consume_watch(self) -> bool:
-        """Drain pushed epoch notifications and latch whether one names an
-        epoch beyond the adopted one. The latch (not the transient poll
-        result) is what round boundaries consult — a notification that
-        arrives mid-round must still trigger the restart decision at the
-        NEXT boundary check."""
-        if self._watch is None:
-            return self._watch_moved
-        now = time.monotonic()
-        for ep, arrived in self._watch.poll():
-            self.obs.note_epoch_notify(now - arrived)
-            if ep > self._epoch:
-                self._watch_moved = True
-        take = getattr(self._watch, "take_preempts", None)
-        if callable(take):
-            for notice in take():
-                self._handle_preempt(notice)
+        """Drain the watch (`WorkerBase._drain_watch`) and latch whether a
+        notification names an epoch beyond the adopted one. The latch (not
+        the transient poll result) is what round boundaries consult — a
+        notification that arrives mid-round must still trigger the restart
+        decision at the NEXT boundary check."""
+        moved, notices = self._drain_watch()
+        if moved:
+            self._watch_moved = True
+        for notice in notices:
+            self._handle_preempt(notice)
         return self._watch_moved
 
     def _handle_preempt(self, notice: Dict) -> None:
-        """Run the notice-budget decision and latch non-ride-out verdicts
-        for the next round boundary. The latch keeps the EARLIEST deadline
-        if notices stack (a re-pushed notice never extends the first)."""
-        remaining = notice["deadline"] - time.monotonic()
-        self.preempt_obs.notices.inc(reason=notice.get("reason", "preempt"))
-        self.preempt_obs.notice_remaining.set(remaining)
-        mode = self.policy.on_preempt_notice(remaining)
-        log.warning(
-            "preempt notice: %.1fs remaining (reason=%s seq=%s) -> %s",
-            remaining, notice.get("reason"), notice.get("seq"), mode)
+        """Latch the policy's non-ride-out verdicts
+        (`WorkerBase._decide_preempt`) for the next round boundary. The
+        latch keeps the EARLIEST deadline if notices stack (a re-pushed
+        notice never extends the first)."""
+        mode = self._decide_preempt(notice)
         if mode == RIDE_OUT:
             return
         if self._preempt_notice is None or \
                 notice["deadline"] < self._preempt_notice["deadline"]:
             self._preempt_notice = {**notice, "mode": mode}
-
-    def _build_mesh(self) -> Mesh:
-        devices = jax.devices()  # global: every process's chips
-        self.last_plan = None
-        if self.layout_planner is not None:
-            plan = self.layout_planner(len(devices), devices)
-            if plan is not None:
-                self.last_plan = plan
-                spec = MeshSpec(dict(plan.mesh_axes))
-                if plan.hierarchical:
-                    return build_hierarchical_mesh(spec, devices)
-                return build_mesh(spec, devices)
-        axes = dict(self.mesh_axes or {})
-        fixed = 1
-        for size in axes.values():
-            fixed *= size
-        if len(devices) % fixed != 0:
-            raise ValueError(
-                f"{len(devices)} devices not divisible by fixed axes {axes}"
-            )
-        axes["data"] = len(devices) // fixed
-        return build_mesh(MeshSpec(axes), devices)
-
-    def _trainer_config(self):
-        """Trainer config for the current layout (planned layouts re-point
-        the batch axis; see ElasticWorker._trainer_config)."""
-        if (self.last_plan is None
-                or self.config.trainer.batch_axis == self.last_plan.batch_axis):
-            return self.config.trainer
-        import dataclasses
-
-        return dataclasses.replace(
-            self.config.trainer, batch_axis=self.last_plan.batch_axis)
-
-    def _restore_or_init(self, trainer: Trainer) -> TrainState:
-        fresh = trainer.init_state()
-        blob_step = self.ckpt.latest_step()
-        if (self.ckpt_plane is not None
-                and self.policy.restore_source() == "peer"):
-            t0 = time.monotonic()
-            got = self.ckpt_plane.restore(
-                fresh, trainer.mesh, live_state_specs(fresh),
-                min_step=blob_step,
-            )
-            if got is not None:
-                state, info = got
-                self.policy.note_peer_restore(time.monotonic() - t0)
-                log.info(
-                    "restored step=%s from %d peer shard(s) onto %d-process "
-                    "mesh (%d bytes in memory, zero blob reads)",
-                    info["step"], info["world_at_save"], jax.process_count(),
-                    info["bytes"])
-                return state
-        if blob_step is None:
-            return fresh
-        state = self.ckpt.restore(
-            abstract_like(fresh), trainer.mesh, live_state_specs(fresh)
-        )
-        if self.ckpt_plane is not None:
-            self.ckpt_plane.obs.restores.inc(source="blob")
-        log.info("restored step=%s onto %d-process mesh",
-                 self.ckpt.latest_step(), jax.process_count())
-        return state
 
     def _exit_for_restart(self) -> None:
         """No save here: a collective orbax save hangs if any peer is gone,
@@ -386,7 +204,7 @@ class MultiHostWorker:
                     "gang restart", self.client.outage_seconds(),
                     self.policy.frozen_threshold)
                 return {"stop": "rescale"}
-            self._hb_sleep()
+            self._outage_pause()
             hb = self.client.heartbeat()
         if not hb.get("ok"):
             hb = self.client.register()
@@ -495,7 +313,7 @@ class MultiHostWorker:
                         "%.1fs; assuming rescale", rnd,
                         self.policy.frozen_threshold)
                     return {"stop": "rescale"}
-                self._hb_sleep()
+                self._outage_pause()
                 continue
             if down_since is not None:
                 # kv_get is a passthrough (no outbox accounting), so close
@@ -660,40 +478,25 @@ class MultiHostWorker:
                                           escalate_mode=WARM_RESTART)
                     == WARM_RESTART):
                 self._exit_for_restart()
-            self._hb_sleep()
+            self._outage_pause()
             info = self.client.register(takeover=True)
         epoch = int(info["epoch"])
-        self._epoch = epoch
-        self.obs.note_epoch(epoch)
+        self._adopt_epoch(epoch, world, rank)
         if self._watch is not None:
-            # Prime the resume cursor with the adopted epoch (it must not
-            # replay as a notification), then subscribe; failure is soft —
-            # poll() retries with backoff, the pull cadence covers the gap.
-            self._watch.last_epoch = max(self._watch.last_epoch, epoch)
+            # Subscribe once the cursor is primed; failure is soft — poll()
+            # retries with backoff, the pull cadence covers the gap.
             self._watch.subscribe()
-        if self.ckpt_plane is not None:
-            # Every rank publishes the identical epoch-scoped placement map
-            # (idempotent kv_put) and invalidates its previous epoch's key.
-            self.ckpt_plane.on_epoch(epoch, world, rank)
 
-        mesh = self._build_mesh()
-        codec_channel = None
-        if self.config.trainer.wire_transport:
-            from edl_tpu.runtime.wire import KVCodecChannel
-
-            # Epoch-scoped: a rescale's new incarnation renegotiates the
-            # codec from scratch (possibly under a new rank 0) while the
-            # widen floor persists across epochs.
-            codec_channel = KVCodecChannel(self.client, epoch)
-        trainer = Trainer(self.model, mesh, self._trainer_config(),
-                          codec_channel=codec_channel,
-                          compile_cache=self.compile_cache)
+        # global devices: every process's chips are in the one mesh
+        trainer = self._make_trainer(self._build_mesh(jax.devices()), epoch)
         if self.profiler is not None:
             self.profiler.mark_warmup()
         t_restore0 = time.monotonic()
         state = self._restore_or_init(trainer)
         self.policy.note_restore_cost(time.monotonic() - t_restore0)
-        last_ckpt_step = int(state.step)
+        #: the optimizer step, counted on the host like ElasticWorker's:
+        #: ``_step`` adds one a call, the same on every rank
+        step = last_ckpt_step = int(state.step)
         t_start = time.perf_counter()
 
         def checkpoint_and_commit() -> None:
@@ -708,7 +511,7 @@ class MultiHostWorker:
                 # Each process pushes its OWN rank's ZeRO slice — the plane
                 # covers the gang when every rank's put lands. Best-effort.
                 self.ckpt_plane.replicate(state, int(state.step), rank, world)
-            last_ckpt_step = int(state.step)
+            last_ckpt_step = step
             if rank == 0:
                 for t in self._uncommitted:
                     self.client.complete_task(t)
@@ -742,7 +545,7 @@ class MultiHostWorker:
                 # incarnation's lease has not expired yet): idle this round,
                 # jittered so a whole gang's wait-round re-polls don't land
                 # on the coordinator in phase-locked waves.
-                time.sleep(self._jittered(0.2))
+                self._pause()
                 continue
             if msg.get("ckpt"):
                 checkpoint_and_commit()
@@ -755,30 +558,17 @@ class MultiHostWorker:
             ran_steps = 0
 
             def _train_one(placed, step_fn, samples, place_dt) -> None:
-                nonlocal state, ran_steps
-                t0 = time.perf_counter()
-                state, loss = step_fn(state, placed)
-                # the wait for the device: on the ready event, then the
-                # copy (see ElasticWorker's `loss_sync`)
-                loss = float(jax.block_until_ready(loss))
-                # Live re-step pricing for the policy's park break-even:
-                # every completed step feeds its wall seconds to the EMA.
-                self.policy.note_step(time.perf_counter() - t0)
+                nonlocal state, step, ran_steps
+                step += 1
+                state, loss = self._step_once(
+                    state, placed, step_fn, step, samples, place_dt)
                 ran_steps += 1
-                self.steps_done += 1
-                self.obs.steps.inc()
-                self.losses.append(loss)
-                if self.profiler is not None:
-                    self.profiler.step(samples, place_seconds=place_dt)
-                if self.config.step_callback is not None:
-                    self.config.step_callback(int(state.step), state)
+                self._record_step(step, state, loss)
 
             from edl_tpu.runtime.data import prefetch_iter
-            from edl_tpu.runtime.pipeline import DevicePrefetcher
             from edl_tpu.runtime.wire import WireRestartRequired
 
             steps = msg.get("steps")
-            depth = self.config.pipeline_depth
             try:
                 if steps is None:
                     # No batch_count metadata: shards must align by construction.
@@ -787,33 +577,18 @@ class MultiHostWorker:
                     # Run exactly `steps` collective steps; cycle a shorter
                     # shard's batches so every rank stays in lockstep.
                     batches = self._padded_batches(shard, tasks, steps)
-                if depth > 0:
-                    # Placement pump: wire encode + local-slice assembly of
-                    # batch N+1 overlap the collective step N. The pump pulls
-                    # from the source itself, so it subsumes `prefetch`'s
-                    # read-ahead; exceptions — including a SystemExit from
-                    # the padded-batches fallback — relay to this thread.
-                    with DevicePrefetcher(
-                        batches, trainer.place_bound, depth=depth,
-                        thread_name="edl-mh-place-pump",
-                    ) as pf:
-                        for item in pf:
-                            placed, step_fn = item.payload
-                            _train_one(placed, step_fn,
-                                       item.samples, item.place_seconds)
-                else:
-                    if self.config.prefetch:
-                        # Batch-level read-ahead: shard decompression overlaps
-                        # the jitted step (exception-safe — a SystemExit from
-                        # the padded-batches fallback still reaches this
-                        # thread).
-                        batches = prefetch_iter(batches)
-                    for batch in batches:
-                        samples = len(next(iter(batch.values())))
-                        t0 = time.perf_counter()
-                        placed, step_fn = trainer.place_bound(batch)
-                        _train_one(placed, step_fn, samples,
-                                   time.perf_counter() - t0)
+                if self.config.pipeline_depth <= 0 and self.config.prefetch:
+                    # Batch-level read-ahead: shard decompression overlaps
+                    # the jitted step (exception-safe — a SystemExit from
+                    # the padded-batches fallback still reaches this
+                    # thread). The placement pump subsumes it: it pulls from
+                    # the source itself.
+                    batches = prefetch_iter(batches)
+                with contextlib.closing(self._dispatched(
+                        batches, trainer, lambda: shard,
+                        thread_name="edl-mh-place-pump")) as placed_batches:
+                    for placed, step_fn, _, samples, place_dt in placed_batches:
+                        _train_one(placed, step_fn, samples, place_dt)
             except WireRestartRequired as e:
                 # A batch overflowed the gang-negotiated wire codec; the
                 # widened floor is already published. Same recovery as a
@@ -848,7 +623,7 @@ class MultiHostWorker:
                         )
                         self._zero_seen.add(t)
                         self.client.fail_task(t)
-            if int(state.step) - last_ckpt_step >= self.config.checkpoint_interval:
+            if step - last_ckpt_step >= self.config.checkpoint_interval:
                 # Deterministic across ranks (lockstep step counter), so every
                 # process enters the collective save together.
                 checkpoint_and_commit()
@@ -870,27 +645,11 @@ class MultiHostWorker:
                 if self.client.heartbeat().get("ok"):
                     self.client.replay()
                 if len(self.client.outbox):
-                    time.sleep(self._jittered(0.2))
+                    self._pause()
             if len(self.client.outbox):
                 log.warning(
                     "exiting with %d completions still buffered (coordinator "
                     "unreachable); their leases will expire and replay",
                     len(self.client.outbox))
-        prof = (
-            {f"profile_{k}": v for k, v in self.profiler.summary().items()}
-            if self.profiler is not None
-            else {}
-        )
-        outage = {f"outage_{k}": v for k, v in self.client.summary().items()}
-        outage.update({f"policy_{m}": float(n)
-                       for m, n in self.policy.decisions.items()})
-        outage["policy_incidents"] = float(self.policy.incidents)
-        return {
-            **prof,
-            **outage,
-            "steps": float(self.steps_done),
-            "final_loss": self.losses[-1] if self.losses else float("nan"),
-            "world": float(world),
-            "rank": float(rank),
-            "seconds": time.perf_counter() - t_start,
-        }
+        return self._summary(world, time.perf_counter() - t_start,
+                             {"rank": float(rank)})

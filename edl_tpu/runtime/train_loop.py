@@ -151,6 +151,15 @@ def _make_optimizer(cfg: TrainerConfig) -> optax.GradientTransformation:
     return opt
 
 
+def loss_value(loss: jax.Array) -> float:
+    """A step's loss on the host: wait on the buffer's ready event, THEN
+    copy. `float()` on a loss still being computed waits on the copy
+    instead, and on the chip that wait outlasted a finished step by 0.8 to
+    4.7 s in a third of the runs (PERF.md, PR 25). The one way this
+    package reads a loss it has just dispatched."""
+    return float(jax.block_until_ready(loss))
+
+
 class Trainer:
     """Builds and owns the jitted train step for (model, mesh, config).
 
@@ -873,7 +882,7 @@ class Trainer:
             n += 1
             self.check_retrace(n)
             if on_step is not None:
-                on_step(n, float(loss))
+                on_step(n, loss_value(loss))
             if profiler is not None:
                 profiler.step(
                     batch_samples,

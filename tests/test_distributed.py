@@ -1,7 +1,6 @@
 """Distributed-identity derivation tests (hermetic, in-process coordinator)."""
 
 import threading
-import time
 
 import pytest
 
@@ -195,55 +194,6 @@ def test_launcher_relaunches_on_rescale_exit(tmp_path):
         assert marker.exists()  # first run happened, second run returned 0
         failed = server.client("probe").kv_get(FAILED_COUNT_KEY)
         assert not failed or int(failed) == 0
-
-
-def test_elastic_worker_exits_for_restart_on_rescale(tmp_path):
-    """restart_on_rescale: a membership change makes the worker checkpoint
-    durably and exit with RESCALE_EXIT_CODE instead of remeshing in-process."""
-    import numpy as np
-
-    from edl_tpu.launcher.launch import RESCALE_EXIT_CODE
-    from edl_tpu.models import fit_a_line
-    from edl_tpu.runtime import (
-        Checkpointer,
-        ElasticConfig,
-        ElasticWorker,
-        SyntheticShardSource,
-        shard_names,
-    )
-    from edl_tpu.runtime.train_loop import TrainerConfig
-
-    coord = InProcessCoordinator(task_lease_sec=60.0, heartbeat_ttl_sec=60.0)
-    admin = coord.client("admin")
-    admin.add_tasks(shard_names("fit", 50))  # plenty: queue never drains
-
-    worker_client = coord.client("trainer-0")
-    worker = ElasticWorker(
-        fit_a_line.MODEL,
-        worker_client,
-        SyntheticShardSource(fit_a_line.MODEL, batch_size=16, batches_per_shard=4),
-        ElasticConfig(
-            checkpoint_dir=str(tmp_path / "ck"),
-            checkpoint_interval=1000,  # only the rescale checkpoint happens
-            heartbeat_interval=0.0,
-            restart_on_rescale=True,
-            trainer=TrainerConfig(optimizer="sgd", learning_rate=0.05),
-        ),
-    )
-
-    def joiner():
-        while worker.steps_done < 3:
-            time.sleep(0.02)
-        coord.client("trainer-1").register()  # epoch bump
-
-    t = threading.Thread(target=joiner, daemon=True)
-    t.start()
-    with pytest.raises(SystemExit) as exc:
-        worker.run()
-    t.join(timeout=5)
-    assert exc.value.code == RESCALE_EXIT_CODE
-    # the pre-exit checkpoint is durable and restorable
-    assert Checkpointer(str(tmp_path / "ck")).latest_step() is not None
 
 
 def test_late_joiner_exits_cleanly_when_job_drained():
