@@ -35,11 +35,23 @@ Backward is the standard two-kernel flash recipe: forward also emits the
 per-row logsumexp ``L = m + log(den)``; backward recomputes ``P = exp(S -
 L)`` tile by tile (never storing it) with ``delta = rowsum(dO * O)`` folded
 in: dS = P * (dP - delta) * scale, dQ = dS K, dK = dS^T Q, dV = P^T dO.
+delta stays one XLA reduction over dO and O where they lie (computing it in
+`flash_bwd_dq`, which holds a query block's dO, was measured and cost that
+kernel more than the reduction takes: PERF.md, PR 30).
 
-Shapes follow the models' convention: q/k/v are (B, S, H, D). Tiles are
-multiples of 128 rows; unaligned sequence lengths pad up to the tile:
-padded KEY rows are masked by a valid-length compare; padded QUERY rows
-produce unobserved garbage and are sliced away.
+Shapes follow the models' convention, q/k/v are (B, S, H, D), and the
+kernels address those arrays as they lie in memory: viewed as (B, S, H*D)
+(a reshape, no operation), a block is (1, rows, W) with W the least common
+multiple of D and 128 lanes where that divides H*D, else all of H*D
+(`_lanes`). No transpose and no copy of q, k, v, o or a cotangent stands
+between `flash_attention`'s arguments and the `pallas_call`s. A block so
+holds g = W / D heads side by side on its lanes: one at D = 128 or 256, two
+at D = 64, every head where H*D is under 128 (the CPU tests). The grid is
+(B, H/g, blocks, spans) and a grid step walks its g heads in a static loop
+over one body, each head's operands read as its D lanes of the block (see
+"heads on lanes" below). Tiles are multiples of 128 rows; unaligned sequence
+lengths pad up to the tile: padded KEY rows are masked by a valid-length
+compare; padded QUERY rows produce unobserved garbage and are sliced away.
 
 Which engine runs the kernels follows the platform each program is LOWERED
 for (`_pallas_call`): lowered for a TPU — attached or merely described —
@@ -125,9 +137,11 @@ def _pad_seq(x: jax.Array, mult: int) -> jax.Array:
 #: the whole sequence is fetched once a head and not once a block of the
 #: owner, and leaves no grid step in the causal future: at S = 4096 spans of
 #: 1024, 2048 and 4096 rows took the forward 8.31, 7.81 and 6.33 ms (chip
-#: sweep, PR 26). 4096 rows of K and V, double-buffered, are 2 MiB of VMEM
-#: at D = 64 in bf16 and 16 MiB at D = 128 in f32; longer was not measured.
-_MAX_SPAN = 4096
+#: sweep, PR 26), and at (2, 8192, 32, 128) spans of 4096 and 8192 rows took
+#: the three kernels 13.05, 14.01, 15.81 and 10.47, 11.40, 13.80 ms (chip
+#: sweep, PR 30). 8192 rows of K and V, double-buffered, are 8 MiB of VMEM
+#: at 128 lanes a block in bf16 and 16 MiB in f32; longer was not measured.
+_MAX_SPAN = 8192
 
 
 def _span(rows: int, blk: int) -> int:
@@ -139,16 +153,17 @@ def _span(rows: int, blk: int) -> int:
     return blk * max(d for d in range(1, most + 1) if tiles % d == 0)
 
 
-def _params(blk_q, blk_k, span_q, span_k, head_dim):
+def _params(blk_q, blk_k, span_q, span_k, lanes, heads):
     """Compiler parameters of one kernel, from its extents. The scoped-VMEM
     request counts every block a step may hold (two operands on each side,
-    double-buffered, at four bytes an element) and eight f32 temporaries of
-    a tile, doubled for what the compiler adds; 32 MiB at least (v5e's
-    default scope is 16 of its 128) and 96 at most."""
-    blocks = 2 * 2 * 4 * head_dim * (blk_q + blk_k + span_q + span_k)
-    tiles = 8 * 4 * blk_q * blk_k
+    double-buffered, at four bytes an element, ``lanes`` wide: all the
+    block's ``heads``) and eight f32 temporaries of a tile for each of two
+    heads in flight, doubled for what the compiler adds; 32 MiB at least
+    (v5e's default scope is 16 of its 128) and 96 at most."""
+    blocks = 2 * 2 * 4 * lanes * (blk_q + blk_k + span_q + span_k)
+    tiles = min(heads, 2) * 8 * 4 * blk_q * blk_k
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=min(max(2 * (blocks + tiles), 32 * 2**20),
                              96 * 2**20))
 
@@ -163,7 +178,7 @@ def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
     no multiple of the tile). Every tile of a causal call is masked, those
     below the diagonal too: a loop of their own without the mask was
     measured and bought nothing (PERF.md, PR 26), the VPU is not the
-    limit."""
+    limit. The mask is the same for every head of a block."""
     if not (causal or ragged):
         return None
     k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, k_dim)
@@ -202,13 +217,44 @@ def _live_key_tiles(*, q_first, k_first, k_left, causal, blk_q, blk_k,
     return (jnp.clip(stop, 0, span_k) + (blk_k - 1)) // blk_k
 
 
+# -- heads on lanes --------------------------------------------------------------
+#
+# A block is W lanes of the (B, S, H*D) array: g = W / D heads side by side,
+# head h on lanes [h D, (h+1) D). A grid step walks them in a static loop
+# INSIDE the tile loop (a loop of tiles for each head left the scheduler one
+# chain at a time and cost the backward kernels 7 and 10%) and reads each
+# operand of head h as that slice of the block's lanes; at g = 1 the slice
+# is the block. The other way to meet a slab, measured on the chip at
+# (32, 1024, 16, 64) and not kept (PERF.md, PR 30): zero the other heads'
+# lanes of the step's own operand and contract over all W lanes, which is
+# exact and needs no lane shift, and keep of each W-wide product the head's
+# own rows or lanes. The MXU's time did not hold still as its 128-wide
+# passes suggest: `flash_fwd` took 1.99 ms a call that way and 1.71 with
+# slices, the backward kernels the same either way.
+
+
+def _lanes(heads: int, head_dim: int) -> int:
+    """Lanes of a block over a (B, S, heads * head_dim) array: the least
+    common multiple of ``head_dim`` and 128 where whole blocks of that
+    width tile the array, else the array's whole width."""
+    lanes = math.lcm(head_dim, 128)
+    return lanes if (heads * head_dim) % lanes == 0 else heads * head_dim
+
+
+def _heads(ref, head_dim):
+    """The lane slices of the heads a block holds, in order."""
+    return [slice(at, at + head_dim)
+            for at in range(0, ref.shape[2], head_dim)]
+
+
 # -- forward -------------------------------------------------------------------
 #
 # Per-query-row statistics (running max, denominator, logsumexp, delta)
-# cross HBM as ROWS: shape (BH, 1, Sq), block (1, 1, blk_q). Mosaic wants
-# a block's last two dims divisible by (8, 128) or equal to the array's;
-# (1, blk_q) over (1, Sq) is, (1, blk_q) over (BH, Sq) is not, and a
-# (blk_q, 1) column would pad every value to a 128-lane row in HBM.
+# cross HBM as ROWS: shape (B*H, 1, Sq), block (g, 1, blk_q), the block's
+# heads. Mosaic wants a block's last two dims divisible by (8, 128) or equal
+# to the array's; (1, blk_q) over (1, Sq) is, (1, blk_q) over (B*H, Sq) is
+# not, and a (blk_q, 1) column would pad every value to a 128-lane row in
+# HBM.
 #
 # The forward works on TRANSPOSED tiles, (blk_k, blk_q), and accumulates
 # O^T: the statistics of a query are then one lane of a dense (1, blk_q)
@@ -216,15 +262,18 @@ def _live_key_tiles(*, q_first, k_first, k_left, causal, blk_q, blk_k,
 # leaves as the row it is stored as. On (blk_q, blk_k) tiles each statistic
 # was a (blk_q, 128) array rewritten for every tile and each reduction a
 # lane reduction on the XLU per 8 rows per tile, which cost more than the
-# tile's matmuls (PERF.md, PR 26). O^T turns once a query block, at the end.
+# tile's matmuls (PERF.md, PR 26). O^T is (W, blk_q), head h on rows
+# [h D, (h+1) D), and turns once a query block, at the end, into the
+# block's (blk_q, W).
 
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
                 o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale, causal, k_len, blk_q, blk_k):
-    qi, si = pl.program_id(1), pl.program_id(2)
-    n_s = pl.num_programs(2)
+                *, scale, causal, k_len, blk_q, blk_k, head_dim):
+    qi, si = pl.program_id(2), pl.program_id(3)
+    n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
+    heads = _heads(q_ref, head_dim)
 
     @pl.when(si == 0)
     def _init():
@@ -235,31 +284,33 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
     q_first = qo_ref[0] + qi * blk_q  # global position of the block's row 0
     k_first = ko_ref[0] + si * span_k
     k_left = k_len - si * span_k
-    q = q_ref[0]  # (blk_q, D)
+    q = [q_ref[0, :, lanes] for lanes in heads]  # each (blk_q, D)
 
     def tile(j, _):
         at = pl.multiple_of(j * blk_k, blk_k)
-        k = k_ref[0, pl.ds(at, blk_k), :]  # (blk_k, D)
-        v = v_ref[0, pl.ds(at, blk_k), :]
-        s_t = _nt(k, q) * scale  # (blk_k, blk_q) f32
-        valid = _visible(s_t.shape, 0, diag=q_first - (k_first + at),
+        rows = pl.ds(at, blk_k)
+        valid = _visible((blk_k, blk_q), 0, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
                          ragged=k_len % blk_k != 0)
-        if valid is not None:
-            s_t = jnp.where(valid, s_t, _NEG_INF)
-        m_prev = m_ref[...]  # (1, blk_q)
-        m_new = jnp.maximum(m_prev, s_t.max(axis=0, keepdims=True))
-        # A query that has seen no key yet keeps its max ON the sentinel,
-        # where exp(s - m) of a masked score would be exp(0): shift such a
-        # query by 0, and every masked score gives exactly 0 without a
-        # second select.
-        p_t = jnp.exp(s_t - jnp.where(m_new > _NEG_INF, m_new, 0.0))
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p_t.sum(axis=0, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # V^T P^T: (D, blk_q)
-        m_ref[...] = m_new
+        for h, lanes in enumerate(heads):
+            k = k_ref[0, rows, lanes]  # (blk_k, D)
+            v = v_ref[0, rows, lanes]
+            s_t = _nt(k, q[h]) * scale  # (blk_k, blk_q) f32
+            if valid is not None:
+                s_t = jnp.where(valid, s_t, _NEG_INF)
+            m_prev = m_ref[h]  # (1, blk_q)
+            m_new = jnp.maximum(m_prev, s_t.max(axis=0, keepdims=True))
+            # A query that has seen no key yet keeps its max ON the
+            # sentinel, where exp(s - m) of a masked score would be exp(0):
+            # shift such a query by 0, and every masked score gives exactly
+            # 0 without a second select.
+            p_t = jnp.exp(s_t - jnp.where(m_new > _NEG_INF, m_new, 0.0))
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * alpha + p_t.sum(axis=0, keepdims=True)
+            acc_ref[lanes, :] = acc_ref[lanes, :] * alpha + jax.lax.dot_general(
+                v, p_t.astype(v.dtype), (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)  # V^T P^T: (D, blk_q)
+            m_ref[h] = m_new
 
     jax.lax.fori_loop(0, _live_key_tiles(
         q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
@@ -267,43 +318,65 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
 
     @pl.when(si == n_s - 1)
     def _emit():
-        l = l_ref[...]
-        # queries that saw no key (padding, a hop in the future): den 0 ->
-        # emit 0, lse at the sentinel
-        safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[...] / safe).T.astype(o_ref.dtype)
-        lse_ref[0] = jnp.where(l > 0, m_ref[...] + jnp.log(safe), _NEG_INF)
+        for h, lanes in enumerate(heads):
+            l = l_ref[h]
+            # queries that saw no key (padding, a hop in the future): den 0
+            # -> emit 0, lse at the sentinel
+            safe = jnp.where(l > 0, l, 1.0)
+            acc_ref[lanes, :] = acc_ref[lanes, :] / safe
+            lse_ref[h] = jnp.where(l > 0, m_ref[h] + jnp.log(safe), _NEG_INF)
+        o_ref[0] = acc_ref[...].T.astype(o_ref.dtype)
 
 
-def _fwd(q3, k3, v3, qo, ko, *, scale, causal, k_len, blk_q, blk_k,
+#: Grid axes of every call: batch row, block of lanes, block of the operand
+#: that OWNS the step (queries in `flash_fwd` and `flash_bwd_dq`, keys in
+#: `flash_bwd_dkv`), span of the operand that streams past it.
+_OWN, _SPAN = 2, 3
+
+
+def _slab(rows, lanes, axis):
+    """``rows`` x ``lanes`` of a (B, S, H*D) array, the rows by grid
+    ``axis``."""
+    return pl.BlockSpec((1, rows, lanes),
+                        lambda *at: (at[0], at[axis], at[1]))
+
+
+def _stat_rows(rows, heads, slabs, axis):
+    """The statistics rows of a slab's ``heads`` over (B*H, 1, S)."""
+    return pl.BlockSpec((heads, 1, rows),
+                        lambda *at: (at[0] * slabs + at[1], 0, at[axis]))
+
+
+def _fwd(q, k, v, qo, ko, *, scale, causal, k_len, blk_q, blk_k, head_dim,
          out_dtype):
-    """q3: (BH, Sq, D); k3/v3: (BH, Sk, D) -> (o3, lse (BH, 1, Sq) f32)."""
-    BH, Sq, D = q3.shape
-    span_k = _span(k3.shape[1], blk_k)
+    """q: (B, Sq, H*D); k/v: (B, Sk, H*D) -> (o, lse (B*H, 1, Sq) f32)."""
+    B, Sq, HD = q.shape
+    Sk = k.shape[1]
+    H = HD // head_dim
+    W = _lanes(H, head_dim)
+    g, slabs = W // head_dim, HD // W
+    span_k = _span(Sk, blk_k)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, s: (b, i, 0))
-    k_spec = pl.BlockSpec((1, span_k, D), lambda b, i, s: (b, s, 0))
+    q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
     return _pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          k_len=k_len, blk_q=blk_q, blk_k=blk_k),
+                          k_len=k_len, blk_q=blk_q, blk_k=blk_k,
+                          head_dim=head_dim),
         "flash_fwd",
-        grid=(BH, Sq // blk_q, k3.shape[1] // span_k),
+        grid=(B, slabs, Sq // blk_q, Sk // span_k),
         in_specs=[scalar, scalar, q_spec, k_spec, k_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, blk_q), lambda b, i, s: (b, 0, i)),
-        ],
+        out_specs=[q_spec, _stat_rows(blk_q, g, slabs, _OWN)],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), out_dtype),
-            jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Sq, HD), out_dtype),
+            jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((1, blk_q), jnp.float32),  # running max m
-            pltpu.VMEM((1, blk_q), jnp.float32),  # running denominator l
-            pltpu.VMEM((D, blk_q), jnp.float32),  # output accumulator, O^T
+            pltpu.VMEM((g, 1, blk_q), jnp.float32),  # running max m
+            pltpu.VMEM((g, 1, blk_q), jnp.float32),  # running denominator l
+            pltpu.VMEM((W, blk_q), jnp.float32),  # output accumulator, O^T
         ],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, D),
-    )(qo, ko, q3, k3, v3)
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g),
+    )(qo, ko, q, k, v)
 
 
 # -- backward ------------------------------------------------------------------
@@ -321,10 +394,11 @@ def _fwd(q3, k3, v3, qo, ko, *, scale, causal, k_len, blk_q, blk_k,
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, acc_ref,
-                   *, scale, causal, k_len, blk_q, blk_k):
-    qi, si = pl.program_id(1), pl.program_id(2)
-    n_s = pl.num_programs(2)
+                   *, scale, causal, k_len, blk_q, blk_k, head_dim):
+    qi, si = pl.program_id(2), pl.program_id(3)
+    n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
+    heads = _heads(q_ref, head_dim)
 
     @pl.when(si == 0)
     def _init():
@@ -333,24 +407,26 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
     q_first = qo_ref[0] + qi * blk_q
     k_first = ko_ref[0] + si * span_k
     k_left = k_len - si * span_k
-    q = q_ref[0]  # (blk_q, D)
-    do = do_ref[0]
-    lse = _column(lse_ref[0])  # (blk_q, 1)
-    delta = _column(delta_ref[0])
+    q = [q_ref[0, :, lanes] for lanes in heads]  # each (blk_q, D)
+    do = [do_ref[0, :, lanes] for lanes in heads]
+    lse = [_column(lse_ref[h]) for h in range(len(heads))]  # each (blk_q, 1)
+    delta = [_column(delta_ref[h]) for h in range(len(heads))]
 
     def tile(j, _):
         at = pl.multiple_of(j * blk_k, blk_k)
-        k = k_ref[0, pl.ds(at, blk_k), :]
-        v = v_ref[0, pl.ds(at, blk_k), :]
-        p = jnp.exp(_nt(q, k) * scale - lse)  # (blk_q, blk_k) f32
-        valid = _visible(p.shape, 1, diag=q_first - (k_first + at),
+        rows = pl.ds(at, blk_k)
+        valid = _visible((blk_q, blk_k), 1, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
                          ragged=k_len % blk_k != 0)
-        if valid is not None:
-            p = jnp.where(valid, p, 0.0)
-        ds = p * (_nt(do, v) - delta)
-        acc_ref[...] += jnp.dot(ds.astype(k.dtype), k,
-                                preferred_element_type=jnp.float32)  # dS K
+        for h, lanes in enumerate(heads):
+            k = k_ref[0, rows, lanes]  # (blk_k, D)
+            v = v_ref[0, rows, lanes]
+            p = jnp.exp(_nt(q[h], k) * scale - lse[h])  # (blk_q, blk_k) f32
+            if valid is not None:
+                p = jnp.where(valid, p, 0.0)
+            ds = p * (_nt(do[h], v) - delta[h])
+            acc_ref[h] += jnp.dot(ds.astype(k.dtype), k,
+                                  preferred_element_type=jnp.float32)  # dS K
 
     jax.lax.fori_loop(0, _live_key_tiles(
         q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
@@ -358,15 +434,17 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(si == n_s - 1)
     def _emit():
-        dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
+        for h, lanes in enumerate(heads):
+            dq_ref[0, :, lanes] = (acc_ref[h] * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, k_len, blk_q, blk_k):
-    ki, si = pl.program_id(1), pl.program_id(2)  # note: K outer, Q streams
-    n_s = pl.num_programs(2)
+                    *, scale, causal, k_len, blk_q, blk_k, head_dim):
+    ki, si = pl.program_id(2), pl.program_id(3)  # note: K outer, Q streams
+    n_s = pl.num_programs(3)
     span_q = q_ref.shape[1]
+    heads = _heads(k_ref, head_dim)
 
     @pl.when(si == 0)
     def _init():
@@ -375,26 +453,28 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
     q_first = qo_ref[0] + si * span_q
     k_first = ko_ref[0] + ki * blk_k
-    k = k_ref[0]  # (blk_k, D)
-    v = v_ref[0]
+    k = [k_ref[0, :, lanes] for lanes in heads]  # each (blk_k, D)
+    v = [v_ref[0, :, lanes] for lanes in heads]
 
     def tile(j, _):
         at = pl.multiple_of(j * blk_q, blk_q)
-        q = q_ref[0, pl.ds(at, blk_q), :]  # (blk_q, D)
-        do = do_ref[0, pl.ds(at, blk_q), :]
-        lse = lse_ref[0, :, pl.ds(at, blk_q)]  # (1, blk_q)
-        delta = delta_ref[0, :, pl.ds(at, blk_q)]
-        p_t = jnp.exp(_nt(k, q) * scale - lse)  # (blk_k, blk_q) f32
-        valid = _visible(p_t.shape, 0, diag=q_first + at - k_first,
+        rows = pl.ds(at, blk_q)
+        valid = _visible((blk_k, blk_q), 0, diag=q_first + at - k_first,
                          k_left=k_len - ki * blk_k, causal=causal,
                          ragged=k_len % blk_k != 0)
-        if valid is not None:
-            p_t = jnp.where(valid, p_t, 0.0)
-        ds_t = p_t * (_nt(v, do) - delta)
-        dv_acc[...] += jnp.dot(p_t.astype(do.dtype), do,
-                               preferred_element_type=jnp.float32)  # P^T dO
-        dk_acc[...] += jnp.dot(ds_t.astype(q.dtype), q,
-                               preferred_element_type=jnp.float32)  # dS^T Q
+        for h, lanes in enumerate(heads):
+            q = q_ref[0, rows, lanes]  # (blk_q, D)
+            do = do_ref[0, rows, lanes]
+            lse = lse_ref[h, :, rows]  # (1, blk_q)
+            delta = delta_ref[h, :, rows]
+            p_t = jnp.exp(_nt(k[h], q) * scale - lse)  # (blk_k, blk_q) f32
+            if valid is not None:
+                p_t = jnp.where(valid, p_t, 0.0)
+            ds_t = p_t * (_nt(v[h], do) - delta)
+            dv_acc[h] += jnp.dot(p_t.astype(do.dtype), do,
+                                 preferred_element_type=jnp.float32)  # P^T dO
+            dk_acc[h] += jnp.dot(ds_t.astype(q.dtype), q,
+                                 preferred_element_type=jnp.float32)  # dS^T Q
 
     # The first query tile whose LAST row is at or after the block's first
     # key; every tile before it lies wholly in the keys' past.
@@ -405,84 +485,97 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(si == n_s - 1)
     def _emit():
-        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        for h, lanes in enumerate(heads):
+            dk_ref[0, :, lanes] = (dk_acc[h] * scale).astype(dk_ref.dtype)
+            dv_ref[0, :, lanes] = dv_acc[h].astype(dv_ref.dtype)
 
 
-def _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, *, scale, causal, k_len,
-         blk_q, blk_k):
-    BH, Sq, D = q3.shape
-    Sk = k3.shape[1]
+def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
+         blk_q, blk_k, head_dim):
+    B, Sq, HD = q.shape
+    Sk = k.shape[1]
+    H = HD // head_dim
+    W = _lanes(H, head_dim)
+    g, slabs = W // head_dim, HD // W
     span_q, span_k = _span(Sq, blk_q), _span(Sk, blk_k)
     # dL/ds_ij = p_ij (dp_ij - delta_i) for the out path PLUS p_ij * dlse_i
     # for the lse path (dlse/ds = softmax row) — the lse cotangent folds
     # into delta with a sign flip. dlse is zeros when lse wasn't consumed.
-    delta = jnp.sum(
-        do3.astype(jnp.float32) * o3.astype(jnp.float32), axis=-1
-    )[:, None, :] - dlse.astype(jnp.float32)  # (BH, 1, Sq)
+    # rowsum(dO * O) is over each head's D where dO and O lie, its sums
+    # written as the rows they cross HBM in: (B, H, Sq), the batch
+    # dimensions in that order (XLA makes one reduction of it in both
+    # models' steps; summing and then transposing cost the dense step a
+    # copy of an operand: PERF.md, PR 30).
+    by_head = lambda x: x.reshape(B, Sq, H, head_dim)
+    delta = jax.lax.dot_general(
+        by_head(do), by_head(o), (((3,), (3,)), ((0, 2, 1), (0, 2, 1))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    ).reshape(B * H, 1, Sq) - dlse.astype(jnp.float32)
     kernel_kw = dict(scale=scale, causal=causal, k_len=k_len,
-                     blk_q=blk_q, blk_k=blk_k)
+                     blk_q=blk_q, blk_k=blk_k, head_dim=head_dim)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    q_spec = pl.BlockSpec((1, blk_q, D), lambda b, i, s: (b, i, 0))
-    row_spec = pl.BlockSpec((1, 1, blk_q), lambda b, i, s: (b, 0, i))
-    k_spec = pl.BlockSpec((1, span_k, D), lambda b, i, s: (b, s, 0))
+    q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
+    row_spec = _stat_rows(blk_q, g, slabs, _OWN)
     dq = _pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_kw),
         "flash_bwd_dq",
-        grid=(BH, Sq // blk_q, Sk // span_k),
+        grid=(B, slabs, Sq // blk_q, Sk // span_k),
         in_specs=[scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, D),
-    )(qo, ko, q3, k3, v3, do3, lse, delta)
+        out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
+        scratch_shapes=[pltpu.VMEM((g, blk_q, head_dim), jnp.float32)],
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g),
+    )(qo, ko, q, k, v, do, lse, delta)
 
     # K outer / Q streams: the accumulators belong to the K block.
-    q_spec = pl.BlockSpec((1, span_q, D), lambda b, j, s: (b, s, 0))
-    row_spec = pl.BlockSpec((1, 1, span_q), lambda b, j, s: (b, 0, s))
-    k_spec = pl.BlockSpec((1, blk_k, D), lambda b, j, s: (b, j, 0))
+    q_spec, k_spec = _slab(span_q, W, _SPAN), _slab(blk_k, W, _OWN)
+    row_spec = _stat_rows(span_q, g, slabs, _SPAN)
     dk, dv = _pallas_call(
         functools.partial(_bwd_dkv_kernel, **kernel_kw),
         "flash_bwd_dkv",
-        grid=(BH, Sk // blk_k, Sq // span_q),
+        grid=(B, slabs, Sk // blk_k, Sq // span_q),
         in_specs=[scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sk, D), k3.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), v3.dtype),
+            jax.ShapeDtypeStruct((B, Sk, HD), k.dtype),
+            jax.ShapeDtypeStruct((B, Sk, HD), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk_k, D), jnp.float32),
-            pltpu.VMEM((blk_k, D), jnp.float32),
+            pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
+            pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
         ],
-        compiler_params=_params(blk_q, blk_k, span_q, blk_k, D),
-    )(qo, ko, q3, k3, v3, do3, lse, delta)
+        compiler_params=_params(blk_q, blk_k, span_q, blk_k, W, g),
+    )(qo, ko, q, k, v, do, lse, delta)
     return dq, dk, dv
 
 
 # -- public entrypoint ---------------------------------------------------------
 
 
-def _flash_fwd(q3, k3, v3, offsets, scale, causal, k_len, blk_q, blk_k,
-               out_dtype):
-    o3, lse = _fwd(q3, k3, v3, *offsets, scale=scale, causal=causal,
-                   k_len=k_len, blk_q=blk_q, blk_k=blk_k, out_dtype=out_dtype)
-    return (o3, lse), (q3, k3, v3, o3, lse, offsets)
+def _flash_fwd(q, k, v, offsets, scale, causal, k_len, blk_q, blk_k,
+               head_dim, out_dtype):
+    o, lse = _fwd(q, k, v, *offsets, scale=scale, causal=causal,
+                  k_len=k_len, blk_q=blk_q, blk_k=blk_k, head_dim=head_dim,
+                  out_dtype=out_dtype)
+    return (o, lse), (q, k, v, o, lse, offsets)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash(*args):
     return _flash_fwd(*args)[0]
 
 
-def _flash_bwd(scale, causal, k_len, blk_q, blk_k, out_dtype, res, cts):
-    q3, k3, v3, o3, lse, (qo, ko) = res
-    do3, dlse = cts
-    dq, dk, dv = _bwd(q3, k3, v3, o3, lse, do3, dlse, qo, ko, scale=scale,
-                      causal=causal, k_len=k_len, blk_q=blk_q, blk_k=blk_k)
+def _flash_bwd(scale, causal, k_len, blk_q, blk_k, head_dim, out_dtype, res,
+               cts):
+    q, k, v, o, lse, (qo, ko) = res
+    do, dlse = cts
+    dq, dk, dv = _bwd(q, k, v, o, lse, do, dlse, qo, ko, scale=scale,
+                      causal=causal, k_len=k_len, blk_q=blk_q, blk_k=blk_k,
+                      head_dim=head_dim)
     return dq, dk, dv, None
 
 
@@ -498,7 +591,8 @@ def _round_up(n: int, m: int) -> int:
 #: (`onchip_flash_sweep.py`, bf16, causal, v5e; PERF.md, Findings) found one:
 #: 512 x 512 was the quickest tile for each of the three kernels at (S, D) =
 #: (1024, 64), (2048, 64) and (1024, 128) and within 6% of the quickest
-#: (1024 x 1024) at (4096, 64). Smaller tiles waste less of the causal
+#: (1024 x 1024) at (4096, 64); at the hybrid cell's (8192, 128) it is within
+#: 2% of the quickest (512 x 1024; PR 30). Smaller tiles waste less of the causal
 #: diagonal but pay a loop trip's fill and drain more often; larger ones
 #: compute more of the future. A sequence shorter than the tile gets one
 #: tile of its own length, in whole 128-row MXU tiles.
@@ -533,6 +627,15 @@ def flash_attention(
 
     ``block_q``/``block_k`` pin the tile (multiples of 128); left out it is
     `_TILE`, or the sequence's length where that is shorter.
+
+    The kernels read and write the arrays where they lie, as (B, S, H*D),
+    a block `_lanes(H, D)` lanes wide: nothing is transposed or copied on
+    the way in or out. Where H*D is no multiple of the least common multiple
+    of D and 128 (an odd head count at D = 64, say) a block is ALL heads
+    wide: right, but a span of 8,192 rows of some thousand lanes, twice for
+    K and V and twice for the double buffer, passes the 96 MiB of VMEM a
+    kernel may ask for and Mosaic refuses the program when it compiles;
+    such a model pads its heads or passes a shorter sequence a call.
     """
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -545,21 +648,21 @@ def flash_attention(
     blk_q = min(block_q or _TILE, _round_up(Sq, 128))
     blk_k = min(block_k or _TILE, _round_up(Sk, 128))
 
-    def to3(x):  # (B, S, H, D) -> (B*H, S, D)
-        Bx, Sx, Hx, Dx = x.shape
-        return x.transpose(0, 2, 1, 3).reshape(Bx * Hx, Sx, Dx)
-
-    q3 = _pad_seq(to3(q), blk_q)
-    k3 = _pad_seq(to3(k), blk_k)
-    v3 = _pad_seq(to3(v), blk_k)
+    # heads side by side on the last axis: the arrays as they lie in memory
+    q2 = _pad_seq(q.reshape(B, Sq, H * D), blk_q)
+    k2 = _pad_seq(k.reshape(B, Sk, H * D), blk_k)
+    v2 = _pad_seq(v.reshape(B, Sk, H * D), blk_k)
     # once a trace, for whoever reads a profile: which tiling was built
-    span_q, span_k = _span(q3.shape[1], blk_q), _span(k3.shape[1], blk_k)
+    span_q, span_k = _span(q2.shape[1], blk_q), _span(k2.shape[1], blk_k)
+    lanes = _lanes(H, D)
+    slabs = B * (H * D // lanes)  # (batch row, block of lanes) pairs
     _log.debug(
-        "flash_attention q%s k%s %s: tile %dx%d; grid steps a call: "
-        "flash_fwd and flash_bwd_dq %d (key span %d), flash_bwd_dkv %d "
-        "(query span %d)", q.shape, k.shape, q.dtype, blk_q, blk_k,
-        B * H * (q3.shape[1] // blk_q) * (k3.shape[1] // span_k), span_k,
-        B * H * (k3.shape[1] // blk_k) * (q3.shape[1] // span_q), span_q)
+        "flash_attention q%s k%s %s: tile %dx%d, %d heads a block; grid "
+        "steps a call: flash_fwd and flash_bwd_dq %d (key span %d), "
+        "flash_bwd_dkv %d (query span %d)", q.shape, k.shape, q.dtype,
+        blk_q, blk_k, lanes // D,
+        slabs * (q2.shape[1] // blk_q) * (k2.shape[1] // span_k), span_k,
+        slabs * (k2.shape[1] // blk_k) * (q2.shape[1] // span_q), span_q)
 
     offsets = (jnp.asarray([q_offset], jnp.int32),
                jnp.asarray([k_offset], jnp.int32))
@@ -568,9 +671,9 @@ def flash_attention(
     # merge at accumulator precision and the CALLER downcasts once after
     # the final merge — the same discipline the einsum ring engine had.
     out_dtype = jnp.float32 if return_lse else q.dtype
-    o3, lse3 = _flash(q3, k3, v3, offsets, scale, causal, Sk, blk_q, blk_k,
-                      jnp.dtype(out_dtype))
-    out = o3[:, :Sq].reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
+    o2, lse = _flash(q2, k2, v2, offsets, scale, causal, Sk, blk_q, blk_k, D,
+                     jnp.dtype(out_dtype))
+    out = o2[:, :Sq].reshape(B, Sq, H, D)
     if not return_lse:
         return out
-    return out, lse3[:, 0, :Sq].reshape(B, H, Sq)
+    return out, lse[:, 0, :Sq].reshape(B, H, Sq)

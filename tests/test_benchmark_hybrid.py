@@ -124,13 +124,21 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     for e in bench["configs"] + bench["workloads"]:
         assert len(e["why"]) <= 200 and "\n" not in e["why"]
     reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)]
-    assert len(reports) == 23 and "mfu_pct.train" not in reports \
+    assert len(reports) == 24 and "mfu_pct.train" not in reports \
         and "attn_roofline_pct.train" not in reports
     # PR 28's counter that the SSD kernels ran: data on a reader that was there
-    assert reports[-1] == "ssd_kernel_time_pct.train"
+    assert reports[-2] == "ssd_kernel_time_pct.train"
     how = run.load_json(BENCH, "layer_metrics", "ssd_kernel_time_pct.train.json")
     assert how["reader"] == "trace_scope_time_share"
     assert how["args"] == {"scopes": ["ssd_fwd", "ssd_bwd"]}
+    # PR 30's: all of attn_core, in both cells; less the kernels' share it is
+    # what XLA puts between the block's arrays and the kernels' operands
+    assert reports[-1] == "attn_core_time_pct.train"
+    dense = next(w["name"] for w in bench["workloads"] if w["name"] != CELL)
+    assert run.reports(bench["per_layer"][-1], dense)
+    how = run.load_json(BENCH, "layer_metrics", "attn_core_time_pct.train.json")
+    assert how["reader"] == "trace_scope_time_share"
+    assert how["args"] == {"scopes": ["attn_core"]}
     # every metric that was there still lists the cell it listed
     for m in bench["per_layer"]:
         if not m["name"].endswith(("train_hybrid",)) \

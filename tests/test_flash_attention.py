@@ -317,20 +317,24 @@ def test_spans_shorter_than_the_sequence(monkeypatch, causal):
     _assert_matches_dense(q, k, v, causal=causal, block_q=128, block_k=128)
 
 
+@pytest.mark.parametrize("H,D", [
+    (2, 16),  # every head in one block narrower than a lane row
+    (4, 64),  # two blocks of 128 lanes, two heads each (g = 2)
+])
 @pytest.mark.parametrize("q_off,k_off", [
     (512, 0),    # a hop wholly in the past: every tile live, none masked
     (0, 513),    # a hop wholly in the future (k_offset > q_offset + Sq)
     (100, 300),  # partly dead: the first 200 queries see no key
     (300, 100),  # the diagonal crosses the tiles off their corners
 ])
-def test_ring_hops_at_multi_tile_blocks(q_off, k_off):
+def test_ring_hops_at_multi_tile_blocks(q_off, k_off, H, D):
     """What `_ring_flash_local` asks of one hop, at a block of 2 x 2 tiles:
     out and lse against the positioned oracle, and gradients through BOTH
     (the lse cotangent folds into delta). A dead hop is zeros, the
     sentinel, and zero gradients."""
     rng = np.random.default_rng(q_off + k_off)
-    q, k, v = rand_qkv(rng, 1, 512, 2, 16)
-    w = jnp.asarray(rng.standard_normal((1, 2, 512)), jnp.float32)
+    q, k, v = rand_qkv(rng, 1, 512, H, D)
+    w = jnp.asarray(rng.standard_normal((1, H, 512)), jnp.float32)
 
     def loss(attend):
         def f(q, k, v):
@@ -363,7 +367,8 @@ def test_ring_hops_at_multi_tile_blocks(q_off, k_off):
         assert not any(np.asarray(g).any() for g in got)
 
 
-def test_bfloat16_gradients_within_an_ulp_of_the_tensor():
+@pytest.mark.parametrize("H,D", [(2, 16), (2, 64)])  # (2, 64): g = 2
+def test_bfloat16_gradients_within_an_ulp_of_the_tensor(H, D):
     """bf16 in, bf16 on the MXU: P (forward), P^T and dS (backward) are
     rounded to bf16 at their matmuls and the gradients leave as bf16, while
     the statistics and the sums stay f32. Against the f32 oracle on the
@@ -373,7 +378,7 @@ def test_bfloat16_gradients_within_an_ulp_of_the_tensor():
     bf16 ulp (2^-8) of the tensor's largest entry, as in test_pipeline's
     and test_collective's tolerance tests, plus the same relative."""
     rng = np.random.default_rng(8)
-    q, k, v = rand_qkv(rng, 1, 640, 2, 16, dtype=jnp.bfloat16)
+    q, k, v = rand_qkv(rng, 1, 640, H, D, dtype=jnp.bfloat16)
     flash = lambda q, k, v: flash_attention(
         q, k, v, causal=True, block_q=256, block_k=256)
     oracle = lambda q, k, v: positioned_oracle(q, k, v, causal=True)[0]
@@ -385,3 +390,109 @@ def test_bfloat16_gradients_within_an_ulp_of_the_tensor():
         np.testing.assert_allclose(
             np.asarray(a, np.float32), b, rtol=2.0 ** -8,
             atol=float(np.abs(b).max()) * 2.0 ** -8, err_msg=f"d{name}")
+
+
+# -- heads on lanes --------------------------------------------------------------
+#
+# The kernels address (B, S, H*D) as it lies: a block is `_lanes(H, D)` lanes
+# wide and holds g = W / D heads side by side, each met through a copy of the
+# step's own operand with the other heads' lanes zeroed (flash_attention.py,
+# "heads on lanes").
+
+
+@pytest.mark.parametrize("H,D,lanes", [
+    (16, 64, 128), (20, 64, 128),  # the GPT-2 configurations: two heads
+    (32, 128, 128),                # the hybrid cell: one head
+    (2, 256, 256), (4, 32, 128), (8, 96, 384),
+    (3, 16, 48), (15, 64, 960),    # no whole blocks: every head in one
+])
+def test_a_block_is_the_least_whole_lane_rows_of_whole_heads(H, D, lanes):
+    assert fa._lanes(H, D) == lanes
+    assert lanes % D == 0 and (H * D) % lanes == 0
+
+
+def _distinct_heads(rng, B, S, H, D):
+    """q, k, v whose heads differ in scale as well as in their draws: a head
+    served from its neighbour's lanes cannot pass for its own."""
+    size = (1.0 + 0.5 * jnp.arange(H, dtype=jnp.float32))[None, None, :, None]
+    return tuple(x * size for x in rand_qkv(rng, B, S, H, D))
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("H,D", [
+    (2, 128),  # one head a block, two blocks
+    (4, 64),   # two heads a block, two blocks
+    (2, 64),   # two heads, one block
+    (4, 32),   # four heads a block
+    (3, 16),   # 48 lanes: no whole lane row, every head in one block
+    (2, 256),  # a head of two lane rows
+])
+def test_heads_on_lanes_match_dense_oracle(H, D, causal, return_lse):
+    """Forward and the three gradients at every way a block can hold heads,
+    two batch rows (the statistics' row index counts both) and two tiles a
+    side. With ``return_lse`` the logsumexp and the gradient through it."""
+    rng = np.random.default_rng(1000 * H + D)
+    B, S = 2, 256
+    q, k, v = _distinct_heads(rng, B, S, H, D)
+    w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=128,
+                               block_k=128, return_lse=return_lse)
+
+    def dense(q, k, v):
+        if return_lse:
+            return positioned_oracle(q, k, v, causal=causal)
+        return dense_attention(q, k, v, causal=causal)
+
+    def loss(attend):
+        def f(q, k, v):
+            got = attend(q, k, v)
+            if return_lse:
+                return jnp.sum(got[0] ** 2) + jnp.sum(got[1] * w)
+            return jnp.sum(got ** 2)
+        return f
+
+    got, want = flash(q, k, v), dense(q, k, v)
+    for a, b in zip(*((got, want) if return_lse else ((got,), (want,)))):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5)
+    g_got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g_got, g_want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=5e-4,
+            atol=5e-4 * float(np.abs(np.asarray(b)).max()),
+            err_msg=f"d{name}")
+
+
+def _primitives_outside_kernels(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives_outside_kernels(sub)
+
+
+@pytest.mark.parametrize("H,D", [(16, 64), (32, 128)])
+@pytest.mark.parametrize("grad", [False, True])
+def test_no_layout_work_between_the_model_and_the_kernels(H, D, grad):
+    """At an aligned shape `flash_attention` and its gradient hand the
+    kernels the arrays they were given: the program holds the three
+    `pallas_call`s and no `pad` and no `transpose` of an operand, a result
+    or a cotangent (the reshapes to (B, S, H*D) and back move nothing);
+    delta's sums over D leave their `dot_general` as the (B, H, S) rows the
+    kernels read."""
+    qkv = (jax.ShapeDtypeStruct((2, 1024, H, D), jnp.bfloat16),) * 3
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else (
+        lambda q, k, v: flash_attention(q, k, v, causal=True))
+    seen = list(_primitives_outside_kernels(jax.make_jaxpr(fn)(*qkv).jaxpr))
+    assert seen.count("pallas_call") == (6 if grad else 2)  # each x 2 engines
+    assert "transpose" not in seen and "pad" not in seen, seen

@@ -56,8 +56,8 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _qkv(sharding, seq, head_dim=64):
-    return (jax.ShapeDtypeStruct((2, seq, 16, head_dim), jnp.bfloat16,
+def _qkv(sharding, seq, head_dim=64, batch=2, heads=16):
+    return (jax.ShapeDtypeStruct((batch, seq, heads, head_dim), jnp.bfloat16,
                                  sharding=sharding),) * 3
 
 
@@ -67,28 +67,41 @@ def _compile_flash(fn, args):
     assert "flash_attention_interpreted" not in text
 
 
-#: (S, D) the tile was swept at (onchip_flash_sweep.py): the benchmark cell's,
-#: a ring hop's, the longest one span holds, a wider head; and one past
-#: `_MAX_SPAN`, which takes two spans a sequence
-RULE_SHAPES = [(1024, 64), (2048, 64), (4096, 64), (1024, 128), (8192, 64)]
+#: (S, D) the tile was swept at (onchip_flash_sweep.py): the dense cell's, a
+#: ring hop's, a longer one, a wider head, the longest one span holds; and
+#: one past `_MAX_SPAN`, which takes two spans a sequence
+RULE_SHAPES = [(1024, 64), (2048, 64), (4096, 64), (1024, 128), (8192, 64),
+               (16384, 64)]
+
+#: (batch, seq, heads, head_dim) of the benchmark's two cells: two heads of
+#: 64 to a 128-lane block and 8 blocks a row; one head of 128 to a block, 32
+#: blocks a row and two spans a head
+CELL_SHAPES = [(32, 1024, 16, 64), (2, 8192, 32, 128)]
 
 
-@pytest.mark.parametrize("seq,head_dim", RULE_SHAPES)
-def test_flash_forward_and_backward_at_the_rules_tiles(one_chip, seq,
-                                                       head_dim):
-    """The tile and spans the kernels take unasked, as Mosaic sees them: a
-    tile it refuses, a slice it cannot align or a kernel over its VMEM limit
-    fails here."""
+#: every rule shape at batch 2 and 16 heads, and the two cells
+FLASH_SHAPES = [pytest.param(*shape, id="x".join(map(str, shape)))
+                for shape in [(2, seq, 16, d) for seq, d in RULE_SHAPES]
+                + CELL_SHAPES]
+
+
+@pytest.mark.parametrize("batch,seq,heads,head_dim", FLASH_SHAPES)
+def test_flash_forward_and_backward_at_the_rules_tiles(one_chip, batch, seq,
+                                                       heads, head_dim):
+    """The tile, spans and lanes the kernels take unasked, as Mosaic sees
+    them: a tile it refuses, a slice it cannot align or a kernel over its
+    VMEM limit fails here."""
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 
     _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                   _qkv(one_chip, seq, head_dim))
+                   _qkv(one_chip, seq, head_dim, batch, heads))
 
 
-@pytest.mark.parametrize("seq,head_dim", RULE_SHAPES)
-def test_flash_with_lse_as_the_ring_calls_it(one_chip, seq, head_dim):
+@pytest.mark.parametrize("batch,seq,heads,head_dim", FLASH_SHAPES)
+def test_flash_with_lse_as_the_ring_calls_it(one_chip, batch, seq, heads,
+                                             head_dim):
     """`_ring_flash_local`'s hop engine: global offsets, f32 partial output
     and a differentiable logsumexp."""
 
@@ -98,19 +111,21 @@ def test_flash_with_lse_as_the_ring_calls_it(one_chip, seq, head_dim):
         return out.sum() + lse.sum()
 
     _compile_flash(jax.value_and_grad(hop, argnums=(0, 1, 2)),
-                   _qkv(one_chip, seq, head_dim))
+                   _qkv(one_chip, seq, head_dim, batch, heads))
 
 
+@pytest.mark.parametrize("heads,head_dim", [(16, 64), (32, 128)])
 @pytest.mark.parametrize("seq", [200, 12])
-def test_flash_short_sequence_pads_to_the_block(one_chip, seq):
+def test_flash_short_sequence_pads_to_the_block(one_chip, seq, heads,
+                                                head_dim):
     """Sequence lengths that are no multiple of the 128-row tile: the
-    padding branch, forward and backward."""
+    padding branch, forward and backward, at both cells' heads."""
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 
     _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                   _qkv(one_chip, seq))
+                   _qkv(one_chip, seq, head_dim, heads=heads))
 
 
 def _pallas_calls(jaxpr):
@@ -121,23 +136,29 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-def test_flash_grid_at_the_benchmark_shape_is_a_few_thousand_steps():
+@pytest.mark.parametrize("shape,grid", [
+    # B, H/g blocks of lanes, blocks of 512 rows, spans of up to 8,192
+    ((32, 1024, 16, 64), (32, 8, 2, 1)),
+    ((2, 8192, 32, 128), (2, 32, 16, 1)),
+])
+def test_flash_grid_at_the_benchmark_shapes(shape, grid):
     """A grid step costs about 0.35 us before it computes: at 128 x 128
-    tiles, one a step, the cell's (32, 1024, 16, 64) call was 32,768 steps
-    and 16 ms (PERF.md, PR 26). A fall back to that must not pass unseen:
-    every kernel of the call, forward and backward, stays under 4,096."""
-    qkv = (jax.ShapeDtypeStruct((32, 1024, 16, 64), jnp.bfloat16),) * 3
+    tiles, one a step, the dense cell's (32, 1024, 16, 64) call was 32,768
+    steps and 16 ms (PERF.md, PR 26). A fall back to that must not pass
+    unseen: every kernel of the call, forward and backward, takes
+    ``B x H/g x blocks x spans`` steps: 512 at the dense cell (two heads a
+    block; 1,024 when a block was one head) and 1,024 at the hybrid cell
+    (one span a head since `_MAX_SPAN` is 8,192)."""
+    qkv = (jax.ShapeDtypeStruct(shape, jnp.bfloat16),) * 3
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*qkv)
-    steps = {}
-    for eqn in _pallas_calls(jaxpr.jaxpr):
-        grid = eqn.params["grid_mapping"].grid
-        steps[eqn.params["name"]] = grid[0] * grid[1] * grid[2]
-    assert set(steps) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
-    assert max(steps.values()) <= 4096, steps
+    grids = {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
+             for eqn in _pallas_calls(jaxpr.jaxpr)}
+    assert grids == dict.fromkeys(
+        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), grid)
 
 
 def _param_avals(model, mesh):
@@ -212,6 +233,48 @@ def test_train_step(topo, chips):
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15 * 2**30
 
 
+def _compiled_cell_step(topo, model, batch, seq):
+    """One chip's whole Adam train step of ``model`` at ``batch`` x ``seq``
+    tokens, as the benchmark's runners build it, compiled for the described
+    v5e."""
+    mesh = build_mesh(MeshSpec({"data": 1}), list(topo.devices)[:1])
+    trainer = Trainer(model, mesh, TrainerConfig(optimizer="adam"))
+    params = _param_avals(model, mesh)
+    rep = NamedSharding(mesh, P())
+    state = TrainState(
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), params,
+        jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            jax.eval_shape(trainer.opt.init, params)))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    return trainer._jit_step.lower(
+        state, {"tokens": tokens, "targets": tokens}).compile()
+
+
+def test_dense_train_step_at_the_cell(topo):
+    """`train_gpt2m_1chip`'s step: GPT-2-medium at full depth, batch 32 x
+    1,024, per-block remat, Adam, as `benchmarks/runners/train.py` builds
+    it. The flash kernels take the block's arrays as they lie, so the step
+    holds no transposed copy of q, k, v or o beyond what XLA's own layouts
+    ask for: its temporaries stay within the 9.77 GiB they were when the
+    kernels took (B*H, S, D) (PERF.md, Sizing), and the step fits the
+    chip's 15.75 GiB."""
+    compiled = _compiled_cell_step(
+        topo, transformer.make_model(**WIDTHS, n_layers=24, remat=True),
+        32, 1024)
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "tpu_custom_call" in text
+    assert "flash_attention_interpreted" not in text
+    mem = compiled.memory_analysis()
+    print(f"dense step for the described v5e: temp "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
+    assert mem.temp_size_in_bytes <= 9.77 * 2**30
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        < 15.75 * 2**30
+
+
 def test_hybrid_train_step_at_the_cell(topo):
     """`train_nemotron3nano_1chip`'s step: the configuration file's widths
     (nine layers `MEMEM*EME`, 8 of 128 experts held, 16,384 rows of the
@@ -220,8 +283,9 @@ def test_hybrid_train_step_at_the_cell(topo):
     the grouped product through Mosaic, and since PR 28 the SSD scan's two
     kernels `ssd_fwd` and `ssd_bwd` at chunks of 128 with 8 heads of 64 a
     group) and its temporaries and arguments fit the chip's 15.75 GiB, the
-    temporaries in no more than PR 27's 7.65 GiB (the cell file's ``sizing``
-    holds PR 27's reading; PERF.md, Sizing, the newest)."""
+    temporaries in no more than the 3.858 GiB they took before the flash
+    kernels addressed (B, S, H*D) (PR 28's reading; the cell file's
+    ``sizing`` holds PR 27's; PERF.md, Sizing, the newest)."""
     import json
     import os
 
@@ -236,22 +300,10 @@ def test_hybrid_train_step_at_the_cell(topo):
         traffic = json.load(f)
     sizes = {ours: config[theirs]
              for theirs, ours in config["maps_to"].items()}
-    mesh = build_mesh(MeshSpec({"data": 1}), list(topo.devices)[:1])
     model = resolve(config["model"], dict(sizes, seq_len=traffic["seq_len"],
                                           remat=True))
-    trainer = Trainer(model, mesh, TrainerConfig(optimizer="adam"))
-    params = _param_avals(model, mesh)
-    rep = NamedSharding(mesh, P())
-    state = TrainState(
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep), params,
-        jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
-            jax.eval_shape(trainer.opt.init, params)))
-    tokens = jax.ShapeDtypeStruct(
-        (traffic["batch"], traffic["seq_len"]), jnp.int32,
-        sharding=NamedSharding(mesh, P("data")))
-    compiled = trainer._jit_step.lower(
-        state, {"tokens": tokens, "targets": tokens}).compile()
+    compiled = _compiled_cell_step(topo, model, traffic["batch"],
+                                   traffic["seq_len"])
     text = compiled.as_text()
     assert "flash_fwd" in text and "tpu_custom_call" in text
     assert "ssd_fwd" in text and "ssd_bwd" in text, \
@@ -263,6 +315,6 @@ def test_hybrid_train_step_at_the_cell(topo):
           f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
           f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
     assert mem.argument_size_in_bytes > 7.4 * 2**30  # the 8.00 GB of state
-    assert mem.temp_size_in_bytes <= 7.65 * 2**30
+    assert mem.temp_size_in_bytes <= 3.8585 * 2**30
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         < 15.75 * 2**30
