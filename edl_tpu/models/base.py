@@ -66,3 +66,10 @@ class Model:
     #: host numbers (and into the metrics registry). A forward pass of its
     #: own, for a caller outside the timed step (`models/hybrid.py`).
     routing_stats: Optional[Callable] = None
+    #: optional (params, batch, whole=False) -> {layer: {...}} for models
+    #: with learned sparse attention: which keys sampled queries attend to,
+    #: the layer's input, and counters (selected, visible, future,
+    #: miscounted), as host numbers and into the metrics registry; with
+    #: ``whole`` each layer's whole selection too, on the device. A forward
+    #: pass of its own, outside the timed step (`models/hybrid.py`).
+    selection_stats: Optional[Callable] = None

@@ -21,7 +21,22 @@ hybrids; equations in each function's docstring):
   dropped: there is no capacity and no ``(T, E, C)`` one-hot. What the
   absent experts would add is left out (the chip's share of an
   expert-parallel deployment; the exchange is a later PR);
-- ``-``  a dense relu^2 MLP (the family's other sizes use it).
+- ``-``  a dense relu^2 MLP (the family's other sizes use it);
+- ``S``  grouped-query attention under a learned sparse-attention indexer
+  (DeepSeek-V3.2's lightning indexer): a per-head RMS norm on q and k, then
+  rotary positions over the whole head; an indexer of its own projections
+  scores every causal (query, key) pair from the layer's input with the
+  gradient stopped, and a query attends to the ``indexer_topk`` keys of
+  largest score (all of them where it sees no more). The selection is made
+  once a layer a step, OUTSIDE the layer's `jax.checkpoint`, and handed to
+  `ops.flash_attention` as its ``selection`` operand; it carries no
+  gradient, so under the LM loss the indexer's leaves stay where they are.
+
+The expert layer's router scores by ``router_score`` (``sigmoid`` with a
+selection bias and a scale, or a plain ``softmax`` renormalised over the
+chosen), its experts are ``expert_act`` ``relu2`` (two matrices) or ``silu``
+(gated: ``silu(h G) * (h U)`` through ``D``, three matrices), and
+``shared_width`` 0 leaves the shared expert out.
 
 Training only: a Mamba layer carries recurrent state beside K/V, which the
 serving engine's cache manager does not know, so `serving/lm.py` and
@@ -52,7 +67,7 @@ from edl_tpu.models.base import Model
 from edl_tpu.obs.metrics import get_registry
 from edl_tpu.parallel.sharding import present_axes
 
-KINDS = "ME*-"
+KINDS = "ME*-S"
 
 #: why the serving tier and the export path refuse this module
 NOT_SERVABLE = (
@@ -79,14 +94,35 @@ _M_EXPERT_TOKENS = _REG.counter(
     labelnames=("layer", "expert"))
 
 
+_M_KEYS_SELECTED = _REG.counter(
+    "edl_sparse_keys_selected_total",
+    "Keys the sparse-attention selection kept, over the queries of the "
+    "batches asked about, by layer", labelnames=("layer",))
+_M_KEYS_VISIBLE = _REG.counter(
+    "edl_sparse_keys_visible_total",
+    "Keys those queries could see under the causal mask alone, by layer",
+    labelnames=("layer",))
+_M_KEYS_FUTURE = _REG.counter(
+    "edl_sparse_keys_future_total",
+    "Selected keys that lie after their query (always 0)",
+    labelnames=("layer",))
+_M_ROWS_MISCOUNTED = _REG.counter(
+    "edl_sparse_rows_miscounted_total",
+    "Queries whose selection does not hold min(t + 1, topk) keys (always 0)",
+    labelnames=("layer",))
+
+
 @dataclass(frozen=True)
 class HybridConfig:
     vocab_size: int = 512
     d_model: int = 64
-    #: one character a layer: M, E, *, -
+    #: one character a layer: M, E, *, -, S
     pattern: str = "ME*E-M"
     seq_len: int = 64
     norm_eps: float = 1e-5
+    #: standard deviation of the embedding's initial rows (every other
+    #: matrix starts at 0.02)
+    embed_std: float = 0.02
     # -- M: Mamba-2 mixer
     mamba_heads: int = 4
     mamba_head_dim: int = 16
@@ -97,10 +133,15 @@ class HybridConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
-    # -- *: grouped-query attention
+    # -- * and S: grouped-query attention
     n_heads: int = 4
     n_kv_heads: int = 2
     head_dim: int = 16
+    # -- S alone: rotary base, and the indexer that selects a query's keys
+    rope_theta: float = 10000.0
+    indexer_heads: int = 4
+    indexer_head_dim: int = 16
+    indexer_topk: int = 16
     # -- E: experts. `n_experts` is the router's published width; the layer
     # holds `experts_count` of them from `experts_first` on
     n_experts: int = 8
@@ -108,8 +149,14 @@ class HybridConfig:
     experts_count: int = 8
     top_k: int = 2
     expert_width: int = 32
+    #: 0: no shared expert
     shared_width: int = 64
     routed_scale: float = 2.5
+    #: ``sigmoid`` (selection bias, renormalised, scaled) or ``softmax``
+    #: (over all published experts, renormalised over the chosen, scaled)
+    router_score: str = "sigmoid"
+    #: ``relu2`` (``relu(h U)^2 D``) or ``silu`` (``(silu(h G) * (h U)) D``)
+    expert_act: str = "relu2"
     # -- -: dense MLP
     mlp_width: int = 32
     batch_axis: Union[str, Tuple[str, ...]] = "data"
@@ -155,6 +202,14 @@ def _check(cfg: HybridConfig) -> None:
                          f"router's {cfg.n_experts} experts")
     if not 1 <= cfg.top_k <= cfg.n_experts:
         raise ValueError(f"top_k {cfg.top_k} of {cfg.n_experts} experts")
+    if cfg.router_score not in ("sigmoid", "softmax"):
+        raise ValueError(f"router_score {cfg.router_score!r}")
+    if cfg.expert_act not in ("relu2", "silu"):
+        raise ValueError(f"expert_act {cfg.expert_act!r}")
+    if "S" in cfg.pattern and (cfg.head_dim % 2 or cfg.indexer_head_dim % 2
+                               or cfg.indexer_topk < 1):
+        raise ValueError("rotary positions pair the halves of an even head; "
+                         "indexer_topk is at least 1")
 
 
 # -- parameters ---------------------------------------------------------------------
@@ -172,12 +227,25 @@ def _layer_shapes(cfg: HybridConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
         q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         return {"norm": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
                 "wo": (q, D)}
+    if kind == "S":
+        q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+        Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
+        return {"norm": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
+                "wo": (q, D), "q_norm": (cfg.head_dim,),
+                "k_norm": (cfg.head_dim,), "ix_wq": (D, Hi * Di),
+                "ix_wk": (D, Di), "ix_ww": (D, Hi), "ix_norm": (Di,),
+                "ix_norm_b": (Di,)}
     if kind == "E":
         F, Fs, n = cfg.expert_width, cfg.shared_width, cfg.experts_count
-        return {"norm": (D,), "router": (cfg.n_experts, D),
-                "router_bias": (cfg.n_experts,),
-                "w_up": (n, D, F), "w_down": (n, F, D),
-                "shared_up": (D, Fs), "shared_down": (Fs, D)}
+        shapes = {"norm": (D,), "router": (cfg.n_experts, D),
+                  "w_up": (n, D, F), "w_down": (n, F, D)}
+        if cfg.router_score == "sigmoid":
+            shapes["router_bias"] = (cfg.n_experts,)
+        if cfg.expert_act == "silu":  # gate and up side by side: [G | U]
+            shapes["w_up"] = (n, D, 2 * F)
+        if Fs:
+            shapes.update(shared_up=(D, Fs), shared_down=(Fs, D))
+        return shapes
     return {"norm": (D,), "w_up": (D, cfg.mlp_width),
             "w_down": (cfg.mlp_width, D)}
 
@@ -196,9 +264,9 @@ def _init_layer(cfg: HybridConfig, kind: str, key: jax.Array) -> dict:
     shapes = _layer_shapes(cfg, kind)
     for name, k in zip(sorted(shapes), jax.random.split(key, len(shapes))):
         shape = shapes[name]
-        if name in ("norm", "gate_norm", "D"):
+        if name in ("norm", "gate_norm", "D", "q_norm", "k_norm", "ix_norm"):
             out[name] = jnp.ones(shape, jnp.float32)
-        elif name in ("conv_b", "router_bias"):
+        elif name in ("conv_b", "router_bias", "ix_norm_b"):
             out[name] = jnp.zeros(shape, jnp.float32)
         elif name == "dt_bias":
             lo, hi = math.log(cfg.time_step_min), math.log(cfg.time_step_max)
@@ -235,7 +303,8 @@ def _init(cfg: HybridConfig, key: jax.Array, mesh: Mesh) -> dict:
     D, V = cfg.d_model, cfg.vocab_size
     k_embed, k_head, k_layers = jax.random.split(key, 3)
     host = {
-        "embed": jax.random.normal(k_embed, (V, D), jnp.float32) * 0.02,
+        "embed": jax.random.normal(k_embed, (V, D), jnp.float32)
+        * cfg.embed_std,
         "layers": {
             name: _init_layer(cfg, name[-1], k) for name, k in zip(
                 cfg.layer_names,
@@ -349,13 +418,134 @@ def _attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
         return _mm("bse,ed->bsd", a.reshape(Bz, S, Hq * Dh), p["wo"])
 
 
+def _rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions 0..S-1 over the whole last axis of x (B, S, ..., d),
+    rotate-half pairing: element i < d/2 pairs with i + d/2, both turned by
+    the angle ``t theta^(-2i/d)``. float32 in, float32 out."""
+    S, d = x.shape[1], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = jnp.arange(S, dtype=jnp.float32)[:, None] * freq  # (S, d/2)
+    angle = angle.reshape((1, S) + (1,) * (x.ndim - 3) + (d // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _layernorm(x: jax.Array, w: jax.Array, b: jax.Array, eps: float):
+    mean = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), -1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+
+
+def _indexer_proj(cfg: HybridConfig, h: jax.Array, p: dict):
+    """The indexer's three projections of h (B, S, D) bf16: ``qI = rope(h
+    W_qI)`` (B, S, Hi, Di) and ``kI = rope(LayerNorm(h W_kI))`` (B, S, Di),
+    both bf16 for the MXU, and the head weights ``w = h W_w`` (B, S, Hi)
+    float32 with the score's two scales, ``Di^-0.5 Hi^-0.5``, folded in."""
+    Bz, S, _ = h.shape
+    Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
+    qI = _mm("bsd,de->bse", h, p["ix_wq"]).reshape(Bz, S, Hi, Di)
+    kI = _layernorm(_mm("bsd,de->bse", h, p["ix_wk"]), p["ix_norm"],
+                    p["ix_norm_b"], cfg.norm_eps)
+    w = _mm("bsd,dh->bsh", h, p["ix_ww"]) * (Di ** -0.5 * Hi ** -0.5)
+    return (_rope(qI, cfg.rope_theta).astype(bf16),
+            _rope(kI, cfg.rope_theta).astype(bf16), w)
+
+
+def _scores(qI, kI, w):
+    """``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``, TRANSPOSED: (B, S
+    keys, S queries) float32, by the Pallas kernel `ops.sparse_select.
+    indexer_scores` (bf16 products accumulated in float32, relu and the
+    head-weighted sum in float32 on the VPU). Pairs in a query's future hold
+    anything."""
+    from edl_tpu.ops.sparse_select import indexer_scores
+
+    return indexer_scores(qI, kI, w)
+
+
+def _select(scores_t: jax.Array, topk: int) -> jax.Array:
+    """Of every query's causal scores (column t of ``scores_t`` (B, S keys, S
+    queries)) the ``min(t + 1, topk)`` largest, ties to the earlier key
+    (what a stable sort or `jax.lax.top_k` keeps): int8 of the same shape,
+    by the Pallas kernel `ops.sparse_select.top_k_select`: the k-th largest
+    of a query by bisection on the float's bits, 32 counting passes over a
+    block that stays in VMEM. A sort of every row, and the same passes in
+    plain XLA, cost this chip more (PERF.md, Findings, PR 32)."""
+    from edl_tpu.ops.sparse_select import top_k_select
+
+    return top_k_select(scores_t, topk)
+
+
+def _selection(cfg: HybridConfig, x: jax.Array, p: dict) -> jax.Array:
+    """Which keys each query of an ``S`` layer attends to, int8 (B, S, S),
+    from the layer's input x (B, S, D) with the gradient stopped: the
+    layer's own pre-norm, the indexer's projections, `_scores`, `_select`.
+    The kernels take whole 128-lane rows of queries: a shorter sequence is
+    padded with positions after every real one, which no real query can
+    select."""
+    Bz, S, _ = x.shape
+    with jax.named_scope("indexer"):
+        h = _rmsnorm(jax.lax.stop_gradient(x), p["norm"],
+                     cfg.norm_eps).astype(bf16)
+        with jax.named_scope("indexer_proj"):
+            # no gradient reaches the indexer's leaves: the selection is a
+            # comparison, and the kernels below have no derivative
+            qI, kI, w = (jax.lax.stop_gradient(jnp.pad(
+                a, ((0, 0), (0, -S % 128)) + ((0, 0),) * (a.ndim - 2)))
+                for a in _indexer_proj(cfg, h, p))
+        with jax.named_scope("indexer_scores"):
+            scores_t = _scores(qI, kI, w)
+    with jax.named_scope("attn_select"):
+        return _select(scores_t, cfg.indexer_topk)[:, :S, :S].swapaxes(1, 2)
+
+
+def _sparse_attention(cfg: HybridConfig, h: jax.Array, p: dict,
+                      selection: jax.Array) -> jax.Array:
+    """``q = rope(rmsnorm_head(h W_q))``, ``k = rope(rmsnorm_head(h W_k))``
+    (a learned scale over each head's ``head_dim``), ``v = h W_v``, no bias;
+    softmax of ``q k^T / sqrt(head_dim)`` over the keys ``selection`` (B, S,
+    S) keeps for the query (all of them causal), query head j on K/V head
+    ``j // (n_heads/n_kv_heads)``; ``out = a W_o``."""
+    Bz, S, _ = h.shape
+    Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    with jax.named_scope("attn_proj"):
+        q = _mm("bsd,de->bse", h, p["wq"]).reshape(Bz, S, Hq, Dh)
+        k = _mm("bsd,de->bse", h, p["wk"]).reshape(Bz, S, Hkv, Dh)
+        v = _mm("bsd,de->bse", h, p["wv"], out=bf16).reshape(Bz, S, Hkv, Dh)
+        q = _rope(_rmsnorm(q, p["q_norm"], cfg.norm_eps),
+                  cfg.rope_theta).astype(bf16)
+        k = _rope(_rmsnorm(k, p["k_norm"], cfg.norm_eps),
+                  cfg.rope_theta).astype(bf16)
+    with jax.named_scope("attn_core"):
+        k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
+        scale = 1.0 / math.sqrt(Dh)
+        if cfg.flash:
+            from edl_tpu.ops import flash_attention
+
+            a = flash_attention(q, k, v, causal=True, scale=scale,
+                                selection=selection)
+        else:  # explicit scores under the selection's mask
+            s = _mm("bqhd,bkhd->bhqk", q, k) * scale
+            s = jnp.where(selection[:, None] != 0, s, -jnp.inf)
+            a = _mm("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, out=bf16)
+    with jax.named_scope("attn_proj"):
+        return _mm("bse,ed->bsd", a.reshape(Bz, S, Hq * Dh), p["wo"])
+
+
 def _route(cfg: HybridConfig, tok: jax.Array, p: dict):
     """The router, in float32, over all the published experts: ``s =
     sigmoid(tok W_r^T)``; the top k of ``s + b`` (the selection bias chooses
     and takes no gradient); weights ``s[chosen] / (sum + 1e-20) x scale``.
+    With ``router_score`` ``softmax``: ``s = softmax(tok W_r^T)``, the top k
+    of ``s``, weights ``s[chosen] / sum x scale``.
     Returns chosen experts (T, k) int32 and their weights (T, k)."""
     logits = jnp.einsum("td,ed->te", tok.astype(jnp.float32), p["router"],
                         precision=jax.lax.Precision.HIGHEST)
+    if cfg.router_score == "softmax":  # no bias: the probabilities choose
+        s = jax.nn.softmax(logits, axis=-1)
+        picked, chosen = jax.lax.top_k(s, cfg.top_k)
+        return chosen.astype(jnp.int32), picked / picked.sum(
+            -1, keepdims=True) * cfg.routed_scale
     s = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(s + jax.lax.stop_gradient(p["router_bias"]),
                               cfg.top_k)
@@ -398,10 +588,17 @@ def _grouped(rows, w, sizes, held):
     return jnp.where(held[:, None], out, 0)
 
 
-def _experts_of(rows, w_up, w_down, sizes):
-    """``relu(rows U_g)^2 V_g``, g a row's group; zeros past the groups."""
+def _experts_of(rows, w_up, w_down, sizes, act: str = "relu2"):
+    """``relu(rows U_g)^2 V_g``, g a row's group; zeros past the groups.
+    With ``act`` ``silu`` the experts are gated, ``w_up`` holds ``[G_g |
+    U_g]`` side by side and one grouped product gives both halves:
+    ``(silu(rows G_g) * (rows U_g)) V_g``."""
     held = jnp.arange(rows.shape[0]) < sizes.sum()
     up = _grouped(rows, w_up, sizes, held)
+    if act == "silu":
+        gate, up = jnp.split(up.astype(jnp.float32), 2, axis=-1)
+        return _grouped((jax.nn.silu(gate) * up).astype(bf16), w_down, sizes,
+                        held)
     act = _relu2(up.astype(jnp.float32)).astype(bf16)
     return _grouped(act, w_down, sizes, held)
 
@@ -410,6 +607,19 @@ def _experts_of(rows, w_up, w_down, sizes):
 #: than uniform routing sends the experts held at the benchmark's sizes
 #: (6,144 of 98,304 a layer)
 _ROW_TILE = 8192
+
+
+def _row_tile(cfg: "HybridConfig", assignments: int) -> int:
+    """Sorted assignments one pass of `_experts_held` computes, of a layer's
+    ``assignments`` (tokens x top_k): `_ROW_TILE`, or the power-of-two
+    multiple of it that holds a third more than uniform routing sends the
+    experts held (8,192 of 98,304 where 6,144 are held at uniform routing;
+    32,768 of 131,072 where 16,384 are). While the held assignments stay
+    under it a layer runs ONE pass whatever the router does, and the step's
+    time does not follow the routing."""
+    uniform = assignments * cfg.experts_count / cfg.n_experts
+    tiles = max(1, math.ceil(uniform * 4 / 3 / _ROW_TILE))
+    return math.gcd(assignments, _ROW_TILE * (1 << (tiles - 1).bit_length()))
 
 
 def _passes(sizes, tile: int):
@@ -434,8 +644,9 @@ def _tile(tok, weights, order, sizes, i, tile: int):
         return which, token, tok[token], weights.reshape(-1)[which], mine
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _experts_held(tok, weights, w_up, w_down, order, sizes, tile: int):
+@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _experts_held(tok, weights, w_up, w_down, order, sizes, tile: int,
+                  act: str = "relu2"):
     """``sum over a token's held assignments of w_e f_e(tok)``, (T, D)
     float32, for tokens (T, D), routing weights (T, k) and the plan of
     `_dispatch_plan`. Of the T x k sorted assignments only the first
@@ -450,19 +661,20 @@ def _experts_held(tok, weights, w_up, w_down, order, sizes, tile: int):
     what one grouped product gives them: in float32 the step's temporaries
     did not fit the chip."""
     return _experts_held_fwd(tok, weights, w_up, w_down, order, sizes,
-                             tile)[0]
+                             tile, act)[0]
 
 
-def _experts_held_loop(tok, weights, w_up, w_down, order, sizes, tile):
+def _experts_held_loop(tok, weights, w_up, w_down, order, sizes, tile,
+                       act="relu2"):
     """`_experts_held`, and how many sorted assignments its product gave a
-    value other than zero (`_routing_stats` counts the dropped by it)."""
+    value other than zero (`_route_stats` counts the dropped by it)."""
     up, down = w_up.astype(bf16), w_down.astype(bf16)
 
     def one(i, carry):
         out, computed = carry
         _, token, rows, w, mine = _tile(tok, weights, order, sizes, i, tile)
         with jax.named_scope("moe_experts"):
-            part = _experts_of(rows, up, down, mine)
+            part = _experts_of(rows, up, down, mine, act)
         with jax.named_scope("moe_combine"):
             return (out.at[token].add(part.astype(jnp.float32) * w[:, None]),
                     computed + jnp.sum(jnp.any(part != 0, axis=1),
@@ -473,13 +685,14 @@ def _experts_held_loop(tok, weights, w_up, w_down, order, sizes, tile):
         (jnp.zeros(tok.shape, jnp.float32), jnp.zeros((), jnp.int32)))
 
 
-def _experts_held_fwd(tok, weights, w_up, w_down, order, sizes, tile):
+def _experts_held_fwd(tok, weights, w_up, w_down, order, sizes, tile,
+                      act="relu2"):
     out, _ = _experts_held_loop(tok, weights, w_up, w_down, order, sizes,
-                                tile)
+                                tile, act)
     return out, (tok, weights, w_up, w_down, order, sizes)
 
 
-def _experts_held_bwd(tile, res, g):
+def _experts_held_bwd(tile, act, res, g):
     tok, weights, w_up, w_down, order, sizes = res
     up, down = w_up.astype(bf16), w_down.astype(bf16)
 
@@ -491,7 +704,8 @@ def _experts_held_bwd(tile, res, g):
             g_rows = g[token]
         with jax.named_scope("moe_experts"):
             part, pull = jax.vjp(
-                lambda r, u, d: _experts_of(r, u, d, mine), rows, up, down)
+                lambda r, u, d: _experts_of(r, u, d, mine, act), rows, up,
+                down)
             d_rows, u, d = pull((g_rows * w[:, None]).astype(bf16))
         with jax.named_scope("moe_combine"):
             d_weights = d_weights.at[which].add(
@@ -519,17 +733,20 @@ def _routed(cfg: HybridConfig, tok, w_up, w_down, chosen, weights):
     with jax.named_scope("moe_dispatch"):
         order, sizes = _dispatch_plan(chosen, cfg.experts_held)
     return _experts_held(tok, weights, w_up, w_down, order, sizes,
-                         math.gcd(chosen.size, _ROW_TILE))
+                         _row_tile(cfg, chosen.size), cfg.expert_act)
 
 
 def _moe(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
     """``out = sum over e chosen and held of w_e f_e(h) + f_shared(h)``,
-    ``f(h) = relu(h U)^2 V``. h (B, S, D) bf16."""
+    ``f(h) = relu(h U)^2 V`` (or the gated ``(silu(h G) * (h U)) V``; no
+    shared term where ``shared_width`` is 0). h (B, S, D) bf16."""
     Bz, S, D = h.shape
     tok = h.reshape(Bz * S, D)
     with jax.named_scope("moe_route"):
         chosen, weights = _route(cfg, tok, p)
     routed = _routed(cfg, tok, p["w_up"], p["w_down"], chosen, weights)
+    if not cfg.shared_width:
+        return routed.reshape(Bz, S, D)
     with jax.named_scope("moe_shared"):
         shared = _mm("tf,fd->td",
                      _relu2(_mm("td,df->tf", tok, p["shared_up"])),
@@ -544,15 +761,18 @@ def _mlp(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
 
 
 _MIXERS = {"M": ("mamba_mixer", _mamba), "*": ("attn", _attention),
-           "E": ("moe", _moe), "-": ("dense_mlp", _mlp)}
+           "E": ("moe", _moe), "-": ("dense_mlp", _mlp),
+           "S": ("attn", _sparse_attention)}
 
 
-def _layer(cfg: HybridConfig, kind: str, x: jax.Array, p: dict) -> jax.Array:
-    """``x + mixer(rmsnorm(x))``; x (B, S, D) bf16."""
+def _layer(cfg: HybridConfig, kind: str, x: jax.Array, p: dict,
+           *selection) -> jax.Array:
+    """``x + mixer(rmsnorm(x))``; x (B, S, D) bf16. An ``S`` layer is handed
+    its ``selection`` too."""
     scope, mixer = _MIXERS[kind]
     with jax.named_scope(scope):
         h = _rmsnorm(x, p["norm"], cfg.norm_eps).astype(bf16)
-        return x + mixer(cfg, h, p).astype(bf16)
+        return x + mixer(cfg, h, p, *selection).astype(bf16)
 
 
 def _stack(cfg: HybridConfig, params: dict, tokens: jax.Array, visit=None):
@@ -565,7 +785,13 @@ def _stack(cfg: HybridConfig, params: dict, tokens: jax.Array, visit=None):
         fn = partial(_layer, cfg, name[-1])
         if cfg.remat:
             fn = jax.checkpoint(fn)
-        x = fn(x, params["layers"][name])
+        # an S layer's selection is an ARGUMENT of its checkpoint: made once
+        # a step and kept for the backward pass, never made again
+        selection = ()
+        if name[-1] == "S":
+            with jax.named_scope("attn"):
+                selection = (_selection(cfg, x, params["layers"][name]),)
+        x = fn(x, params["layers"][name], *selection)
     return x
 
 
@@ -613,47 +839,98 @@ def _loss(cfg: HybridConfig, params: dict, batch: dict, mesh: Mesh):
     )(params, batch["tokens"], batch["targets"])
 
 
-# -- routing statistics ---------------------------------------------------------------
+# -- routing and selection statistics --------------------------------------------------
 
 
-def _routing_stats(cfg: HybridConfig, params: dict, tokens: jax.Array):
-    """What every E layer does with one batch, through the layer's own
-    routing, dispatch plan and grouped product: per layer the assignments
-    made, those to held experts, each held expert's rows, and ``dropped``:
-    the held assignments less the rows that the held experts' product gave a
-    value other than zero (`_experts_held_loop`, what the step runs)."""
-    stats = {}
+def _route_stats(cfg: HybridConfig, x: jax.Array, p: dict) -> dict:
+    """What one E layer does with its input x, through the layer's own
+    routing, dispatch plan and grouped product: the assignments made, those
+    to held experts, each held expert's rows, and ``dropped``: the held
+    assignments less the rows that the held experts' product gave a value
+    other than zero (`_experts_held_loop`, what the step runs)."""
     first, count = cfg.experts_held
+    h = _rmsnorm(x, p["norm"], cfg.norm_eps).astype(bf16)
+    tok = h.reshape(-1, cfg.d_model)
+    chosen, weights = _route(cfg, tok, p)
+    order, sizes = _dispatch_plan(chosen, cfg.experts_held)
+    _, computed = _experts_held_loop(
+        tok, weights, p["w_up"], p["w_down"], order, sizes,
+        _row_tile(cfg, chosen.size), cfg.expert_act)
+    to_held = jnp.sum((chosen >= first) & (chosen < first + count),
+                      dtype=jnp.int32)
+    return {"made": jnp.asarray(chosen.size, jnp.int32), "held": to_held,
+            "per_expert": sizes, "dropped": to_held - computed}
+
+
+def sampled_rows(seq_len: int, topk: int, spread: int = 13) -> Tuple[int, ...]:
+    """Query positions `selection_stats` reports in full: the first, three
+    about ``topk`` (the last row that keeps every key, the first that drops
+    one), ``spread`` evenly over the sequence, and the last."""
+    rows = {0, seq_len - 1, topk // 2, topk - 1, topk, topk + 1}
+    rows |= {round(i * (seq_len - 1) / (spread - 1)) for i in range(spread)}
+    return tuple(sorted(r for r in rows if 0 <= r < seq_len))
+
+
+def _select_stats(cfg: HybridConfig, rows, x: jax.Array, p: dict) -> dict:
+    """What one S layer's selection does with its input x, through the
+    layer's own `_selection`: counters over every query, for the query
+    positions ``rows`` the keys picked, the layer's input, and the selection
+    itself."""
+    picked = _selection(cfg, x, p)  # (B, S, S)
+    Bz, S, _ = picked.shape
+    t = jnp.arange(S, dtype=jnp.int32)
+    count = jnp.sum(picked, axis=-1, dtype=jnp.int32)  # (B, S)
+    future = jnp.sum(jnp.where(t[None, :] > t[:, None], picked, 0),
+                     dtype=jnp.int32)
+    return {"selected": jnp.sum(count), "visible": Bz * jnp.sum(t + 1),
+            "future": future,
+            "miscounted": jnp.sum(
+                count != jnp.minimum(t + 1, cfg.indexer_topk),
+                dtype=jnp.int32),
+            "input": x, "picked": picked[:, jnp.asarray(rows, jnp.int32)],
+            "selection": picked}
+
+
+def _layer_stats(cfg: HybridConfig, rows, params: dict, tokens: jax.Array):
+    """``({E layer: _route_stats}, {S layer: _select_stats})`` of one batch
+    from ONE forward pass: both hooks below run this one program, so a
+    model with both kinds of layer compiles one forward pass for them and
+    not two (20 s less a run of the sparse cell; my chip run, PR 32)."""
+    routing, selection = {}, {}
 
     def visit(name, x):
-        if name[-1] != "E":
-            return
-        p = params["layers"][name]
-        h = _rmsnorm(x, p["norm"], cfg.norm_eps).astype(bf16)
-        tok = h.reshape(-1, cfg.d_model)
-        chosen, weights = _route(cfg, tok, p)
-        order, sizes = _dispatch_plan(chosen, cfg.experts_held)
-        _, computed = _experts_held_loop(
-            tok, weights, p["w_up"], p["w_down"], order, sizes,
-            math.gcd(chosen.size, _ROW_TILE))
-        to_held = jnp.sum((chosen >= first) & (chosen < first + count),
-                          dtype=jnp.int32)
-        stats[name] = {"made": jnp.asarray(chosen.size, jnp.int32),
-                       "held": to_held, "per_expert": sizes,
-                       "dropped": to_held - computed}
+        if name[-1] == "E":
+            routing[name] = _route_stats(cfg, x, params["layers"][name])
+        elif name[-1] == "S":
+            selection[name] = _select_stats(cfg, rows, x,
+                                            params["layers"][name])
 
     _stack(cfg, params, tokens, visit=visit)
-    return stats
+    return routing, selection
 
 
-def make_routing_stats(cfg: HybridConfig):
-    """``routing_stats(params, batch) -> {layer: {made, held, per_expert,
-    dropped}}`` as host numbers, and the same into the metrics registry. One
-    jitted forward pass; never part of the train step."""
-    run = jax.jit(partial(_routing_stats, cfg))
+def make_layer_stats(cfg: HybridConfig):
+    """The two hooks of a model over one jitted forward pass (`_layer_
+    stats`), neither ever part of the train step.
+
+    ``routing_stats(params, batch) -> {layer: {made, held, per_expert,
+    dropped}}`` as host numbers, and the same into the metrics registry.
+
+    ``selection_stats(params, batch, whole=False) -> {layer: {...}}`` for a
+    model with S layers, else None. Per layer, as host numbers: ``rows``
+    (the query positions sampled: `sampled_rows`), ``picked`` (B, rows, S)
+    int8 (the keys those queries attend to), ``input`` (B, S, D) (the
+    layer's input, what the indexer saw), and the counters ``selected``,
+    ``visible``, ``future`` and ``miscounted`` over every query of the
+    batch, which also go into the metrics registry. With ``whole`` also
+    ``selection``: the layer's whole selection (B, S, S) int8, left ON THE
+    DEVICE (a byte a pair: 256 MiB a layer at 16k)."""
+    rows = sampled_rows(cfg.seq_len, cfg.indexer_topk) \
+        if "S" in cfg.pattern else ()
+    run = jax.jit(partial(_layer_stats, cfg, rows))
 
     def routing_stats(params, batch) -> Dict[str, dict]:
-        got = jax.device_get(run(params, batch["tokens"]))
+        got = jax.device_get(run(params, batch["tokens"])[0])
         out = {}
         for layer, s in got.items():
             per = [int(n) for n in s["per_expert"]]
@@ -667,7 +944,24 @@ def make_routing_stats(cfg: HybridConfig):
                                      expert=str(cfg.experts_first + i))
         return out
 
-    return routing_stats
+    def selection_stats(params, batch, whole: bool = False) -> Dict[str, dict]:
+        out = {}
+        for layer, st in run(params, batch["tokens"])[1].items():
+            selection = st.pop("selection")
+            st = jax.device_get(st)
+            out[layer] = dict(
+                {k: int(st[k]) for k in ("selected", "visible", "future",
+                                         "miscounted")},
+                rows=rows, picked=st["picked"], input=st["input"])
+            if whole:
+                out[layer]["selection"] = selection
+            _M_KEYS_SELECTED.inc(out[layer]["selected"], layer=layer)
+            _M_KEYS_VISIBLE.inc(out[layer]["visible"], layer=layer)
+            _M_KEYS_FUTURE.inc(out[layer]["future"], layer=layer)
+            _M_ROWS_MISCOUNTED.inc(out[layer]["miscounted"], layer=layer)
+        return out
+
+    return routing_stats, selection_stats if rows else None
 
 
 # -- the Model ------------------------------------------------------------------------
@@ -684,8 +978,10 @@ def synthetic_batch(cfg: HybridConfig, rng: np.random.Generator,
 def forward_flops_per_token(cfg: HybridConfig) -> Dict[str, float]:
     """Model FLOPs of one token's forward pass by layer kind (one layer of
     it) and for the head: matmuls only, products under a causal mask halved
-    (attention's, and the SSD's within a chunk), routed experts at ``top_k
-    x held / published`` of a token."""
+    (attention's, the indexer's scores, and the SSD's within a chunk), an S
+    layer's attention over the keys selected alone with its indexer apart
+    (``indexer``: it runs forward only), routed experts at ``top_k x held /
+    published`` of a token."""
     D, S = cfg.d_model, cfg.seq_len
     H, Pd, G, N, Q = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
                       cfg.state_size, cfg.chunk_size)
@@ -693,13 +989,19 @@ def forward_flops_per_token(cfg: HybridConfig) -> Dict[str, float]:
     q = cfg.n_heads * cfg.head_dim
     kv = cfg.n_kv_heads * cfg.head_dim
     ssd = 0.5 * (2 * Q * N * G + 2 * Q * Pd * H) + 2 * (2 * Pd * N * H)
+    Hi, Di, K = cfg.indexer_heads, cfg.indexer_head_dim, cfg.indexer_topk
+    # keys a query attends to under the selection, averaged over positions
+    kept = (min(K, S) * (min(K, S) + 1) / 2 + max(S - K, 0) * K) / S
+    mats = 3 if cfg.expert_act == "silu" else 2
     return {
+        "S": 2 * D * (q + 2 * kv) + 2 * q * D + 4 * kept * q,
+        "indexer": 2 * D * (Hi * Di + Di + Hi) + 0.5 * 2 * S * Hi * Di,
         "M": 2 * D * (inner + cfg.conv_dim + H) + 2 * cfg.conv_kernel
         * cfg.conv_dim + ssd + 2 * inner * D,
         "*": 2 * D * (q + 2 * kv) + 2 * q * D + 0.5 * 4 * S * q,
         "E": 2 * D * cfg.n_experts + 4 * D * cfg.shared_width
         + cfg.top_k * cfg.experts_count / cfg.n_experts
-        * 4 * D * cfg.expert_width,
+        * 2 * mats * D * cfg.expert_width,
         "-": 4 * D * cfg.mlp_width,
         "head": 2 * D * cfg.vocab_size,
     }
@@ -707,15 +1009,18 @@ def forward_flops_per_token(cfg: HybridConfig) -> Dict[str, float]:
 
 def _flops_per_step(cfg: HybridConfig, batch_size: int) -> float:
     """Train-step model FLOPs (`models.base` convention: backward twice the
-    forward, recompute not counted)."""
+    forward, recompute not counted; an S layer's indexer takes no gradient
+    and counts its forward pass alone)."""
     per = forward_flops_per_token(cfg)
     forward = sum(per[kind] for kind in cfg.pattern) + per["head"]
-    return 3.0 * forward * cfg.seq_len * batch_size
+    return (3.0 * forward + cfg.pattern.count("S") * per["indexer"]) \
+        * cfg.seq_len * batch_size
 
 
 def make_model(cfg: Optional[HybridConfig] = None, **overrides) -> Model:
     cfg = cfg or HybridConfig(**overrides)
     _check(cfg)
+    routing_stats, selection_stats = make_layer_stats(cfg)
     return Model(
         name="hybrid",
         init=lambda key, mesh: _init(cfg, key, mesh),
@@ -726,7 +1031,8 @@ def make_model(cfg: Optional[HybridConfig] = None, **overrides) -> Model:
         label_keys=("targets",),
         config=cfg,
         flops_per_step=lambda bs: _flops_per_step(cfg, bs),
-        routing_stats=make_routing_stats(cfg),
+        routing_stats=routing_stats,
+        selection_stats=selection_stats,
     )
 
 

@@ -31,6 +31,16 @@ compare — the kernel is the within-block engine; `ppermute` stays the
 between-device engine. Every causal bound in a kernel is computed from them
 at run time.
 
+A SELECTION (``flash_attention(..., selection=...)``, PR 32) narrows the
+mask to pairs a caller chose, the same for every head: one more operand, a
+byte a (query, key) pair, which each kernel reads tile by tile where it
+makes its causal mask (`_chosen`), laid out as the kernel's tiles are (keys
+on sublanes for `flash_fwd` and `flash_bwd_dkv`, which read its transpose;
+queries on sublanes for `flash_bwd_dq`). The loops' trip counts do not know
+it: every tile up to the causal diagonal is computed, and a row's softmax and
+gradients see the selected causal pairs alone. Without the operand nothing
+of this is traced, and the kernels are the programs they were.
+
 Backward is the standard two-kernel flash recipe: forward also emits the
 per-row logsumexp ``L = m + log(den)``; backward recomputes ``P = exp(S -
 L)`` tile by tile (never storing it) with ``delta = rowsum(dO * O)`` folded
@@ -153,14 +163,18 @@ def _span(rows: int, blk: int) -> int:
     return blk * max(d for d in range(1, most + 1) if tiles % d == 0)
 
 
-def _params(blk_q, blk_k, span_q, span_k, lanes, heads):
+def _params(blk_q, blk_k, span_q, span_k, lanes, heads, selected=False):
     """Compiler parameters of one kernel, from its extents. The scoped-VMEM
     request counts every block a step may hold (two operands on each side,
     double-buffered, at four bytes an element, ``lanes`` wide: all the
     block's ``heads``) and eight f32 temporaries of a tile for each of two
     heads in flight, doubled for what the compiler adds; 32 MiB at least
-    (v5e's default scope is 16 of its 128) and 96 at most."""
+    (v5e's default scope is 16 of its 128) and 96 at most. ``selected``
+    adds the selection's block, a byte a (query, key) pair of the step's
+    owner by its span, double-buffered."""
     blocks = 2 * 2 * 4 * lanes * (blk_q + blk_k + span_q + span_k)
+    if selected:
+        blocks += 2 * max(blk_q * span_k, blk_k * span_q)
     tiles = min(heads, 2) * 8 * 4 * blk_q * blk_k
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -188,6 +202,16 @@ def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
         seen = k_idx - q_idx <= diag
         valid = seen if valid is None else jnp.logical_and(valid, seen)
     return valid
+
+
+def _chosen(valid, picked):
+    """``valid`` (`_visible`'s, or None) narrowed to the pairs a selection
+    kept: ``picked`` is the tile of the selection operand, a byte a pair,
+    non-zero where the query attends to the key, laid out as the tile's
+    scores are. Widened to 32 bits first, so that the mask has the layout of
+    the float32 scores it selects among."""
+    picked = picked.astype(jnp.int32) != 0
+    return picked if valid is None else jnp.logical_and(valid, picked)
 
 
 def _nt(a, b):
@@ -267,9 +291,12 @@ def _heads(ref, head_dim):
 # block's (blk_q, W).
 
 
-def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
-                o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                *, scale, causal, k_len, blk_q, blk_k, head_dim):
+def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
+                scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+    # the selection's block, (1, span_k, blk_q) of the TRANSPOSED selection,
+    # stands after v where the call has one
+    sel_ref = rest[0] if selected else None
+    o_ref, lse_ref, m_ref, l_ref, acc_ref = rest[1:] if selected else rest
     qi, si = pl.program_id(2), pl.program_id(3)
     n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
@@ -292,6 +319,8 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref,
         valid = _visible((blk_k, blk_q), 0, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
                          ragged=k_len % blk_k != 0)
+        if sel_ref is not None:
+            valid = _chosen(valid, sel_ref[0, rows, :])
         for h, lanes in enumerate(heads):
             k = k_ref[0, rows, lanes]  # (blk_k, D)
             v = v_ref[0, rows, lanes]
@@ -341,15 +370,29 @@ def _slab(rows, lanes, axis):
                         lambda *at: (at[0], at[axis], at[1]))
 
 
+def _pairs(q_rows, q_axis, k_rows, k_axis, transposed):
+    """A block of the selection, a byte a (query, key) pair over (B, Sq, Sk),
+    or over (B, Sk, Sq) where ``transposed``: ``q_rows`` queries by grid
+    axis ``q_axis`` and ``k_rows`` keys by ``k_axis``, the same for every
+    head."""
+    if transposed:
+        return pl.BlockSpec((1, k_rows, q_rows),
+                            lambda *at: (at[0], at[k_axis], at[q_axis]))
+    return pl.BlockSpec((1, q_rows, k_rows),
+                        lambda *at: (at[0], at[q_axis], at[k_axis]))
+
+
 def _stat_rows(rows, heads, slabs, axis):
     """The statistics rows of a slab's ``heads`` over (B*H, 1, S)."""
     return pl.BlockSpec((heads, 1, rows),
                         lambda *at: (at[0] * slabs + at[1], 0, at[axis]))
 
 
-def _fwd(q, k, v, qo, ko, *, scale, causal, k_len, blk_q, blk_k, head_dim,
-         out_dtype):
-    """q: (B, Sq, H*D); k/v: (B, Sk, H*D) -> (o, lse (B*H, 1, Sq) f32)."""
+def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
+         blk_k, head_dim, out_dtype):
+    """q: (B, Sq, H*D); k/v: (B, Sk, H*D) -> (o, lse (B*H, 1, Sq) f32).
+    ``selection``, where there is one: ``(pairs (B, Sq, Sk), the same
+    transposed)``, a byte a pair."""
     B, Sq, HD = q.shape
     Sk = k.shape[1]
     H = HD // head_dim
@@ -358,13 +401,15 @@ def _fwd(q, k, v, qo, ko, *, scale, causal, k_len, blk_q, blk_k, head_dim,
     span_k = _span(Sk, blk_k)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
     q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
+    chosen = [] if selection is None else [selection[1]]
     return _pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           k_len=k_len, blk_q=blk_q, blk_k=blk_k,
-                          head_dim=head_dim),
+                          head_dim=head_dim, selected=bool(chosen)),
         "flash_fwd",
         grid=(B, slabs, Sq // blk_q, Sk // span_k),
-        in_specs=[scalar, scalar, q_spec, k_spec, k_spec],
+        in_specs=[scalar, scalar, q_spec, k_spec, k_spec]
+        + [_pairs(blk_q, _OWN, span_k, _SPAN, True)] * len(chosen),
         out_specs=[q_spec, _stat_rows(blk_q, g, slabs, _OWN)],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sq, HD), out_dtype),
@@ -375,8 +420,9 @@ def _fwd(q, k, v, qo, ko, *, scale, causal, k_len, blk_q, blk_k, head_dim,
             pltpu.VMEM((g, 1, blk_q), jnp.float32),  # running denominator l
             pltpu.VMEM((W, blk_q), jnp.float32),  # output accumulator, O^T
         ],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g),
-    )(qo, ko, q, k, v)
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g,
+                                bool(chosen)),
+    )(qo, ko, q, k, v, *chosen)
 
 
 # -- backward ------------------------------------------------------------------
@@ -393,8 +439,10 @@ def _fwd(q, k, v, qo, ko, *, scale, causal, k_len, blk_q, blk_k, head_dim,
 
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, delta_ref, dq_ref, acc_ref,
-                   *, scale, causal, k_len, blk_q, blk_k, head_dim):
+                   lse_ref, delta_ref, *rest,
+                   scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+    sel_ref = rest[0] if selected else None  # (1, blk_q, span_k)
+    dq_ref, acc_ref = rest[1:] if selected else rest
     qi, si = pl.program_id(2), pl.program_id(3)
     n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
@@ -418,6 +466,8 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         valid = _visible((blk_q, blk_k), 1, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
                          ragged=k_len % blk_k != 0)
+        if sel_ref is not None:
+            valid = _chosen(valid, sel_ref[0, :, rows])
         for h, lanes in enumerate(heads):
             k = k_ref[0, rows, lanes]  # (blk_k, D)
             v = v_ref[0, rows, lanes]
@@ -439,8 +489,10 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, k_len, blk_q, blk_k, head_dim):
+                    lse_ref, delta_ref, *rest,
+                    scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+    sel_ref = rest[0] if selected else None  # (1, blk_k, span_q), transposed
+    dk_ref, dv_ref, dk_acc, dv_acc = rest[1:] if selected else rest
     ki, si = pl.program_id(2), pl.program_id(3)  # note: K outer, Q streams
     n_s = pl.num_programs(3)
     span_q = q_ref.shape[1]
@@ -462,6 +514,8 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         valid = _visible((blk_k, blk_q), 0, diag=q_first + at - k_first,
                          k_left=k_len - ki * blk_k, causal=causal,
                          ragged=k_len % blk_k != 0)
+        if sel_ref is not None:
+            valid = _chosen(valid, sel_ref[0, :, rows])
         for h, lanes in enumerate(heads):
             q = q_ref[0, rows, lanes]  # (blk_q, D)
             do = do_ref[0, rows, lanes]
@@ -490,8 +544,8 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
             dv_ref[0, :, lanes] = dv_acc[h].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
-         blk_q, blk_k, head_dim):
+def _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection=None, *, scale,
+         causal, k_len, blk_q, blk_k, head_dim):
     B, Sq, HD = q.shape
     Sk = k.shape[1]
     H = HD // head_dim
@@ -512,8 +566,9 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     ).reshape(B * H, 1, Sq) - dlse.astype(jnp.float32)
-    kernel_kw = dict(scale=scale, causal=causal, k_len=k_len,
-                     blk_q=blk_q, blk_k=blk_k, head_dim=head_dim)
+    selected = selection is not None
+    kernel_kw = dict(scale=scale, causal=causal, k_len=k_len, blk_q=blk_q,
+                     blk_k=blk_k, head_dim=head_dim, selected=selected)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
@@ -523,12 +578,13 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
         "flash_bwd_dq",
         grid=(B, slabs, Sq // blk_q, Sk // span_k),
         in_specs=[scalar, scalar,
-                  q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+                  q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
+        + [_pairs(blk_q, _OWN, span_k, _SPAN, False)] * selected,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
         scratch_shapes=[pltpu.VMEM((g, blk_q, head_dim), jnp.float32)],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g),
-    )(qo, ko, q, k, v, do, lse, delta)
+        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g, selected),
+    )(qo, ko, q, k, v, do, lse, delta, *(selection or ())[:1])
 
     # K outer / Q streams: the accumulators belong to the K block.
     q_spec, k_spec = _slab(span_q, W, _SPAN), _slab(blk_k, W, _OWN)
@@ -538,7 +594,8 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
         "flash_bwd_dkv",
         grid=(B, slabs, Sk // blk_k, Sq // span_q),
         in_specs=[scalar, scalar,
-                  q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+                  q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
+        + [_pairs(span_q, _SPAN, blk_k, _OWN, True)] * selected,
         out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, Sk, HD), k.dtype),
@@ -548,35 +605,35 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, *, scale, causal, k_len,
             pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
             pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
         ],
-        compiler_params=_params(blk_q, blk_k, span_q, blk_k, W, g),
-    )(qo, ko, q, k, v, do, lse, delta)
+        compiler_params=_params(blk_q, blk_k, span_q, blk_k, W, g, selected),
+    )(qo, ko, q, k, v, do, lse, delta, *(selection or ())[1:])
     return dq, dk, dv
 
 
 # -- public entrypoint ---------------------------------------------------------
 
 
-def _flash_fwd(q, k, v, offsets, scale, causal, k_len, blk_q, blk_k,
-               head_dim, out_dtype):
-    o, lse = _fwd(q, k, v, *offsets, scale=scale, causal=causal,
+def _flash_fwd(q, k, v, offsets, selection, scale, causal, k_len, blk_q,
+               blk_k, head_dim, out_dtype):
+    o, lse = _fwd(q, k, v, *offsets, selection, scale=scale, causal=causal,
                   k_len=k_len, blk_q=blk_q, blk_k=blk_k, head_dim=head_dim,
                   out_dtype=out_dtype)
-    return (o, lse), (q, k, v, o, lse, offsets)
+    return (o, lse), (q, k, v, o, lse, offsets, selection)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(*args):
     return _flash_fwd(*args)[0]
 
 
 def _flash_bwd(scale, causal, k_len, blk_q, blk_k, head_dim, out_dtype, res,
                cts):
-    q, k, v, o, lse, (qo, ko) = res
+    q, k, v, o, lse, (qo, ko), selection = res
     do, dlse = cts
-    dq, dk, dv = _bwd(q, k, v, o, lse, do, dlse, qo, ko, scale=scale,
-                      causal=causal, k_len=k_len, blk_q=blk_q, blk_k=blk_k,
-                      head_dim=head_dim)
-    return dq, dk, dv, None
+    dq, dk, dv = _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection,
+                      scale=scale, causal=causal, k_len=k_len, blk_q=blk_q,
+                      blk_k=blk_k, head_dim=head_dim)
+    return dq, dk, dv, None, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -611,8 +668,19 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     return_lse: bool = False,
+    selection: Optional[jax.Array] = None,
 ):
     """Blockwise-online attention. q: (B, Sq, H, D); k/v: (B, Sk, H, D).
+
+    ``selection`` (B, Sq, Sk), a byte a pair (int8), non-zero where the
+    query attends to the key, the same for every head: the softmax of a row
+    and both backward kernels then run over the pairs that are selected AND
+    pass the causal test, and see no other. It carries no gradient. The
+    kernels read it tile by tile beside the causal test, `flash_fwd` and
+    `flash_bwd_dkv` from its transpose (one XLA transpose here, where their
+    tiles have keys on sublanes). Every tile up to the causal diagonal is
+    still computed: a selection saves no arithmetic. Left out, the kernels'
+    programs are the ones they were without this operand.
 
     ``q_offset``/``k_offset`` are the GLOBAL positions of row 0 (ints or
     traced scalars) — sequence-parallel callers pass their shard offsets
@@ -671,8 +739,12 @@ def flash_attention(
     # merge at accumulator precision and the CALLER downcasts once after
     # the final merge — the same discipline the einsum ring engine had.
     out_dtype = jnp.float32 if return_lse else q.dtype
-    o2, lse = _flash(q2, k2, v2, offsets, scale, causal, Sk, blk_q, blk_k, D,
-                     jnp.dtype(out_dtype))
+    if selection is not None:
+        pairs = jnp.pad(selection.astype(jnp.int8), (
+            (0, 0), (0, q2.shape[1] - Sq), (0, k2.shape[1] - Sk)))
+        selection = (pairs, pairs.swapaxes(1, 2))
+    o2, lse = _flash(q2, k2, v2, offsets, selection, scale, causal, Sk, blk_q,
+                     blk_k, D, jnp.dtype(out_dtype))
     out = o2[:, :Sq].reshape(B, Sq, H, D)
     if not return_lse:
         return out

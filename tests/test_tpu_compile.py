@@ -318,3 +318,90 @@ def test_hybrid_train_step_at_the_cell(topo):
     assert mem.temp_size_in_bytes <= 3.8585 * 2**30
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         < 15.75 * 2**30
+
+
+def test_sparse_train_step_at_the_cell(topo):
+    """`train_keyevl2_1chip`'s step: the configuration file's widths (six
+    published layers as `SESESESESESE`, 16 of 128 gated experts held, 18,992
+    rows of the vocabulary) at the cell's 1 x 16,384 tokens, per-layer
+    remat, Adam. It compiles for the described v5e: the flash kernels at (1,
+    16384, 32, 128) WITH their int8 selection operand through Mosaic (a
+    block of 512 x 8,192 bytes beside K and V), the selection's two kernels
+    `indexer_scores` and `top_k_select` (`ops/sparse_select.py`), the
+    grouped product over one pass of 32,768 rows a layer; temporaries and
+    arguments fit the chip's 15.75 GiB (PR 32's reading: temp 5.017 GiB +
+    arguments 7.367 GiB; 6.480 with the selection in plain XLA)."""
+    import json
+    import os
+
+    from edl_tpu.models import resolve
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic", "fixed_b1_s16384.json")) as f:
+        traffic = json.load(f)
+    sizes = {ours: config[theirs]
+             for theirs, ours in config["maps_to"].items()}
+    model = resolve(config["model"], dict(sizes, seq_len=traffic["seq_len"],
+                                          remat=True))
+    compiled = _compiled_cell_step(topo, model, traffic["batch"],
+                                   traffic["seq_len"])
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                   "indexer_scores", "top_k_select"):
+        assert kernel in text
+    assert "tpu_custom_call" in text
+    assert "flash_attention_interpreted" not in text
+    mem = compiled.memory_analysis()
+    print(f"sparse step for the described v5e: temp "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
+    assert mem.argument_size_in_bytes > 7.3 * 2**30  # the 7.91 GB of state
+    assert mem.temp_size_in_bytes <= 5.1 * 2**30
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        < 15.75 * 2**30
+
+
+@pytest.mark.parametrize("batch,seq,heads,head_dim", [
+    (1, 16384, 32, 128), (2, 1024, 16, 64), (1, 200, 16, 64)])
+def test_flash_with_a_selection_compiles(one_chip, batch, seq, heads,
+                                         head_dim):
+    """The three kernels with the selection operand (a byte a pair, the same
+    for every head) through Mosaic: at the sparse cell's shape, with two
+    heads of 64 to a block, and padded to one tile."""
+    q, k, v = _qkv(one_chip, seq, head_dim, batch, heads)
+    picked = jax.ShapeDtypeStruct((batch, seq, seq), jnp.int8,
+                                  sharding=one_chip)
+
+    def loss(q, k, v, picked):
+        return jnp.sum(flash_attention(q, k, v, selection=picked)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, k, v, picked).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    assert "flash_attention_interpreted" not in text
+
+
+@pytest.mark.parametrize("seq, heads", [(16384, 16), (128, 4)])
+def test_selection_kernels_compile(one_chip, seq, heads):
+    """`indexer_scores` and `top_k_select` through Mosaic at the sparse
+    cell's shape (16 heads of 64 on one key head, 16,384 positions, all the
+    keys of 128 queries in one VMEM block) and at one lane row."""
+    from edl_tpu.ops.sparse_select import indexer_scores, top_k_select
+
+    def of(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def select(qI, kI, w):
+        return top_k_select(indexer_scores(qI, kI, w), 2048)
+
+    text = jax.jit(select).lower(
+        of((1, seq, heads, 64), jnp.bfloat16), of((1, seq, 64), jnp.bfloat16),
+        of((1, seq, heads), jnp.float32)).compile().as_text()
+    assert "indexer_scores" in text and "top_k_select" in text
+    assert "flash_attention_interpreted" not in text
