@@ -11,7 +11,8 @@ hybrids; equations in each function's docstring):
 - ``M``  a Mamba-2 mixer: causal depthwise conv, the SSD chunked scan and
   its backward (the Pallas kernels of `ops.ssd`), a grouped gated RMS norm;
 - ``*``  grouped-query causal attention without positional encoding, through
-  `ops.flash_attention` with K and V repeated to the query heads outside it;
+  `ops.flash_attention`, which takes K and V as projected (a K/V head's
+  group of query heads to a grid step; nothing repeats them);
 - ``E``  an expert layer: sigmoid router with a selection bias over ALL the
   published experts, top-k, renormalised and scaled; non-gated relu^2
   experts; one shared expert. The layer is told which experts it holds
@@ -395,8 +396,9 @@ def _attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
     """``q = h W_q`` (n_heads x head_dim), ``k, v = h W_k, h W_v`` (n_kv_heads
     x head_dim), no bias, no positional encoding; causal softmax of ``q k^T /
     sqrt(head_dim)``, query head j on K/V head ``j // (n_heads/n_kv_heads)``;
-    ``out = a W_o``. K and V are repeated to the query heads outside the
-    kernel, which is exact."""
+    ``out = a W_o``. The flash kernels take K and V as projected, a K/V
+    head's group of query heads to a grid step; the plain path repeats them
+    to the query heads, which is exact."""
     Bz, S, _ = h.shape
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("attn_proj"):
@@ -404,7 +406,6 @@ def _attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
         k = _mm("bsd,de->bse", h, p["wk"], out=bf16).reshape(Bz, S, Hkv, Dh)
         v = _mm("bsd,de->bse", h, p["wv"], out=bf16).reshape(Bz, S, Hkv, Dh)
     with jax.named_scope("attn_core"):
-        k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
         scale = 1.0 / math.sqrt(Dh)
         if cfg.flash:
             from edl_tpu.ops import flash_attention
@@ -413,6 +414,7 @@ def _attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
         else:
             from edl_tpu.parallel.ring_attention import dense_attention
 
+            k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
             a = dense_attention(q, k, v, causal=True, scale=scale)
     with jax.named_scope("attn_proj"):
         return _mm("bse,ed->bsd", a.reshape(Bz, S, Hq * Dh), p["wo"])
@@ -517,14 +519,14 @@ def _sparse_attention(cfg: HybridConfig, h: jax.Array, p: dict,
         k = _rope(_rmsnorm(k, p["k_norm"], cfg.norm_eps),
                   cfg.rope_theta).astype(bf16)
     with jax.named_scope("attn_core"):
-        k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
         scale = 1.0 / math.sqrt(Dh)
-        if cfg.flash:
+        if cfg.flash:  # K and V as projected: a group a grid step
             from edl_tpu.ops import flash_attention
 
             a = flash_attention(q, k, v, causal=True, scale=scale,
                                 selection=selection)
         else:  # explicit scores under the selection's mask
+            k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
             s = _mm("bqhd,bkhd->bhqk", q, k) * scale
             s = jnp.where(selection[:, None] != 0, s, -jnp.inf)
             a = _mm("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v, out=bf16)

@@ -49,7 +49,9 @@ delta stays one XLA reduction over dO and O where they lie (computing it in
 `flash_bwd_dq`, which holds a query block's dO, was measured and cost that
 kernel more than the reduction takes: PERF.md, PR 30).
 
-Shapes follow the models' convention, q/k/v are (B, S, H, D), and the
+Shapes follow the models' convention, q is (B, S, H, D) and k/v (B, S, Hkv,
+D) with Hkv dividing H (grouped-query attention: query head j reads K/V head
+j // (H / Hkv); see "groups" below, PR 33), and the
 kernels address those arrays as they lie in memory: viewed as (B, S, H*D)
 (a reshape, no operation), a block is (1, rows, W) with W the least common
 multiple of D and 128 lanes where that divides H*D, else all of H*D
@@ -59,7 +61,15 @@ holds g = W / D heads side by side on its lanes: one at D = 128 or 256, two
 at D = 64, every head where H*D is under 128 (the CPU tests). The grid is
 (B, H/g, blocks, spans) and a grid step walks its g heads in a static loop
 over one body, each head's operands read as its D lanes of the block (see
-"heads on lanes" below). Tiles are multiples of 128 rows; unaligned sequence
+"heads on lanes" below). With groups a step's query heads are (part of) one
+K/V head's group and its K/V block that head's lanes of the UNREPEATED array:
+K, V, the selection's block and the mask made from it cross HBM and the VPU
+once a step for all the step's heads, and dK/dV leave as the group's f32 sum
+in k's shape. What a call moves by operand (`_tiling`, logged once a trace)
+at the sparse cell's (1, 16384, 32 on 4, 128) with its selection: `flash_fwd`
+and `flash_bwd_dq` 32 MiB of K/V and 1 GiB of selection (8 and 8 a head a step
+on K/V repeated to 32 heads in two spans), `flash_bwd_dkv` 8 GiB of Q and dO
+and 1 GiB of selection (8 and 8). Tiles are multiples of 128 rows; unaligned sequence
 lengths pad up to the tile: padded KEY rows are masked by a valid-length
 compare; padded QUERY rows produce unobserved garbage and are sliced away.
 
@@ -78,7 +88,7 @@ from __future__ import annotations
 import functools
 import logging
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -147,11 +157,16 @@ def _pad_seq(x: jax.Array, mult: int) -> jax.Array:
 #: the whole sequence is fetched once a head and not once a block of the
 #: owner, and leaves no grid step in the causal future: at S = 4096 spans of
 #: 1024, 2048 and 4096 rows took the forward 8.31, 7.81 and 6.33 ms (chip
-#: sweep, PR 26), and at (2, 8192, 32, 128) spans of 4096 and 8192 rows took
+#: sweep, PR 26), at (2, 8192, 32, 128) spans of 4096 and 8192 rows took
 #: the three kernels 13.05, 14.01, 15.81 and 10.47, 11.40, 13.80 ms (chip
-#: sweep, PR 30). 8192 rows of K and V, double-buffered, are 8 MiB of VMEM
-#: at 128 lanes a block in bf16 and 16 MiB in f32; longer was not measured.
-_MAX_SPAN = 8192
+#: sweep, PR 30), and at the sparse cell's (1, 16384, 32 on 4, 128) with its
+#: selection spans of 8192 and 16384 rows took them 19.63, 21.77, 31.20 and
+#: 18.61, 20.83, 30.05 ms (chip sweep, PR 33: two spans leave a quarter of
+#: the steps in the causal future, each moving its blocks to compute
+#: nothing). 16384 rows of K and V, double-buffered, are 16 MiB of VMEM at
+#: 128 lanes a block in bf16 beside 16 MiB of selection; longer was not
+#: measured.
+_MAX_SPAN = 16384
 
 
 def _span(rows: int, blk: int) -> int:
@@ -163,18 +178,28 @@ def _span(rows: int, blk: int) -> int:
     return blk * max(d for d in range(1, most + 1) if tiles % d == 0)
 
 
-def _params(blk_q, blk_k, span_q, span_k, lanes, heads, selected=False):
-    """Compiler parameters of one kernel, from its extents. The scoped-VMEM
-    request counts every block a step may hold (two operands on each side,
-    double-buffered, at four bytes an element, ``lanes`` wide: all the
-    block's ``heads``) and eight f32 temporaries of a tile for each of two
-    heads in flight, doubled for what the compiler adds; 32 MiB at least
-    (v5e's default scope is 16 of its 128) and 96 at most. ``selected``
-    adds the selection's block, a byte a (query, key) pair of the step's
-    owner by its span, double-buffered."""
-    blocks = 2 * 2 * 4 * lanes * (blk_q + blk_k + span_q + span_k)
+def _block_bytes(blk_q, blk_k, span_q, span_k, q_lanes, k_lanes, itemsize,
+                 selected):
+    """Bytes of VMEM a step's blocks take: two operands on the query side
+    (``q_lanes`` wide: all the block's query heads) and two on the key side
+    (``k_lanes``), each a block and a span of rows, double-buffered, at
+    ``itemsize`` bytes an element; ``selected`` adds the selection's block,
+    a byte a (query, key) pair of the step's owner by its span,
+    double-buffered. The count the scoped-VMEM request is made from
+    (`_params`) and the count `_step_heads` holds a group's block to."""
+    blocks = 2 * 2 * itemsize * (q_lanes * (blk_q + span_q)
+                                 + k_lanes * (blk_k + span_k))
     if selected:
         blocks += 2 * max(blk_q * span_k, blk_k * span_q)
+    return blocks
+
+
+def _params(blocks, blk_q, blk_k, heads):
+    """Compiler parameters of one kernel. The scoped-VMEM request counts
+    every block a step may hold (``blocks``: `_block_bytes`) and eight f32
+    temporaries of a tile for each of two heads in flight, doubled for what
+    the compiler adds; 32 MiB at least (v5e's default scope is 16 of its
+    128) and 96 at most."""
     tiles = min(heads, 2) * 8 * 4 * blk_q * blk_k
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -271,6 +296,101 @@ def _heads(ref, head_dim):
             for at in range(0, ref.shape[2], head_dim)]
 
 
+# -- groups ------------------------------------------------------------------------
+#
+# K and V may have fewer heads than q: (B, Sk, Hkv, D) with Hkv dividing H,
+# query head j on K/V head j // (H / Hkv). Nothing repeats K or V: a grid
+# step's block of query heads is (part of) one K/V head's GROUP, its K/V
+# block that head's D lanes of the unrepeated (B, Sk, Hkv*D) array, chosen by
+# the block spec's index map, so what the group shares crosses HBM once a
+# step for all the step's heads: K, V, the selection's block and the mask
+# made from it. The static loop over the step's query heads reads one K and V
+# tile for all of them. Where a step holds less than the group (`_step_heads`)
+# consecutive steps of the lane axis map to one K/V block index and the
+# pipeline does not fetch it again. `flash_bwd_dkv` owns a key block of the
+# K/V head and sums dK and dV over the group in its f32 scratch, the group's
+# steps INSIDE the span axis: the transposed selection's block keeps its
+# index while they go by. With Hkv = H a group is one head and every block,
+# grid and index map is what it was without groups.
+
+#: Most bytes of blocks (`_block_bytes`) a step may hold where the number of
+#: a group's heads in a step is a choice. `flash_fwd` and `flash_bwd_dq` hold
+#: any group met so far whole (42.5 MB at the sparse cell: K, V and the
+#: selection of a 16,384-row span, eight heads' q and o); it is
+#: `flash_bwd_dkv`, whose streamed Q and dO spans are the group wide, that it
+#: bounds: four heads of an 8,192-row span (36.7 MB; eight are 72.4, ran, and
+#: took 14.81 for 15.03 ms at the hybrid cell), two of a 16,384-row span
+#: beside its selection (52.4 MB; four are 87 MB and with the tile's
+#: temporaries pass the 96 MiB a kernel may ask for). Chip sweep, PR 33.
+_MAX_BLOCK_BYTES = 64 * 2**20
+
+#: Most query heads of a group one step walks in its static loop: the most
+#: measured. At the hybrid cell's (2, 8192, 32 on 2, 128) 16, 8, 4, 2 and 1
+#: heads a step took `flash_fwd` 9.47, 9.60, 9.88, 10.54, 10.61 ms and
+#: `flash_bwd_dq` 11.65, 11.82, 12.10, 12.75, 13.48; at the sparse cell's 8,
+#: 4, 2, 1 took them 19.63, 21.27, 26.19, 32.95 and 21.77, 23.14, 27.27,
+#: 29.40, `flash_bwd_dkv` (8, 4, 2, 1) 30.29, 31.20, 32.53, 38.86 (chip
+#: sweep, PR 33, host clock around a jitted call). The loop's body is traced
+#: once a head, so a kernel's compile time grows with it (9 s for 4 at 16).
+_MAX_STEP_HEADS = 16
+
+
+def _step_heads(heads, kv_heads, head_dim, itemsize, blk_q, blk_k, span_q,
+                span_k, selected):
+    """``(query heads, K/V heads, bytes of blocks)`` of one grid step of the
+    kernel with these extents. Without groups: `_lanes`' heads of both, and
+    the bytes counted at four an element whatever the operands (the request
+    those programs have had). With a K/V block that is one head (D a multiple
+    of 128 lanes, or one K/V head in all): the most heads of its group, a
+    divisor of the group in whole lane rows, that `_MAX_STEP_HEADS` allows
+    and whose blocks stay within `_MAX_BLOCK_BYTES`, the least such divisor
+    where none does. With a K/V block of several heads (two of 64): those
+    heads' whole groups, which lie side by side in q."""
+    group = heads // kv_heads
+    kv = _lanes(kv_heads, head_dim) // head_dim
+
+    def blocks(q_heads):
+        return _block_bytes(blk_q, blk_k, span_q, span_k, q_heads * head_dim,
+                            kv * head_dim, 4 if group == 1 else itemsize,
+                            selected)
+
+    if group == 1 or kv > 1:
+        return kv * group, kv, blocks(kv * group)
+    whole = [d for d in range(1, group + 1) if group % d == 0
+             and ((d * head_dim) % 128 == 0 or d == group)]
+    fit = [d for d in whole if d <= _MAX_STEP_HEADS
+           and blocks(d) <= _MAX_BLOCK_BYTES]
+    q_heads = max(fit) if fit else min(whole)
+    return q_heads, 1, blocks(q_heads)
+
+
+class _Plan(NamedTuple):
+    """One kernel of a call: the query and K/V heads a step holds, the bytes
+    of its blocks, its grid, and how many blocks of query heads go to one
+    block of K/V heads (consecutive lane blocks that share a K/V block in
+    `flash_fwd` and `flash_bwd_dq`; the steps a span in which
+    `flash_bwd_dkv` walks its K/V heads' groups)."""
+    heads: int
+    kv_heads: int
+    blocks: int
+    grid: tuple
+    ratio: int
+
+
+def _plan(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected, own_keys):
+    """The `_Plan` of `flash_bwd_dkv` (``own_keys``: a step owns a key block
+    and streams spans of queries) or of the other two (it owns a query block
+    and streams spans of keys), at padded lengths ``Sq`` and ``Sk``."""
+    span_q, span_k = ((_span(Sq, blk_q), blk_k) if own_keys
+                      else (blk_q, _span(Sk, blk_k)))
+    g, g_kv, blocks = _step_heads(H, Hkv, D, itemsize, blk_q, blk_k, span_q,
+                                  span_k, selected)
+    ratio = H // g * g_kv // Hkv
+    grid = ((B, Hkv // g_kv, Sk // blk_k, Sq // span_q * ratio) if own_keys
+            else (B, H // g, Sq // blk_q, Sk // span_k))
+    return _Plan(g, g_kv, blocks, grid, ratio)
+
+
 # -- forward -------------------------------------------------------------------
 #
 # Per-query-row statistics (running max, denominator, logsumexp, delta)
@@ -300,7 +420,8 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
     qi, si = pl.program_id(2), pl.program_id(3)
     n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
-    heads = _heads(q_ref, head_dim)
+    heads, kv_heads = _heads(q_ref, head_dim), _heads(k_ref, head_dim)
+    per = len(heads) // len(kv_heads)  # query heads on each K/V head
 
     @pl.when(si == 0)
     def _init():
@@ -322,8 +443,9 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
         if sel_ref is not None:
             valid = _chosen(valid, sel_ref[0, rows, :])
         for h, lanes in enumerate(heads):
-            k = k_ref[0, rows, lanes]  # (blk_k, D)
-            v = v_ref[0, rows, lanes]
+            if h % per == 0:  # the K/V head's tile, once for its query heads
+                k = k_ref[0, rows, kv_heads[h // per]]  # (blk_k, D)
+                v = v_ref[0, rows, kv_heads[h // per]]
             s_t = _nt(k, q[h]) * scale  # (blk_k, blk_q) f32
             if valid is not None:
                 s_t = jnp.where(valid, s_t, _NEG_INF)
@@ -359,55 +481,82 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
 
 #: Grid axes of every call: batch row, block of lanes, block of the operand
 #: that OWNS the step (queries in `flash_fwd` and `flash_bwd_dq`, keys in
-#: `flash_bwd_dkv`), span of the operand that streams past it.
+#: `flash_bwd_dkv`), span of the operand that streams past it. The lane
+#: axis counts blocks of QUERY heads in `flash_fwd` and `flash_bwd_dq` and
+#: blocks of K/V heads in `flash_bwd_dkv`, whose span axis also counts the
+#: ``walk`` steps in which it walks those heads' groups a span (1 where a
+#: step holds the groups whole, and without groups).
 _OWN, _SPAN = 2, 3
 
 
-def _slab(rows, lanes, axis):
+def _span_at(step, walk):
+    """The span of a step of the span axis."""
+    return step if walk == 1 else step // walk
+
+
+def _lanes_at(at, shared, walk):
+    """The block of lanes of an operand at grid position ``at``: the lane
+    axis's, or, for a K/V block under ``shared`` consecutive blocks of its
+    group's query heads, the one they share; for a query-side block of
+    `flash_bwd_dkv` walking a group in ``walk`` steps, the step's."""
+    if walk != 1:
+        return at[1] * walk + at[_SPAN] % walk
+    return at[1] if shared == 1 else at[1] // shared
+
+
+def _rows_at(at, axis, walk):
+    """The block of rows along grid ``axis`` at grid position ``at``."""
+    return _span_at(at[axis], walk) if axis == _SPAN else at[axis]
+
+
+def _slab(rows, lanes, axis, shared=1, walk=1):
     """``rows`` x ``lanes`` of a (B, S, H*D) array, the rows by grid
-    ``axis``."""
-    return pl.BlockSpec((1, rows, lanes),
-                        lambda *at: (at[0], at[axis], at[1]))
+    ``axis``, the lanes by `_lanes_at`."""
+    return pl.BlockSpec((1, rows, lanes), lambda *at: (
+        at[0], _rows_at(at, axis, walk), _lanes_at(at, shared, walk)))
 
 
-def _pairs(q_rows, q_axis, k_rows, k_axis, transposed):
+def _pairs(q_rows, q_axis, k_rows, k_axis, transposed, walk=1):
     """A block of the selection, a byte a (query, key) pair over (B, Sq, Sk),
     or over (B, Sk, Sq) where ``transposed``: ``q_rows`` queries by grid
     axis ``q_axis`` and ``k_rows`` keys by ``k_axis``, the same for every
     head."""
     if transposed:
-        return pl.BlockSpec((1, k_rows, q_rows),
-                            lambda *at: (at[0], at[k_axis], at[q_axis]))
+        return pl.BlockSpec((1, k_rows, q_rows), lambda *at: (
+            at[0], at[k_axis], _rows_at(at, q_axis, walk)))
     return pl.BlockSpec((1, q_rows, k_rows),
                         lambda *at: (at[0], at[q_axis], at[k_axis]))
 
 
-def _stat_rows(rows, heads, slabs, axis):
-    """The statistics rows of a slab's ``heads`` over (B*H, 1, S)."""
-    return pl.BlockSpec((heads, 1, rows),
-                        lambda *at: (at[0] * slabs + at[1], 0, at[axis]))
+def _stat_rows(rows, heads, slabs, axis, walk=1):
+    """The statistics rows of a block's ``heads`` query heads over (B*H, 1,
+    S), ``slabs`` such blocks a batch row."""
+    return pl.BlockSpec((heads, 1, rows), lambda *at: (
+        at[0] * slabs + _lanes_at(at, 1, walk), 0, _rows_at(at, axis, walk)))
 
 
 def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
          blk_k, head_dim, out_dtype):
-    """q: (B, Sq, H*D); k/v: (B, Sk, H*D) -> (o, lse (B*H, 1, Sq) f32).
+    """q: (B, Sq, H*D); k/v: (B, Sk, Hkv*D) -> (o, lse (B*H, 1, Sq) f32).
     ``selection``, where there is one: ``(pairs (B, Sq, Sk), the same
     transposed)``, a byte a pair."""
     B, Sq, HD = q.shape
     Sk = k.shape[1]
-    H = HD // head_dim
-    W = _lanes(H, head_dim)
-    g, slabs = W // head_dim, HD // W
+    H, Hkv = HD // head_dim, k.shape[2] // head_dim
     span_k = _span(Sk, blk_k)
-    scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
-    q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
     chosen = [] if selection is None else [selection[1]]
+    plan = _plan(B, Sq, Sk, H, Hkv, head_dim, q.dtype.itemsize, blk_q, blk_k,
+                 bool(chosen), own_keys=False)
+    g, W, slabs = plan.heads, plan.heads * head_dim, H // plan.heads
+    scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
+    q_spec = _slab(blk_q, W, _OWN)
+    k_spec = _slab(span_k, plan.kv_heads * head_dim, _SPAN, plan.ratio)
     return _pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           k_len=k_len, blk_q=blk_q, blk_k=blk_k,
                           head_dim=head_dim, selected=bool(chosen)),
         "flash_fwd",
-        grid=(B, slabs, Sq // blk_q, Sk // span_k),
+        grid=plan.grid,
         in_specs=[scalar, scalar, q_spec, k_spec, k_spec]
         + [_pairs(blk_q, _OWN, span_k, _SPAN, True)] * len(chosen),
         out_specs=[q_spec, _stat_rows(blk_q, g, slabs, _OWN)],
@@ -420,8 +569,7 @@ def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
             pltpu.VMEM((g, 1, blk_q), jnp.float32),  # running denominator l
             pltpu.VMEM((W, blk_q), jnp.float32),  # output accumulator, O^T
         ],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g,
-                                bool(chosen)),
+        compiler_params=_params(plan.blocks, blk_q, blk_k, g),
     )(qo, ko, q, k, v, *chosen)
 
 
@@ -446,7 +594,8 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
     qi, si = pl.program_id(2), pl.program_id(3)
     n_s = pl.num_programs(3)
     span_k = k_ref.shape[1]
-    heads = _heads(q_ref, head_dim)
+    heads, kv_heads = _heads(q_ref, head_dim), _heads(k_ref, head_dim)
+    per = len(heads) // len(kv_heads)  # query heads on each K/V head
 
     @pl.when(si == 0)
     def _init():
@@ -468,9 +617,10 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                          ragged=k_len % blk_k != 0)
         if sel_ref is not None:
             valid = _chosen(valid, sel_ref[0, :, rows])
-        for h, lanes in enumerate(heads):
-            k = k_ref[0, rows, lanes]  # (blk_k, D)
-            v = v_ref[0, rows, lanes]
+        for h in range(len(heads)):
+            if h % per == 0:  # the K/V head's tile, once for its query heads
+                k = k_ref[0, rows, kv_heads[h // per]]  # (blk_k, D)
+                v = v_ref[0, rows, kv_heads[h // per]]
             p = jnp.exp(_nt(q[h], k) * scale - lse[h])  # (blk_q, blk_k) f32
             if valid is not None:
                 p = jnp.where(valid, p, 0.0)
@@ -489,24 +639,27 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, *rest,
-                    scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+                    lse_ref, delta_ref, *rest, scale, causal, k_len, blk_q,
+                    blk_k, head_dim, selected, walk):
     sel_ref = rest[0] if selected else None  # (1, blk_k, span_q), transposed
     dk_ref, dv_ref, dk_acc, dv_acc = rest[1:] if selected else rest
-    ki, si = pl.program_id(2), pl.program_id(3)  # note: K outer, Q streams
-    n_s = pl.num_programs(3)
+    # K outer, Q streams; the last axis counts a span's ``walk`` steps
+    # through the K/V heads' groups (1: the step holds them whole)
+    ki, step = pl.program_id(2), pl.program_id(3)
+    si, n_s = _span_at(step, walk), pl.num_programs(3)
     span_q = q_ref.shape[1]
-    heads = _heads(k_ref, head_dim)
+    heads, kv_heads = _heads(q_ref, head_dim), _heads(k_ref, head_dim)
+    per = len(heads) // len(kv_heads)  # the step's query heads a K/V head
 
-    @pl.when(si == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     q_first = qo_ref[0] + si * span_q
     k_first = ko_ref[0] + ki * blk_k
-    k = [k_ref[0, :, lanes] for lanes in heads]  # each (blk_k, D)
-    v = [v_ref[0, :, lanes] for lanes in heads]
+    k = [k_ref[0, :, lanes] for lanes in kv_heads]  # each (blk_k, D)
+    v = [v_ref[0, :, lanes] for lanes in kv_heads]
 
     def tile(j, _):
         at = pl.multiple_of(j * blk_q, blk_q)
@@ -521,14 +674,16 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
             do = do_ref[0, rows, lanes]
             lse = lse_ref[h, :, rows]  # (1, blk_q)
             delta = delta_ref[h, :, rows]
-            p_t = jnp.exp(_nt(k[h], q) * scale - lse)  # (blk_k, blk_q) f32
+            # (blk_k, blk_q) f32
+            p_t = jnp.exp(_nt(k[h // per], q) * scale - lse)
             if valid is not None:
                 p_t = jnp.where(valid, p_t, 0.0)
-            ds_t = p_t * (_nt(v[h], do) - delta)
-            dv_acc[h] += jnp.dot(p_t.astype(do.dtype), do,
-                                 preferred_element_type=jnp.float32)  # P^T dO
-            dk_acc[h] += jnp.dot(ds_t.astype(q.dtype), q,
-                                 preferred_element_type=jnp.float32)  # dS^T Q
+            ds_t = p_t * (_nt(v[h // per], do) - delta)
+            # a K/V head's dV = P^T dO and dK = dS^T Q, summed over its group
+            dv_acc[h // per] += jnp.dot(p_t.astype(do.dtype), do,
+                                        preferred_element_type=jnp.float32)
+            dk_acc[h // per] += jnp.dot(ds_t.astype(q.dtype), q,
+                                        preferred_element_type=jnp.float32)
 
     # The first query tile whose LAST row is at or after the block's first
     # key; every tile before it lies wholly in the keys' past.
@@ -537,9 +692,9 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         first = jnp.clip(k_first - q_first, 0, span_q) // blk_q
     jax.lax.fori_loop(first, span_q // blk_q, tile, None)
 
-    @pl.when(si == n_s - 1)
+    @pl.when(step == n_s - 1)
     def _emit():
-        for h, lanes in enumerate(heads):
+        for h, lanes in enumerate(kv_heads):
             dk_ref[0, :, lanes] = (dk_acc[h] * scale).astype(dk_ref.dtype)
             dv_ref[0, :, lanes] = dv_acc[h].astype(dv_ref.dtype)
 
@@ -548,9 +703,7 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection=None, *, scale,
          causal, k_len, blk_q, blk_k, head_dim):
     B, Sq, HD = q.shape
     Sk = k.shape[1]
-    H = HD // head_dim
-    W = _lanes(H, head_dim)
-    g, slabs = W // head_dim, HD // W
+    H, Hkv = HD // head_dim, k.shape[2] // head_dim
     span_q, span_k = _span(Sq, blk_q), _span(Sk, blk_k)
     # dL/ds_ij = p_ij (dp_ij - delta_i) for the out path PLUS p_ij * dlse_i
     # for the lse path (dlse/ds = softmax row) — the lse cotangent folds
@@ -571,43 +724,99 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection=None, *, scale,
                      blk_k=blk_k, head_dim=head_dim, selected=selected)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    q_spec, k_spec = _slab(blk_q, W, _OWN), _slab(span_k, W, _SPAN)
+    plan = functools.partial(_plan, B, Sq, Sk, H, Hkv, head_dim,
+                             q.dtype.itemsize, blk_q, blk_k, selected)
+
+    own = plan(own_keys=False)
+    g, W, slabs = own.heads, own.heads * head_dim, H // own.heads
+    q_spec = _slab(blk_q, W, _OWN)
+    k_spec = _slab(span_k, own.kv_heads * head_dim, _SPAN, own.ratio)
     row_spec = _stat_rows(blk_q, g, slabs, _OWN)
     dq = _pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_kw),
         "flash_bwd_dq",
-        grid=(B, slabs, Sq // blk_q, Sk // span_k),
+        grid=own.grid,
         in_specs=[scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
         + [_pairs(blk_q, _OWN, span_k, _SPAN, False)] * selected,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B, Sq, HD), q.dtype),
         scratch_shapes=[pltpu.VMEM((g, blk_q, head_dim), jnp.float32)],
-        compiler_params=_params(blk_q, blk_k, blk_q, span_k, W, g, selected),
+        compiler_params=_params(own.blocks, blk_q, blk_k, g),
     )(qo, ko, q, k, v, do, lse, delta, *(selection or ())[:1])
 
-    # K outer / Q streams: the accumulators belong to the K block.
-    q_spec, k_spec = _slab(span_q, W, _SPAN), _slab(blk_k, W, _OWN)
-    row_spec = _stat_rows(span_q, g, slabs, _SPAN)
+    # K outer / Q streams: the accumulators belong to the K block, whose
+    # heads' groups the span axis walks in ``walk`` steps a span.
+    own = plan(own_keys=True)
+    g, g_kv, walk = own.heads, own.kv_heads, own.ratio
+    q_spec = _slab(span_q, g * head_dim, _SPAN, walk=walk)
+    k_spec = _slab(blk_k, g_kv * head_dim, _OWN)
+    row_spec = _stat_rows(span_q, g, H // g, _SPAN, walk)
     dk, dv = _pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kernel_kw),
+        functools.partial(_bwd_dkv_kernel, walk=walk, **kernel_kw),
         "flash_bwd_dkv",
-        grid=(B, slabs, Sk // blk_k, Sq // span_q),
+        grid=own.grid,
         in_specs=[scalar, scalar,
                   q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
-        + [_pairs(span_q, _SPAN, blk_k, _OWN, True)] * selected,
+        + [_pairs(span_q, _SPAN, blk_k, _OWN, True, walk)] * selected,
         out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, HD), k.dtype),
-            jax.ShapeDtypeStruct((B, Sk, HD), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
-            pltpu.VMEM((g, blk_k, head_dim), jnp.float32),
+            pltpu.VMEM((g_kv, blk_k, head_dim), jnp.float32),
+            pltpu.VMEM((g_kv, blk_k, head_dim), jnp.float32),
         ],
-        compiler_params=_params(blk_q, blk_k, span_q, blk_k, W, g, selected),
+        compiler_params=_params(own.blocks, blk_q, blk_k, g),
     )(qo, ko, q, k, v, do, lse, delta, *(selection or ())[1:])
     return dq, dk, dv
+
+
+# -- what a call builds ----------------------------------------------------------
+
+
+def _fetches(grid, sees):
+    """How many times a call's pipeline fetches an operand's block: a step
+    whose block index is the step before's fetches nothing. ``sees`` maps the
+    grid axes the index follows to how many values it takes along each."""
+    live = [axis for axis, values in sees.items() if values > 1]
+    if not live:
+        return 1
+    return math.prod(grid[:max(live)]) * sees[max(live)]
+
+
+def _tiling(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected):
+    """What a call of these (padded) extents builds, by kernel: the grid, the
+    query and K/V heads a step holds, and the MiB a call's pipeline moves
+    between HBM and VMEM by operand. `flash_attention` logs it once a trace;
+    it is the record of whether a group's K, V and selection cross HBM once
+    for the group (`tests/test_tpu_compile.py` pins it at the cells)."""
+    span_q, span_k = _span(Sq, blk_q), _span(Sk, blk_k)
+    plan = functools.partial(_plan, B, Sq, Sk, H, Hkv, D, itemsize, blk_q,
+                             blk_k, selected)
+    MiB = lambda n: round(n / 2**20, 1)
+    out = {"tile": (blk_q, blk_k), "heads": (H, Hkv)}
+    g, g_kv, _, grid, _ = plan(own_keys=False)
+    own = blk_q * g * D * itemsize * math.prod(grid[:3])
+    streamed = {
+        "k+v": 2 * span_k * g_kv * D * itemsize * _fetches(
+            grid, {0: B, 1: Hkv // g_kv, _SPAN: grid[_SPAN]}),
+        "selection": selected * blk_q * span_k * _fetches(
+            grid, {0: B, _OWN: grid[_OWN], _SPAN: grid[_SPAN]})}
+    for name, blocks in (("flash_fwd", {"q+o": 2 * own}),
+                         ("flash_bwd_dq", {"q+do+dq": 3 * own})):
+        out[name] = {"grid": grid, "heads_a_step": (g, g_kv), "MiB": {
+            k: MiB(n) for k, n in {**streamed, **blocks}.items()}}
+    g, g_kv, _, grid, _ = plan(own_keys=True)
+    out["flash_bwd_dkv"] = {"grid": grid, "heads_a_step": (g, g_kv), "MiB": {
+        "q+do": MiB(2 * span_q * g * D * itemsize * _fetches(
+            grid, {0: B, 1: grid[1], _SPAN: grid[_SPAN]})),
+        "selection": MiB(selected * blk_k * span_q * _fetches(
+            grid, {0: B, _OWN: grid[_OWN], _SPAN: Sq // span_q})),
+        "k+v+dk+dv": MiB(4 * blk_k * g_kv * D * itemsize
+                         * math.prod(grid[:3]))}}
+    return out
 
 
 # -- public entrypoint ---------------------------------------------------------
@@ -637,6 +846,17 @@ def _flash_bwd(scale, causal, k_len, blk_q, blk_k, head_dim, out_dtype, res,
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+#: `_flash` for the calls with groups. A kernel whose static loop walks a
+#: group's heads is 8 or 16 bodies to trace, and a model that does not scan
+#: its layers calls it once a layer a pass (24 call sites in the sparse
+#: cell's step, each traced for both engines): under `jax.jit` equal calls
+#: share one trace and one lowering, and XLA inlines them. Tracing and
+#: lowering that step took 9 to 12 s a call site a time and 5 to 6 s so (6
+#: to 7 before groups; the sandbox's CPU, PR 33), and the cell's run does it
+#: three times under a limit of 360 s. Without groups the call stays bare:
+#: those programs' text is what it was.
+_flash_traced_once = jax.jit(_flash, static_argnums=tuple(range(5, 12)))
 
 
 def _round_up(n: int, m: int) -> int:
@@ -670,7 +890,10 @@ def flash_attention(
     return_lse: bool = False,
     selection: Optional[jax.Array] = None,
 ):
-    """Blockwise-online attention. q: (B, Sq, H, D); k/v: (B, Sk, H, D).
+    """Blockwise-online attention. q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D),
+    Hkv dividing H: query head j reads K/V head j // (H / Hkv), and nothing
+    repeats K or V (see "groups"); dK and dV come back in k's and v's shape,
+    each the sum over the head's group, made in f32.
 
     ``selection`` (B, Sq, Sk), a byte a pair (int8), non-zero where the
     query attends to the key, the same for every head: the softmax of a row
@@ -700,13 +923,18 @@ def flash_attention(
     a block `_lanes(H, D)` lanes wide: nothing is transposed or copied on
     the way in or out. Where H*D is no multiple of the least common multiple
     of D and 128 (an odd head count at D = 64, say) a block is ALL heads
-    wide: right, but a span of 8,192 rows of some thousand lanes, twice for
+    wide: right, but a span of 16,384 rows of some thousand lanes, twice for
     K and V and twice for the double buffer, passes the 96 MiB of VMEM a
     kernel may ask for and Mosaic refuses the program when it compiles;
     such a model pads its heads or passes a shorter sequence a call.
     """
     B, Sq, H, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hkv = k.shape[1], k.shape[2]
+    if H % Hkv or v.shape != k.shape:
+        raise ValueError(
+            f"flash_attention: q has {H} heads and k {k.shape}, v {v.shape}: "
+            "k and v want one shape whose heads divide q's (query head j "
+            "reads K/V head j // (H / Hkv))")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     # Tile alignment: both extents are multiples of 128 (scores and their
@@ -718,19 +946,14 @@ def flash_attention(
 
     # heads side by side on the last axis: the arrays as they lie in memory
     q2 = _pad_seq(q.reshape(B, Sq, H * D), blk_q)
-    k2 = _pad_seq(k.reshape(B, Sk, H * D), blk_k)
-    v2 = _pad_seq(v.reshape(B, Sk, H * D), blk_k)
-    # once a trace, for whoever reads a profile: which tiling was built
-    span_q, span_k = _span(q2.shape[1], blk_q), _span(k2.shape[1], blk_k)
-    lanes = _lanes(H, D)
-    slabs = B * (H * D // lanes)  # (batch row, block of lanes) pairs
-    _log.debug(
-        "flash_attention q%s k%s %s: tile %dx%d, %d heads a block; grid "
-        "steps a call: flash_fwd and flash_bwd_dq %d (key span %d), "
-        "flash_bwd_dkv %d (query span %d)", q.shape, k.shape, q.dtype,
-        blk_q, blk_k, lanes // D,
-        slabs * (q2.shape[1] // blk_q) * (k2.shape[1] // span_k), span_k,
-        slabs * (k2.shape[1] // blk_k) * (q2.shape[1] // span_q), span_q)
+    k2 = _pad_seq(k.reshape(B, Sk, Hkv * D), blk_k)
+    v2 = _pad_seq(v.reshape(B, Sk, Hkv * D), blk_k)
+    if _log.isEnabledFor(logging.DEBUG):
+        # once a trace, for whoever reads a profile: which tiling was built
+        _log.debug("flash_attention q%s k%s %s: %s", q.shape, k.shape,
+                   q.dtype, _tiling(B, q2.shape[1], k2.shape[1], H, Hkv, D,
+                                    q.dtype.itemsize, blk_q, blk_k,
+                                    selection is not None))
 
     offsets = (jnp.asarray([q_offset], jnp.int32),
                jnp.asarray([k_offset], jnp.int32))
@@ -743,8 +966,9 @@ def flash_attention(
         pairs = jnp.pad(selection.astype(jnp.int8), (
             (0, 0), (0, q2.shape[1] - Sq), (0, k2.shape[1] - Sk)))
         selection = (pairs, pairs.swapaxes(1, 2))
-    o2, lse = _flash(q2, k2, v2, offsets, selection, scale, causal, Sk, blk_q,
-                     blk_k, D, jnp.dtype(out_dtype))
+    flash = _flash if Hkv == H else _flash_traced_once
+    o2, lse = flash(q2, k2, v2, offsets, selection, scale, causal, Sk, blk_q,
+                    blk_k, D, jnp.dtype(out_dtype))
     out = o2[:, :Sq].reshape(B, Sq, H, D)
     if not return_lse:
         return out

@@ -20,12 +20,20 @@ from edl_tpu.parallel.ring_attention import dense_attention
 fa = importlib.import_module("edl_tpu.ops.flash_attention")
 
 
-def rand_qkv(rng, B, S, H, D, dtype=jnp.float32, Sk=None):
-    Sk = Sk or S
+def rand_qkv(rng, B, S, H, D, dtype=jnp.float32, Sk=None, Hkv=None):
+    Sk, Hkv = Sk or S, Hkv or H
     q = jnp.asarray(rng.standard_normal((B, S, H, D)), dtype)
-    k = jnp.asarray(rng.standard_normal((B, Sk, H, D)), dtype)
-    v = jnp.asarray(rng.standard_normal((B, Sk, H, D)), dtype)
+    k = jnp.asarray(rng.standard_normal((B, Sk, Hkv, D)), dtype)
+    v = jnp.asarray(rng.standard_normal((B, Sk, Hkv, D)), dtype)
     return q, k, v
+
+
+def repeated(q, k, v):
+    """K and V repeated to q's heads, query head j on K/V head j // group:
+    what a caller did before the kernels took groups, and what the oracles
+    take."""
+    group = q.shape[2] // k.shape[2]
+    return q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -243,7 +251,9 @@ def test_randomized_shapes_and_offsets_property():
 
 def positioned_oracle(q, k, v, *, causal, q_off=0, k_off=0):
     """Dense attention over globally positioned scores -> (out, lse). A row
-    that sees no key gives zeros and the kernel's finite sentinel."""
+    that sees no key gives zeros and the kernel's finite sentinel. K and V
+    of fewer heads than q are repeated to them."""
+    q, k, v = repeated(q, k, v)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32)
     s = s / math.sqrt(q.shape[-1])
@@ -317,9 +327,11 @@ def test_spans_shorter_than_the_sequence(monkeypatch, causal):
     _assert_matches_dense(q, k, v, causal=causal, block_q=128, block_k=128)
 
 
-@pytest.mark.parametrize("H,D", [
-    (2, 16),  # every head in one block narrower than a lane row
-    (4, 64),  # two blocks of 128 lanes, two heads each (g = 2)
+@pytest.mark.parametrize("H,Hkv,D", [
+    (2, 2, 16),   # every head in one block narrower than a lane row
+    (4, 4, 64),   # two blocks of 128 lanes, two heads each (g = 2)
+    (4, 2, 64),   # groups of two on a K/V block of two heads of 64
+    (4, 1, 128),  # a group of four on one K/V head of 128
 ])
 @pytest.mark.parametrize("q_off,k_off", [
     (512, 0),    # a hop wholly in the past: every tile live, none masked
@@ -327,13 +339,13 @@ def test_spans_shorter_than_the_sequence(monkeypatch, causal):
     (100, 300),  # partly dead: the first 200 queries see no key
     (300, 100),  # the diagonal crosses the tiles off their corners
 ])
-def test_ring_hops_at_multi_tile_blocks(q_off, k_off, H, D):
+def test_ring_hops_at_multi_tile_blocks(q_off, k_off, H, Hkv, D):
     """What `_ring_flash_local` asks of one hop, at a block of 2 x 2 tiles:
     out and lse against the positioned oracle, and gradients through BOTH
     (the lse cotangent folds into delta). A dead hop is zeros, the
     sentinel, and zero gradients."""
     rng = np.random.default_rng(q_off + k_off)
-    q, k, v = rand_qkv(rng, 1, 512, H, D)
+    q, k, v = rand_qkv(rng, 1, 512, H, D, Hkv=Hkv)
     w = jnp.asarray(rng.standard_normal((1, H, 512)), jnp.float32)
 
     def loss(attend):
@@ -411,30 +423,40 @@ def test_a_block_is_the_least_whole_lane_rows_of_whole_heads(H, D, lanes):
     assert lanes % D == 0 and (H * D) % lanes == 0
 
 
-def _distinct_heads(rng, B, S, H, D):
+def _distinct_heads(rng, B, S, H, D, Hkv=None, Sk=None):
     """q, k, v whose heads differ in scale as well as in their draws: a head
     served from its neighbour's lanes cannot pass for its own."""
-    size = (1.0 + 0.5 * jnp.arange(H, dtype=jnp.float32))[None, None, :, None]
-    return tuple(x * size for x in rand_qkv(rng, B, S, H, D))
+    size = lambda x: (1.0 + 0.5 * jnp.arange(
+        x.shape[2], dtype=jnp.float32))[None, None, :, None]
+    return tuple(x * size(x) for x in rand_qkv(rng, B, S, H, D, Sk=Sk,
+                                               Hkv=Hkv))
 
 
 @pytest.mark.parametrize("return_lse", [False, True])
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("H,D", [
-    (2, 128),  # one head a block, two blocks
-    (4, 64),   # two heads a block, two blocks
-    (2, 64),   # two heads, one block
-    (4, 32),   # four heads a block
-    (3, 16),   # 48 lanes: no whole lane row, every head in one block
-    (2, 256),  # a head of two lane rows
+@pytest.mark.parametrize("H,Hkv,D", [
+    (2, 2, 128),  # one head a block, two blocks
+    (4, 4, 64),   # two heads a block, two blocks
+    (2, 2, 64),   # two heads, one block
+    (4, 4, 32),   # four heads a block
+    (3, 3, 16),   # 48 lanes: no whole lane row, every head in one block
+    (2, 2, 256),  # a head of two lane rows
+    # groups: K/V heads of fewer than q's, nothing repeated
+    (4, 2, 128),   # groups of two, a group a step, two K/V blocks
+    (8, 2, 128),   # groups of four
+    (16, 2, 128),  # groups of eight
+    (8, 4, 64),    # groups of two on K/V blocks of two heads of 64
+    (8, 1, 64),    # one K/V head of 64 (its block the whole array), eight on it
+    (6, 2, 16),    # groups of three in one block of every head
 ])
-def test_heads_on_lanes_match_dense_oracle(H, D, causal, return_lse):
-    """Forward and the three gradients at every way a block can hold heads,
-    two batch rows (the statistics' row index counts both) and two tiles a
-    side. With ``return_lse`` the logsumexp and the gradient through it."""
-    rng = np.random.default_rng(1000 * H + D)
+def test_heads_on_lanes_match_dense_oracle(H, Hkv, D, causal, return_lse):
+    """Forward and the three gradients at every way a block can hold heads
+    and a K/V head its group, two batch rows (the statistics' row index
+    counts both) and two tiles a side. With ``return_lse`` the logsumexp and
+    the gradient through it."""
+    rng = np.random.default_rng(1000 * H + D + (Hkv != H) * Hkv)
     B, S = 2, 256
-    q, k, v = _distinct_heads(rng, B, S, H, D)
+    q, k, v = _distinct_heads(rng, B, S, H, D, Hkv)
     w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
 
     def flash(q, k, v):
@@ -444,7 +466,7 @@ def test_heads_on_lanes_match_dense_oracle(H, D, causal, return_lse):
     def dense(q, k, v):
         if return_lse:
             return positioned_oracle(q, k, v, causal=causal)
-        return dense_attention(q, k, v, causal=causal)
+        return dense_attention(*repeated(q, k, v), causal=causal)
 
     def loss(attend):
         def f(q, k, v):
@@ -496,3 +518,123 @@ def test_no_layout_work_between_the_model_and_the_kernels(H, D, grad):
     seen = list(_primitives_outside_kernels(jax.make_jaxpr(fn)(*qkv).jaxpr))
     assert seen.count("pallas_call") == (6 if grad else 2)  # each x 2 engines
     assert "transpose" not in seen and "pad" not in seen, seen
+
+
+# -- groups --------------------------------------------------------------------------
+#
+# K and V of fewer heads than q (flash_attention.py, "groups") against the
+# SAME kernels on K and V repeated to q's heads, which is what the callers
+# did: a query head's arithmetic is the same tile for tile, so the output,
+# the logsumexp and dQ are equal to the last bit; dK and dV are the group's
+# sum, made in f32 in the kernel's scratch, against the f32 sum of the
+# repeated call's per-head gradients.
+
+GROUP_CASES = [
+    # H, Hkv, D, heads a step at most, selection, dtype
+    (4, 2, 64, 8, False, jnp.float32),    # groups of 2, K/V blocks of two
+    (4, 2, 128, 8, True, jnp.float32),    # groups of 2, a group a step
+    (8, 2, 128, 8, False, jnp.float32),   # groups of 4
+    (8, 2, 128, 2, True, jnp.float32),    # ... two heads a step: two steps
+    (8, 1, 128, 8, True, jnp.float32),    # a group of 8, whole
+    (8, 1, 128, 4, False, jnp.float32),   # ... in two steps
+    (8, 1, 128, 1, True, jnp.float32),    # ... a head a step, eight steps
+    (8, 1, 64, 8, True, jnp.float32),     # a group of 8 at D = 64
+    (16, 2, 64, 8, False, jnp.float32),   # groups of 8 on two K/V heads of 64
+    (8, 2, 128, 8, True, jnp.bfloat16),
+    (8, 1, 64, 2, False, jnp.bfloat16),
+]
+
+
+def _group_sum(g, like):
+    """The per-head gradient of a repeated K or V, summed over each group in
+    f32: (B, Sk, H, D) -> ``like``'s (B, Sk, Hkv, D)."""
+    B, Sk, Hkv, D = like.shape
+    return g.astype(jnp.float32).reshape(B, Sk, Hkv, -1, D).sum(3)
+
+
+@pytest.mark.parametrize("H,Hkv,D,most,selected,dtype", GROUP_CASES)
+@pytest.mark.parametrize("return_lse", [False, True])
+def test_groups_are_the_repeated_call(monkeypatch, H, Hkv, D, most, selected,
+                                      dtype, return_lse):
+    """Causal with offsets that put the diagonal inside a tile, a key length
+    that is no multiple of the tile (padding in the last), two batch rows,
+    with and without a selection, the group whole in a step and in several
+    (`_MAX_STEP_HEADS`), through the logsumexp where it is returned."""
+    monkeypatch.setattr(fa, "_MAX_STEP_HEADS", most)
+    rng = np.random.default_rng(H * D + most)
+    B, Sq, Sk = 2, 256, 300
+    q, k, v = (x.astype(dtype) for x in _distinct_heads(
+        rng, B, Sq, H, D, Hkv, Sk=Sk))
+    probe = jnp.asarray(rng.standard_normal((B, Sq, H, D)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((B, H, Sq)), jnp.float32)
+    picked = None
+    if selected:
+        picked = jnp.asarray(rng.random((B, Sq, Sk)) < 0.4, jnp.int8)
+
+    def attend(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, q_offset=70, k_offset=20, block_q=128,
+            block_k=128, return_lse=return_lse, selection=picked)
+
+    def loss(q, k, v):
+        got = attend(q, k, v)
+        if return_lse:
+            return jnp.sum(got[0] * probe) + jnp.sum(
+                jnp.where(got[1] > -1e29, got[1], 0.0) * w)
+        return jnp.sum(got.astype(jnp.float32) * probe)
+
+    got, want = attend(q, k, v), attend(*repeated(q, k, v))
+    for a, b in zip(*((got, want) if return_lse else ((got,), (want,)))):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+    dq, dk, dv = jax.grad(loss, (0, 1, 2))(q, k, v)
+    rq, rk, rv = jax.grad(loss, (0, 1, 2))(*repeated(q, k, v))
+    assert dk.shape == k.shape and dv.shape == v.shape and dk.dtype == dtype
+    assert np.array_equal(np.asarray(dq, np.float32),
+                          np.asarray(rq, np.float32))
+    for name, a, b in (("dk", dk, _group_sum(rk, k)),
+                       ("dv", dv, _group_sum(rv, v))):
+        b = np.asarray(b)
+        # f32: the same terms in another order; bf16: the repeated call
+        # rounded each head's gradient before the sum, this one the sum
+        tol = 1e-5 if dtype == jnp.float32 else 2.0 ** -7
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), b, rtol=tol,
+            atol=tol * float(np.abs(b).max()), err_msg=name)
+
+
+def test_kv_heads_that_do_not_divide_the_query_heads_are_refused():
+    q, k, v = rand_qkv(np.random.default_rng(0), 1, 128, 6, 16, Hkv=4)
+    with pytest.raises(ValueError, match="heads divide"):
+        flash_attention(q, k, v)
+    with pytest.raises(ValueError, match="one shape"):
+        flash_attention(q, k[:, :, :3], v[:, :, :2])
+
+
+@pytest.mark.parametrize("seq,H,Hkv,D,itemsize,selected,want", [
+    (1024, 16, 16, 64, 2, False, ((2, 2), (2, 2))),   # no groups: `_lanes`
+    (8192, 32, 32, 128, 2, False, ((1, 1), (1, 1))),
+    (1024, 20, 20, 64, 2, False, ((2, 2), (2, 2))),
+    (16384, 32, 4, 128, 2, True, ((8, 1), (2, 1))),   # the sparse cell
+    (8192, 32, 2, 128, 2, False, ((16, 1), (4, 1))),  # the hybrid cell
+    (8192, 64, 2, 128, 2, False, ((16, 1), (4, 1))),  # `_MAX_STEP_HEADS`
+    (8192, 8, 4, 64, 2, False, ((4, 2), (4, 2))),     # K/V blocks of two heads
+    (4096, 8, 1, 64, 4, False, ((8, 1), (8, 1))),     # f32, one K/V head
+    (8192, 8, 1, 64, 4, False, ((8, 1), (4, 1))),     # ... half the heads fit
+    (16384, 8, 1, 64, 4, False, ((8, 1), (2, 1))),    # ... a quarter
+    (1024, 6, 2, 16, 4, False, ((6, 2), (6, 2))),     # every head in one block
+])
+def test_the_heads_a_step_holds_are_read_off_the_call(seq, H, Hkv, D, itemsize,
+                                                      selected, want):
+    """`_step_heads` in tiles of 512: (query heads, K/V heads) of a step of
+    `flash_fwd` and `flash_bwd_dq`, and of `flash_bwd_dkv`, whose Q and dO
+    spans are the larger blocks; no flag and no model's name, the call's
+    shapes alone."""
+    span = fa._span(seq, 512)
+    fwd = fa._step_heads(H, Hkv, D, itemsize, 512, 512, 512, span, selected)
+    dkv = fa._step_heads(H, Hkv, D, itemsize, 512, 512, span, 512, selected)
+    assert (fwd[:2], dkv[:2]) == want
+    for q_heads, kv_heads, _ in (fwd, dkv):
+        assert H % q_heads == 0 and Hkv % kv_heads == 0
+        assert q_heads % kv_heads == 0

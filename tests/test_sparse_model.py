@@ -155,9 +155,15 @@ def test_a_selection_of_every_causal_key_changes_nothing():
 #: without a selection, `_experts_held`, `_route` and `_stack` trace to the
 #: programs they traced to. A PR that changes these programs on purpose
 #: records the new text's hash here and says so.
+#: PR 33 re-recorded "hybrid" ON PURPOSE (it was 12753bf7...f662a039):
+#: `_attention` hands the flash kernels K and V as projected, a K/V head's
+#: group of query heads to a grid step, so the step has no `jnp.repeat` of
+#: K/V, no sum over the repeats, dK/dV leave `flash_bwd_dkv` in k's shape,
+#: and a call with groups goes through `jax.jit` (equal calls share a
+#: trace). "transformer" (no groups) is the parent's of PR 32, untouched.
 LOWERED = {
     "transformer": "6cda28d287b364371755d16242f437e25ef2316df25ea872f8030e7963a0c08d",
-    "hybrid": "12753bf70061c080ec7b1e6097caa73bbd16356543bda0ae2f506311f662a039",
+    "hybrid": "40b71001a88715a7d8406b9a00f401659d669e2d623f656b932f801131658a08",
 }
 
 

@@ -14,6 +14,10 @@ only one process may load the TPU's library, so nothing here may touch it
 at import or at collection time (see the on-chip-measurement guide, §2).
 """
 
+import importlib
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,6 +28,9 @@ from edl_tpu.ops import flash_attention
 from edl_tpu.parallel import MeshSpec, build_mesh
 from edl_tpu.parallel.collective import zero_shard_spec
 from edl_tpu.runtime.train_loop import Trainer, TrainerConfig, TrainState
+
+#: the module; `edl_tpu.ops` exports the function under the same name
+fa = importlib.import_module("edl_tpu.ops.flash_attention")
 
 #: GPT-2-medium widths (chip_smoke.py's); depth is cut to keep compiles short
 WIDTHS = dict(vocab_size=50257, d_model=1024, n_heads=16, d_ff=4096,
@@ -56,9 +63,10 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _qkv(sharding, seq, head_dim=64, batch=2, heads=16):
-    return (jax.ShapeDtypeStruct((batch, seq, heads, head_dim), jnp.bfloat16,
-                                 sharding=sharding),) * 3
+def _qkv(sharding, seq, head_dim=64, batch=2, heads=16, kv_heads=None):
+    of = lambda heads: jax.ShapeDtypeStruct(
+        (batch, seq, heads, head_dim), jnp.bfloat16, sharding=sharding)
+    return of(heads), of(kv_heads or heads), of(kv_heads or heads)
 
 
 def _compile_flash(fn, args):
@@ -68,40 +76,48 @@ def _compile_flash(fn, args):
 
 
 #: (S, D) the tile was swept at (onchip_flash_sweep.py): the dense cell's, a
-#: ring hop's, a longer one, a wider head, the longest one span holds; and
-#: one past `_MAX_SPAN`, which takes two spans a sequence
+#: ring hop's, a longer one, a wider head, the hybrid cell's, the longest one
+#: span holds; and one past `_MAX_SPAN`, which takes two spans a sequence
 RULE_SHAPES = [(1024, 64), (2048, 64), (4096, 64), (1024, 128), (8192, 64),
-               (16384, 64)]
+               (16384, 64), (32768, 64)]
 
-#: (batch, seq, heads, head_dim) of the benchmark's two cells: two heads of
-#: 64 to a 128-lane block and 8 blocks a row; one head of 128 to a block, 32
-#: blocks a row and two spans a head
+#: (batch, seq, heads, head_dim) of the benchmark's first two cells as they
+#: called the kernels before PR 33: two heads of 64 to a 128-lane block and 8
+#: blocks a row; one head of 128 to a block, 32 blocks a row, one span a head
 CELL_SHAPES = [(32, 1024, 16, 64), (2, 8192, 32, 128)]
 
 
-#: every rule shape at batch 2 and 16 heads, and the two cells
+#: (batch, seq, heads, head_dim, K/V heads) with groups (PR 33): the hybrid
+#: cell's 32 on 2 and the sparse cell's 32 on 4 as their models call the
+#: kernels (the sparse cell's with its selection: further down), groups of
+#: two on K/V blocks of two heads of 64, sixteen of 64 on one K/V head
+GROUPED_SHAPES = [(2, 8192, 32, 128, 2), (1, 16384, 32, 128, 4),
+                  (2, 2048, 16, 64, 8), (2, 1024, 16, 64, 1)]
+
+#: every rule shape at batch 2 and 16 heads, the two cells, and the groups
 FLASH_SHAPES = [pytest.param(*shape, id="x".join(map(str, shape)))
-                for shape in [(2, seq, 16, d) for seq, d in RULE_SHAPES]
-                + CELL_SHAPES]
+                for shape in [(2, seq, 16, d, 16) for seq, d in RULE_SHAPES]
+                + [shape + shape[2:3] for shape in CELL_SHAPES]
+                + GROUPED_SHAPES]
 
 
-@pytest.mark.parametrize("batch,seq,heads,head_dim", FLASH_SHAPES)
-def test_flash_forward_and_backward_at_the_rules_tiles(one_chip, batch, seq,
-                                                       heads, head_dim):
-    """The tile, spans and lanes the kernels take unasked, as Mosaic sees
-    them: a tile it refuses, a slice it cannot align or a kernel over its
-    VMEM limit fails here."""
+@pytest.mark.parametrize("batch,seq,heads,head_dim,kv_heads", FLASH_SHAPES)
+def test_flash_forward_and_backward_at_the_rules_tiles(
+        one_chip, batch, seq, heads, head_dim, kv_heads):
+    """The tile, spans, lanes and heads a step the kernels take unasked, as
+    Mosaic sees them: a tile it refuses, a slice it cannot align or a kernel
+    over its VMEM limit fails here."""
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
 
     _compile_flash(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                   _qkv(one_chip, seq, head_dim, batch, heads))
+                   _qkv(one_chip, seq, head_dim, batch, heads, kv_heads))
 
 
-@pytest.mark.parametrize("batch,seq,heads,head_dim", FLASH_SHAPES)
+@pytest.mark.parametrize("batch,seq,heads,head_dim,kv_heads", FLASH_SHAPES)
 def test_flash_with_lse_as_the_ring_calls_it(one_chip, batch, seq, heads,
-                                             head_dim):
+                                             head_dim, kv_heads):
     """`_ring_flash_local`'s hop engine: global offsets, f32 partial output
     and a differentiable logsumexp."""
 
@@ -111,7 +127,7 @@ def test_flash_with_lse_as_the_ring_calls_it(one_chip, batch, seq, heads,
         return out.sum() + lse.sum()
 
     _compile_flash(jax.value_and_grad(hop, argnums=(0, 1, 2)),
-                   _qkv(one_chip, seq, head_dim, batch, heads))
+                   _qkv(one_chip, seq, head_dim, batch, heads, kv_heads))
 
 
 @pytest.mark.parametrize("heads,head_dim", [(16, 64), (32, 128)])
@@ -136,29 +152,71 @@ def _pallas_calls(jaxpr):
             yield from _pallas_calls(sub)
 
 
-@pytest.mark.parametrize("shape,grid", [
-    # B, H/g blocks of lanes, blocks of 512 rows, spans of up to 8,192
-    ((32, 1024, 16, 64), (32, 8, 2, 1)),
-    ((2, 8192, 32, 128), (2, 32, 16, 1)),
+@pytest.mark.parametrize("shape,kv_heads,selected,grids,moved", [
+    # B, blocks of lanes, blocks of 512 rows, spans of up to 8,192
+    ((32, 1024, 16, 64), 16, False, [(32, 8, 2, 1)] * 3, None),
+    # the hybrid cell: 32 query heads on 2 K/V heads of 128, a group of 16 a
+    # step (four in `flash_bwd_dkv`, which walks the group in four steps a
+    # span), one span
+    ((2, 8192, 32, 128), 2, False,
+     [(2, 2, 16, 1), (2, 2, 16, 1), (2, 2, 16, 4)], None),
+    # the sparse cell: 32 on 4 with the selection, a group of 8 a step (two
+    # in `flash_bwd_dkv`), one span of 16,384: K and V 32 MiB and the
+    # selection's blocks 1 GiB a call where a head a step on repeated K/V in
+    # two spans moved 8 GiB each
+    ((1, 16384, 32, 128), 4, True,
+     [(1, 4, 32, 1), (1, 4, 32, 1), (1, 4, 32, 4)],
+     {"flash_fwd": {"k+v": 32.0, "selection": 1024.0, "q+o": 256.0},
+      "flash_bwd_dq": {"k+v": 32.0, "selection": 1024.0, "q+do+dq": 384.0},
+      "flash_bwd_dkv": {"q+do": 8192.0, "selection": 1024.0,
+                        "k+v+dk+dv": 64.0}}),
+    # and as it was called before PR 33, K and V repeated to 32 heads
+    ((1, 16384, 32, 128), 32, True, [(1, 32, 32, 1)] * 3,
+     {"flash_fwd": {"k+v": 256.0, "selection": 8192.0, "q+o": 256.0},
+      "flash_bwd_dq": {"k+v": 256.0, "selection": 8192.0, "q+do+dq": 384.0},
+      "flash_bwd_dkv": {"q+do": 256.0, "selection": 8192.0,
+                        "k+v+dk+dv": 512.0}}),
 ])
-def test_flash_grid_at_the_benchmark_shapes(shape, grid):
+def test_flash_grid_at_the_benchmark_shapes(shape, kv_heads, selected, grids,
+                                            moved, caplog):
     """A grid step costs about 0.35 us before it computes: at 128 x 128
     tiles, one a step, the dense cell's (32, 1024, 16, 64) call was 32,768
-    steps and 16 ms (PERF.md, PR 26). A fall back to that must not pass
-    unseen: every kernel of the call, forward and backward, takes
-    ``B x H/g x blocks x spans`` steps: 512 at the dense cell (two heads a
-    block; 1,024 when a block was one head) and 1,024 at the hybrid cell
-    (one span a head since `_MAX_SPAN` is 8,192)."""
-    qkv = (jax.ShapeDtypeStruct(shape, jnp.bfloat16),) * 3
+    steps and 16 ms (PERF.md, PR 26). A fall back to that, or to a head a
+    step under a group, must not pass unseen: every kernel of the call,
+    forward and backward, takes ``B x blocks of lanes x blocks x spans``
+    steps: 512 at the dense cell (two heads a block; 1,024 when a block was
+    one head), 64 and 256 at the hybrid cell and 128 and 512 at the sparse
+    cell (PR 33: a K/V head's group a step, and K and V of ``Hkv x D`` lanes
+    as the model projects them; 1,024 and 2,048 a head a step at PR 32). The
+    trace-time line `flash_attention` logs says the same and what a call
+    moves by operand."""
+    B, S, H, D = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, kv_heads, D), jnp.bfloat16)
+    picked = jax.ShapeDtypeStruct((B, S, S), jnp.int8) if selected else None
 
-    def loss(q, k, v):
-        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+    def loss(q, k, v, picked):
+        return flash_attention(q, k, v, causal=True, selection=picked
+                               ).astype(jnp.float32).sum()
 
-    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(*qkv)
-    grids = {eqn.params["name"]: tuple(eqn.params["grid_mapping"].grid)
-             for eqn in _pallas_calls(jaxpr.jaxpr)}
-    assert grids == dict.fromkeys(
-        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"), grid)
+    with caplog.at_level("DEBUG", logger="edl_tpu.ops.flash_attention"):
+        jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            q, kv, kv, picked)
+    calls = {eqn.params["name"]: eqn for eqn in _pallas_calls(jaxpr.jaxpr)}
+    names = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    assert {name: tuple(calls[name].params["grid_mapping"].grid)
+            for name in names} == dict(zip(names, grids))
+    for name in names:  # K and V go in as they are: Hkv x D lanes, unrepeated
+        k_in, v_in = calls[name].invars[3:5]
+        assert k_in.aval.shape == v_in.aval.shape == (B, S, kv_heads * D)
+    dk, dv = calls["flash_bwd_dkv"].outvars
+    assert dk.aval.shape == dv.aval.shape == (B, S, kv_heads * D)
+    line = caplog.records[0].getMessage()
+    tiling = fa._tiling(B, S, S, H, kv_heads, D, 2, 512, 512, selected)
+    assert str(tiling) in line
+    assert [tiling[name]["grid"] for name in names] == grids
+    if moved is not None:
+        assert {name: tiling[name]["MiB"] for name in names} == moved
 
 
 def _param_avals(model, mesh):
@@ -275,6 +333,40 @@ def test_dense_train_step_at_the_cell(topo):
         < 15.75 * 2**30
 
 
+def _kv_broadcasts(text, elements):
+    """The instructions of a compiled program under the scope `attn_core`
+    that broadcast an ARRAY to ``elements`` elements or more: what a
+    `jnp.repeat` of K or V to the query heads compiles to (a
+    `broadcast_in_dim` to (B, S, Hkv, group, D), on its own or in a
+    fusion)."""
+    found = []
+    for line in text.splitlines():
+        made = re.search(r"= \w+\[([\d,]+)\]\S* broadcast\(", line)
+        if (made and "attn_core" in line and "broadcast_in_dim" in line
+                and math.prod(map(int, made.group(1).split(","))) >= elements):
+            found.append(line.strip()[:200])
+    return found
+
+
+def test_a_repeat_of_kv_is_seen_in_the_compiled_text(one_chip):
+    """`_kv_broadcasts` reads what the cells' rehearsals hold it to: the
+    repeat the models made before PR 33, and nothing in the call with
+    groups."""
+    q, k, v = _qkv(one_chip, 1024, 128, 2, 16, 2)
+
+    def compiled(repeat):
+        def loss(q, k, v):
+            with jax.named_scope("attn_core"):
+                if repeat:
+                    k, v = (jnp.repeat(a, 8, axis=2) for a in (k, v))
+                return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+            q, k, v).compile().as_text()
+
+    assert len(_kv_broadcasts(compiled(True), 2 * 1024 * 16 * 128)) == 2
+    assert not _kv_broadcasts(compiled(False), 2 * 1024 * 16 * 128)
+
+
 def test_hybrid_train_step_at_the_cell(topo):
     """`train_nemotron3nano_1chip`'s step: the configuration file's widths
     (nine layers `MEMEM*EME`, 8 of 128 experts held, 16,384 rows of the
@@ -310,6 +402,9 @@ def test_hybrid_train_step_at_the_cell(topo):
         "the SSD scan's kernels are not in the step"
     # `_pallas_call` names its interpreted branch so for every kernel
     assert "flash_attention_interpreted" not in text
+    # K and V go to the kernels as projected (PR 33): 2 heads, not 32
+    assert not _kv_broadcasts(text, traffic["batch"] * traffic["seq_len"]
+                              * sizes["n_heads"] * sizes["head_dim"])
     mem = compiled.memory_analysis()
     print(f"hybrid step for the described v5e: temp "
           f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
@@ -330,7 +425,8 @@ def test_sparse_train_step_at_the_cell(topo):
     `indexer_scores` and `top_k_select` (`ops/sparse_select.py`), the
     grouped product over one pass of 32,768 rows a layer; temporaries and
     arguments fit the chip's 15.75 GiB (PR 32's reading: temp 5.017 GiB +
-    arguments 7.367 GiB; 6.480 with the selection in plain XLA)."""
+    arguments 7.367 GiB; 6.480 with the selection in plain XLA; since PR 33,
+    with K and V handed to the kernels as projected, temp 4.742 GiB)."""
     import json
     import os
 
@@ -355,24 +451,31 @@ def test_sparse_train_step_at_the_cell(topo):
         assert kernel in text
     assert "tpu_custom_call" in text
     assert "flash_attention_interpreted" not in text
+    # K and V go to the kernels as projected (PR 33): 4 heads, not 32
+    assert not _kv_broadcasts(text, traffic["batch"] * traffic["seq_len"]
+                              * sizes["n_heads"] * sizes["head_dim"])
     mem = compiled.memory_analysis()
     print(f"sparse step for the described v5e: temp "
           f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
           f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
     assert mem.argument_size_in_bytes > 7.3 * 2**30  # the 7.91 GB of state
-    assert mem.temp_size_in_bytes <= 5.1 * 2**30
+    assert mem.temp_size_in_bytes <= 4.8 * 2**30
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         < 15.75 * 2**30
 
 
-@pytest.mark.parametrize("batch,seq,heads,head_dim", [
-    (1, 16384, 32, 128), (2, 1024, 16, 64), (1, 200, 16, 64)])
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim", [
+    (1, 16384, 32, 32, 128), (2, 1024, 16, 16, 64), (1, 200, 16, 16, 64),
+    (1, 16384, 32, 4, 128), (2, 1024, 16, 4, 64), (1, 200, 16, 1, 64)])
 def test_flash_with_a_selection_compiles(one_chip, batch, seq, heads,
-                                         head_dim):
+                                         kv_heads, head_dim):
     """The three kernels with the selection operand (a byte a pair, the same
     for every head) through Mosaic: at the sparse cell's shape, with two
-    heads of 64 to a block, and padded to one tile."""
-    q, k, v = _qkv(one_chip, seq, head_dim, batch, heads)
+    heads of 64 to a block, and padded to one tile; each also with K and V
+    of fewer heads, a group a step (the sparse cell's 32 on 4 as its model
+    calls it since PR 33; groups of four on K/V blocks of two heads of 64;
+    sixteen of 64 on one)."""
+    q, k, v = _qkv(one_chip, seq, head_dim, batch, heads, kv_heads)
     picked = jax.ShapeDtypeStruct((batch, seq, seq), jnp.int8,
                                   sharding=one_chip)
 
