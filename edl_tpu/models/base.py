@@ -73,3 +73,8 @@ class Model:
     #: ``whole`` each layer's whole selection too, on the device. A forward
     #: pass of its own, outside the timed step (`models/hybrid.py`).
     selection_stats: Optional[Callable] = None
+    #: optional (params, batch) -> {layer: {visible, causal}} for models with
+    #: sliding-window attention layers: the (query, key) pairs each attention
+    #: layer's core sees over the batch's queries, under its window and under
+    #: causality alone, counted by the core itself (`models/hybrid.py`).
+    window_stats: Optional[Callable] = None

@@ -23,6 +23,13 @@ hybrids; equations in each function's docstring):
   absent experts would add is left out (the chip's share of an
   expert-parallel deployment; the exchange is a later PR);
 - ``-``  a dense relu^2 MLP (the family's other sizes use it);
+- ``W``  ``*`` under a sliding window, with rotary positions: q and k turned
+  by rotate-half over the whole head (``rope_theta``), and a query sees its
+  latest ``window`` keys, itself included. The flash kernels walk only the
+  tiles that hold such a pair (`ops.flash_attention`'s ``window``), so the
+  layer's arithmetic follows the window and not the sequence. A model of
+  ``*`` and ``W`` layers is the mix of global layers without positions and
+  local rotary ones that several released decoders have;
 - ``S``  grouped-query attention under a learned sparse-attention indexer
   (DeepSeek-V3.2's lightning indexer): a per-head RMS norm on q and k, then
   rotary positions over the whole head; an indexer of its own projections
@@ -35,9 +42,16 @@ hybrids; equations in each function's docstring):
 
 The expert layer's router scores by ``router_score`` (``sigmoid`` with a
 selection bias and a scale, or a plain ``softmax`` renormalised over the
-chosen), its experts are ``expert_act`` ``relu2`` (two matrices) or ``silu``
-(gated: ``silu(h G) * (h U)`` through ``D``, three matrices), and
-``shared_width`` 0 leaves the shared expert out.
+chosen), its experts are ``expert_act`` ``relu2`` (two matrices) or gated,
+``silu`` or ``relu`` (``act(h G) * (h U)`` through ``D``, three matrices),
+and ``shared_width`` 0 leaves the shared expert out. ``router_input`` says
+what the router reads: the layer's ``own`` normed input, or that of the
+``previous`` layer (an attention sublayer: the router of such a model
+stands BEFORE attention and its experts after it); `_stack` then makes the
+routing outside the layer's `jax.checkpoint` and hands it on, as it hands an
+``S`` layer its selection. With ``router_frozen`` the routing weights carry
+no gradient, to the router or through it: the router's leaves take a zero
+gradient and stay (a fine-tune with the routers frozen).
 
 Training only: a Mamba layer carries recurrent state beside K/V, which the
 serving engine's cache manager does not know, so `serving/lm.py` and
@@ -68,7 +82,7 @@ from edl_tpu.models.base import Model
 from edl_tpu.obs.metrics import get_registry
 from edl_tpu.parallel.sharding import present_axes
 
-KINDS = "ME*-S"
+KINDS = "ME*-SW"
 
 #: why the serving tier and the export path refuse this module
 NOT_SERVABLE = (
@@ -113,11 +127,22 @@ _M_ROWS_MISCOUNTED = _REG.counter(
     labelnames=("layer",))
 
 
+_M_PAIRS_VISIBLE = _REG.counter(
+    "edl_window_pairs_visible_total",
+    "(query, key) pairs an attention layer's core saw, over the queries of "
+    "the batches asked about: under its window where it has one, by layer",
+    labelnames=("layer",))
+_M_PAIRS_CAUSAL = _REG.counter(
+    "edl_window_pairs_causal_total",
+    "Pairs those queries see under causality alone, by layer",
+    labelnames=("layer",))
+
+
 @dataclass(frozen=True)
 class HybridConfig:
     vocab_size: int = 512
     d_model: int = 64
-    #: one character a layer: M, E, *, -, S
+    #: one character a layer: M, E, *, -, S, W
     pattern: str = "ME*E-M"
     seq_len: int = 64
     norm_eps: float = 1e-5
@@ -134,12 +159,15 @@ class HybridConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
-    # -- * and S: grouped-query attention
+    # -- *, S and W: grouped-query attention
     n_heads: int = 4
     n_kv_heads: int = 2
     head_dim: int = 16
-    # -- S alone: rotary base, and the indexer that selects a query's keys
+    # -- S and W: rotary base
     rope_theta: float = 10000.0
+    # -- W alone: keys a query sees, itself included
+    window: int = 16
+    # -- S alone: the indexer that selects a query's keys
     indexer_heads: int = 4
     indexer_head_dim: int = 16
     indexer_topk: int = 16
@@ -156,8 +184,14 @@ class HybridConfig:
     #: ``sigmoid`` (selection bias, renormalised, scaled) or ``softmax``
     #: (over all published experts, renormalised over the chosen, scaled)
     router_score: str = "sigmoid"
-    #: ``relu2`` (``relu(h U)^2 D``) or ``silu`` (``(silu(h G) * (h U)) D``)
+    #: ``relu2`` (``relu(h U)^2 D``), or gated: ``silu`` or ``relu``
+    #: (``(act(h G) * (h U)) D``)
     expert_act: str = "relu2"
+    #: what the router reads: the layer's ``own`` normed input, or the
+    #: ``previous`` layer's (an attention sublayer's)
+    router_input: str = "own"
+    #: the routing weights carry no gradient, to the router or through it
+    router_frozen: bool = False
     # -- -: dense MLP
     mlp_width: int = 32
     batch_axis: Union[str, Tuple[str, ...]] = "data"
@@ -205,12 +239,24 @@ def _check(cfg: HybridConfig) -> None:
         raise ValueError(f"top_k {cfg.top_k} of {cfg.n_experts} experts")
     if cfg.router_score not in ("sigmoid", "softmax"):
         raise ValueError(f"router_score {cfg.router_score!r}")
-    if cfg.expert_act not in ("relu2", "silu"):
+    if cfg.expert_act not in ("relu2", *_GATES):
         raise ValueError(f"expert_act {cfg.expert_act!r}")
+    if cfg.router_input not in ("own", "previous"):
+        raise ValueError(f"router_input {cfg.router_input!r}")
+    if cfg.router_input == "previous" and any(
+            kind == "E" and (i == 0 or cfg.pattern[i - 1] not in "*SW")
+            for i, kind in enumerate(cfg.pattern)):
+        raise ValueError(
+            f"pattern {cfg.pattern!r}: with router_input 'previous' an E "
+            "layer's router reads the normed input of the attention layer "
+            "(*, S or W) before it, and an E layer here has none")
     if "S" in cfg.pattern and (cfg.head_dim % 2 or cfg.indexer_head_dim % 2
                                or cfg.indexer_topk < 1):
         raise ValueError("rotary positions pair the halves of an even head; "
                          "indexer_topk is at least 1")
+    if "W" in cfg.pattern and (cfg.head_dim % 2 or cfg.window < 1):
+        raise ValueError("rotary positions pair the halves of an even head; "
+                         "a window is at least the query's own key")
 
 
 # -- parameters ---------------------------------------------------------------------
@@ -224,7 +270,7 @@ def _layer_shapes(cfg: HybridConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
                 "conv_w": (cfg.conv_dim, cfg.conv_kernel),
                 "conv_b": (cfg.conv_dim,), "dt_bias": (H,), "A_log": (H,),
                 "D": (H,), "gate_norm": (inner,), "out_proj": (inner, D)}
-    if kind == "*":
+    if kind in "*W":
         q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         return {"norm": (D,), "wq": (D, q), "wk": (D, kv), "wv": (D, kv),
                 "wo": (q, D)}
@@ -242,7 +288,7 @@ def _layer_shapes(cfg: HybridConfig, kind: str) -> Dict[str, Tuple[int, ...]]:
                   "w_up": (n, D, F), "w_down": (n, F, D)}
         if cfg.router_score == "sigmoid":
             shapes["router_bias"] = (cfg.n_experts,)
-        if cfg.expert_act == "silu":  # gate and up side by side: [G | U]
+        if cfg.expert_act in _GATES:  # gate and up side by side: [G | U]
             shapes["w_up"] = (n, D, 2 * F)
         if Fs:
             shapes.update(shared_up=(D, Fs), shared_down=(Fs, D))
@@ -338,6 +384,10 @@ def _relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
 
 
+#: the gates of a gated expert, by ``expert_act``
+_GATES = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _ssd(cfg: HybridConfig, x, dt, A, Bm, Cm, D):
     """The Mamba-2 recurrence by the SSD chunked scan. Per head (group
     ``g = head // (H/G)``): ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``,
@@ -392,32 +442,56 @@ def _mamba(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
         return _mm("bse,ed->bsd", y.reshape(Bz, S, inner), p["out_proj"])
 
 
-def _attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
+def _attention(cfg: HybridConfig, h: jax.Array, p: dict,
+               window: Optional[int] = None) -> jax.Array:
     """``q = h W_q`` (n_heads x head_dim), ``k, v = h W_k, h W_v`` (n_kv_heads
     x head_dim), no bias, no positional encoding; causal softmax of ``q k^T /
     sqrt(head_dim)``, query head j on K/V head ``j // (n_heads/n_kv_heads)``;
     ``out = a W_o``. The flash kernels take K and V as projected, a K/V
     head's group of query heads to a grid step; the plain path repeats them
-    to the query heads, which is exact."""
+    to the query heads, which is exact. With a ``window`` (a ``W`` layer)
+    ``q, k = rope(q), rope(k)`` and a query sees its latest ``window`` keys
+    alone; the core's scope then reads ``attn_window`` where a global
+    layer's reads ``attn_full``, both inside ``attn_core``."""
     Bz, S, _ = h.shape
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("attn_proj"):
-        q = _mm("bsd,de->bse", h, p["wq"], out=bf16).reshape(Bz, S, Hq, Dh)
-        k = _mm("bsd,de->bse", h, p["wk"], out=bf16).reshape(Bz, S, Hkv, Dh)
+        if window is None:
+            q = _mm("bsd,de->bse", h, p["wq"], out=bf16).reshape(Bz, S, Hq, Dh)
+            k = _mm("bsd,de->bse", h, p["wk"], out=bf16).reshape(Bz, S, Hkv, Dh)
+        else:  # the rotation in float32, the MXU's operands in bf16
+            q = _rope(_mm("bsd,de->bse", h, p["wq"]).reshape(Bz, S, Hq, Dh),
+                      cfg.rope_theta).astype(bf16)
+            k = _rope(_mm("bsd,de->bse", h, p["wk"]).reshape(Bz, S, Hkv, Dh),
+                      cfg.rope_theta).astype(bf16)
         v = _mm("bsd,de->bse", h, p["wv"], out=bf16).reshape(Bz, S, Hkv, Dh)
-    with jax.named_scope("attn_core"):
-        scale = 1.0 / math.sqrt(Dh)
-        if cfg.flash:
-            from edl_tpu.ops import flash_attention
-
-            a = flash_attention(q, k, v, causal=True, scale=scale)
-        else:
-            from edl_tpu.parallel.ring_attention import dense_attention
-
-            k, v = (jnp.repeat(a, Hq // Hkv, axis=2) for a in (k, v))
-            a = dense_attention(q, k, v, causal=True, scale=scale)
+    with jax.named_scope("attn_core"), jax.named_scope(
+            "attn_full" if window is None else "attn_window"):
+        a = _attend(cfg, q, k, v, window)
     with jax.named_scope("attn_proj"):
         return _mm("bse,ed->bsd", a.reshape(Bz, S, Hq * Dh), p["wo"])
+
+
+def _attend(cfg: HybridConfig, q, k, v, window=None):
+    """Causal softmax attention of q (B, S, Hq, Dh) over k, v (B, S, Hkv,
+    Dh), a query on its latest ``window`` keys where one is given: the flash
+    kernels, or the plain path on K and V repeated to the query heads."""
+    scale = 1.0 / math.sqrt(cfg.head_dim)
+    if cfg.flash:
+        from edl_tpu.ops import flash_attention
+
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               window=window)
+    from edl_tpu.parallel.ring_attention import dense_attention
+
+    k, v = (jnp.repeat(a, q.shape[2] // k.shape[2], axis=2) for a in (k, v))
+    return dense_attention(q, k, v, causal=True, scale=scale, window=window)
+
+
+def _window_attention(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
+    """A ``W`` layer: `_attention` with rotary positions on q and k and the
+    configuration's window."""
+    return _attention(cfg, h, p, cfg.window)
 
 
 def _rope(x: jax.Array, theta: float) -> jax.Array:
@@ -592,14 +666,14 @@ def _grouped(rows, w, sizes, held):
 
 def _experts_of(rows, w_up, w_down, sizes, act: str = "relu2"):
     """``relu(rows U_g)^2 V_g``, g a row's group; zeros past the groups.
-    With ``act`` ``silu`` the experts are gated, ``w_up`` holds ``[G_g |
-    U_g]`` side by side and one grouped product gives both halves:
-    ``(silu(rows G_g) * (rows U_g)) V_g``."""
+    With ``act`` ``silu`` or ``relu`` the experts are gated, ``w_up`` holds
+    ``[G_g | U_g]`` side by side and one grouped product gives both halves:
+    ``(act(rows G_g) * (rows U_g)) V_g``."""
     held = jnp.arange(rows.shape[0]) < sizes.sum()
     up = _grouped(rows, w_up, sizes, held)
-    if act == "silu":
+    if act in _GATES:
         gate, up = jnp.split(up.astype(jnp.float32), 2, axis=-1)
-        return _grouped((jax.nn.silu(gate) * up).astype(bf16), w_down, sizes,
+        return _grouped((_GATES[act](gate) * up).astype(bf16), w_down, sizes,
                         held)
     act = _relu2(up.astype(jnp.float32)).astype(bf16)
     return _grouped(act, w_down, sizes, held)
@@ -738,14 +812,26 @@ def _routed(cfg: HybridConfig, tok, w_up, w_down, chosen, weights):
                          _row_tile(cfg, chosen.size), cfg.expert_act)
 
 
-def _moe(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
-    """``out = sum over e chosen and held of w_e f_e(h) + f_shared(h)``,
-    ``f(h) = relu(h U)^2 V`` (or the gated ``(silu(h G) * (h U)) V``; no
-    shared term where ``shared_width`` is 0). h (B, S, D) bf16."""
-    Bz, S, D = h.shape
-    tok = h.reshape(Bz * S, D)
+def _routing(cfg: HybridConfig, tok: jax.Array, p: dict):
+    """`_route` of the tokens (T, D) bf16, rows of the router's normed
+    input, under the scope ``moe_route``; with ``router_frozen`` the weights
+    carry no gradient, to the router or to the tokens."""
     with jax.named_scope("moe_route"):
         chosen, weights = _route(cfg, tok, p)
+    if cfg.router_frozen:
+        weights = jax.lax.stop_gradient(weights)
+    return chosen, weights
+
+
+def _moe(cfg: HybridConfig, h: jax.Array, p: dict, *routing) -> jax.Array:
+    """``out = sum over e chosen and held of w_e f_e(h) + f_shared(h)``,
+    ``f(h) = relu(h U)^2 V`` (or the gated ``(act(h G) * (h U)) V``; no
+    shared term where ``shared_width`` is 0). h (B, S, D) bf16. ``routing``:
+    the ``(chosen, weights)`` that `_stack` made from another layer's input
+    (``router_input`` ``previous``); else the router reads h."""
+    Bz, S, D = h.shape
+    tok = h.reshape(Bz * S, D)
+    chosen, weights = routing or _routing(cfg, tok, p)
     routed = _routed(cfg, tok, p["w_up"], p["w_down"], chosen, weights)
     if not cfg.shared_width:
         return routed.reshape(Bz, S, D)
@@ -764,36 +850,47 @@ def _mlp(cfg: HybridConfig, h: jax.Array, p: dict) -> jax.Array:
 
 _MIXERS = {"M": ("mamba_mixer", _mamba), "*": ("attn", _attention),
            "E": ("moe", _moe), "-": ("dense_mlp", _mlp),
-           "S": ("attn", _sparse_attention)}
+           "S": ("attn", _sparse_attention), "W": ("attn", _window_attention)}
 
 
 def _layer(cfg: HybridConfig, kind: str, x: jax.Array, p: dict,
-           *selection) -> jax.Array:
-    """``x + mixer(rmsnorm(x))``; x (B, S, D) bf16. An ``S`` layer is handed
-    its ``selection`` too."""
+           *handed) -> jax.Array:
+    """``x + mixer(rmsnorm(x))``; x (B, S, D) bf16. An ``S`` layer is
+    ``handed`` its selection too, an ``E`` layer whose router reads another
+    layer's input its routing."""
     scope, mixer = _MIXERS[kind]
     with jax.named_scope(scope):
         h = _rmsnorm(x, p["norm"], cfg.norm_eps).astype(bf16)
-        return x + mixer(cfg, h, p, *selection).astype(bf16)
+        return x + mixer(cfg, h, p, *handed).astype(bf16)
 
 
 def _stack(cfg: HybridConfig, params: dict, tokens: jax.Array, visit=None):
-    """Embedding and the layers; ``visit(name, x)`` sees each layer's input."""
+    """Embedding and the layers; ``visit(name, x, *routing)`` sees each
+    layer's input, and the routing of an ``E`` layer that was handed one."""
     with jax.named_scope("embed"):
         x = params["embed"][tokens].astype(bf16)
+    before = None  # the layer before: its input and its pre-norm's scale
     for name in cfg.layer_names:
+        kind, p = name[-1], params["layers"][name]
+        # What a layer is handed is an ARGUMENT of its checkpoint: made once
+        # a step and kept for the backward pass, never made again. An E
+        # layer whose router reads the layer before's normed input gets its
+        # routing so, an S layer its selection.
+        handed = ()
+        if kind == "E" and cfg.router_input == "previous":
+            with jax.named_scope("moe"):
+                handed = _routing(cfg, _rmsnorm(*before, cfg.norm_eps).astype(
+                    bf16).reshape(-1, cfg.d_model), p)
         if visit is not None:
-            visit(name, x)
-        fn = partial(_layer, cfg, name[-1])
+            visit(name, x, *handed)
+        fn = partial(_layer, cfg, kind)
         if cfg.remat:
             fn = jax.checkpoint(fn)
-        # an S layer's selection is an ARGUMENT of its checkpoint: made once
-        # a step and kept for the backward pass, never made again
-        selection = ()
-        if name[-1] == "S":
+        if kind == "S":
             with jax.named_scope("attn"):
-                selection = (_selection(cfg, x, params["layers"][name]),)
-        x = fn(x, params["layers"][name], *selection)
+                handed = (_selection(cfg, x, p),)
+        before = (x, p["norm"])
+        x = fn(x, p, *handed)
     return x
 
 
@@ -844,16 +941,17 @@ def _loss(cfg: HybridConfig, params: dict, batch: dict, mesh: Mesh):
 # -- routing and selection statistics --------------------------------------------------
 
 
-def _route_stats(cfg: HybridConfig, x: jax.Array, p: dict) -> dict:
+def _route_stats(cfg: HybridConfig, x: jax.Array, p: dict, *routing) -> dict:
     """What one E layer does with its input x, through the layer's own
-    routing, dispatch plan and grouped product: the assignments made, those
-    to held experts, each held expert's rows, and ``dropped``: the held
-    assignments less the rows that the held experts' product gave a value
-    other than zero (`_experts_held_loop`, what the step runs)."""
+    routing (or the ``routing`` `_stack` handed it), dispatch plan and
+    grouped product: the assignments made, those to held experts, each held
+    expert's rows, and ``dropped``: the held assignments less the rows that
+    the held experts' product gave a value other than zero
+    (`_experts_held_loop`, what the step runs)."""
     first, count = cfg.experts_held
     h = _rmsnorm(x, p["norm"], cfg.norm_eps).astype(bf16)
     tok = h.reshape(-1, cfg.d_model)
-    chosen, weights = _route(cfg, tok, p)
+    chosen, weights = routing or _route(cfg, tok, p)
     order, sizes = _dispatch_plan(chosen, cfg.experts_held)
     _, computed = _experts_held_loop(
         tok, weights, p["w_up"], p["w_down"], order, sizes,
@@ -900,9 +998,10 @@ def _layer_stats(cfg: HybridConfig, rows, params: dict, tokens: jax.Array):
     not two (20 s less a run of the sparse cell; my chip run, PR 32)."""
     routing, selection = {}, {}
 
-    def visit(name, x):
+    def visit(name, x, *handed):
         if name[-1] == "E":
-            routing[name] = _route_stats(cfg, x, params["layers"][name])
+            routing[name] = _route_stats(cfg, x, params["layers"][name],
+                                         *handed)
         elif name[-1] == "S":
             selection[name] = _select_stats(cfg, rows, x,
                                             params["layers"][name])
@@ -911,9 +1010,42 @@ def _layer_stats(cfg: HybridConfig, rows, params: dict, tokens: jax.Array):
     return routing, selection
 
 
+#: keys a call of `_pairs_seen` counts over: ``exp(log n)`` through the
+#: chip's float32 ``log`` and ``exp`` rounds to n with room to spare up to a
+#: few thousand keys, and no longer at 16,384 (106 of 16,384 rows read one
+#: key off there; my chip run, PR 35)
+_PAIRS_CHUNK = 2048
+
+
+def _pairs_seen(cfg: HybridConfig, batch: int, window=None) -> jax.Array:
+    """How many keys the queries of ``batch`` sequences see in all, counted
+    by the attention core's own mask and loops. The flash kernels: with q
+    and k zeros every key a query sees scores 0, so the row's logsumexp is
+    the log of their number (the sentinel where it sees none), `_PAIRS_
+    CHUNK` keys a call at their global positions. The plain path: the mask
+    `dense_attention` builds, summed."""
+    S = cfg.seq_len
+    if cfg.flash:
+        from edl_tpu.ops import flash_attention
+
+        C = math.gcd(S, _PAIRS_CHUNK)
+        q, k = (jnp.zeros((batch, rows, 1, cfg.head_dim), bf16)
+                for rows in (S, C))
+
+        def chunk(first):
+            _, lse = flash_attention(q, k, k, causal=True, return_lse=True,
+                                     k_offset=first, window=window)
+            return jnp.sum(jnp.round(jnp.exp(lse)).astype(jnp.int32))
+
+        return jnp.sum(jax.lax.map(chunk, jnp.arange(0, S, C)))
+    from edl_tpu.parallel.ring_attention import visible_pairs
+
+    return batch * jnp.sum(visible_pairs(S, window), dtype=jnp.int32)
+
+
 def make_layer_stats(cfg: HybridConfig):
-    """The two hooks of a model over one jitted forward pass (`_layer_
-    stats`), neither ever part of the train step.
+    """The hooks of a model, none ever part of the train step: two over one
+    jitted forward pass (`_layer_stats`), one over the attention core alone.
 
     ``routing_stats(params, batch) -> {layer: {made, held, per_expert,
     dropped}}`` as host numbers, and the same into the metrics registry.
@@ -926,7 +1058,14 @@ def make_layer_stats(cfg: HybridConfig):
     ``visible``, ``future`` and ``miscounted`` over every query of the
     batch, which also go into the metrics registry. With ``whole`` also
     ``selection``: the layer's whole selection (B, S, S) int8, left ON THE
-    DEVICE (a byte a pair: 256 MiB a layer at 16k)."""
+    DEVICE (a byte a pair: 256 MiB a layer at 16k).
+
+    ``window_stats(params, batch) -> {layer: {visible, causal}}`` for a
+    model with W layers, else None: for every ``*`` and ``W`` layer the
+    (query, key) pairs its core sees over the batch's queries, under its
+    window where it has one, and under causality alone; exact counts, made
+    by the core itself (`_pairs_seen`: the parameters and the tokens do not
+    enter), as host numbers and into the metrics registry."""
     rows = sampled_rows(cfg.seq_len, cfg.indexer_topk) \
         if "S" in cfg.pattern else ()
     run = jax.jit(partial(_layer_stats, cfg, rows))
@@ -963,7 +1102,23 @@ def make_layer_stats(cfg: HybridConfig):
             _M_ROWS_MISCOUNTED.inc(out[layer]["miscounted"], layer=layer)
         return out
 
-    return routing_stats, selection_stats if rows else None
+    pairs = jax.jit(lambda batch: {
+        "W": _pairs_seen(cfg, batch, cfg.window),
+        "*": _pairs_seen(cfg, batch)}, static_argnums=0)
+
+    def window_stats(params, batch) -> Dict[str, dict]:
+        got = jax.device_get(pairs(len(batch["tokens"])))
+        out = {}
+        for layer in cfg.layer_names:
+            if layer[-1] in got:
+                out[layer] = {"visible": int(got[layer[-1]]),
+                              "causal": int(got["*"])}
+                _M_PAIRS_VISIBLE.inc(out[layer]["visible"], layer=layer)
+                _M_PAIRS_CAUSAL.inc(out[layer]["causal"], layer=layer)
+        return out
+
+    return (routing_stats, selection_stats if rows else None,
+            window_stats if "W" in cfg.pattern else None)
 
 
 # -- the Model ------------------------------------------------------------------------
@@ -982,7 +1137,8 @@ def forward_flops_per_token(cfg: HybridConfig) -> Dict[str, float]:
     it) and for the head: matmuls only, products under a causal mask halved
     (attention's, the indexer's scores, and the SSD's within a chunk), an S
     layer's attention over the keys selected alone with its indexer apart
-    (``indexer``: it runs forward only), routed experts at ``top_k x held /
+    (``indexer``: it runs forward only), a W layer's over the keys inside
+    its window alone, routed experts at ``top_k x held /
     published`` of a token."""
     D, S = cfg.d_model, cfg.seq_len
     H, Pd, G, N, Q = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups,
@@ -994,8 +1150,11 @@ def forward_flops_per_token(cfg: HybridConfig) -> Dict[str, float]:
     Hi, Di, K = cfg.indexer_heads, cfg.indexer_head_dim, cfg.indexer_topk
     # keys a query attends to under the selection, averaged over positions
     kept = (min(K, S) * (min(K, S) + 1) / 2 + max(S - K, 0) * K) / S
-    mats = 3 if cfg.expert_act == "silu" else 2
+    mats = 3 if cfg.expert_act in _GATES else 2
+    w = min(cfg.window, S)  # keys a W layer's query sees, averaged likewise
+    seen = (w * (w + 1) / 2 + (S - w) * w) / S
     return {
+        "W": 2 * D * (q + 2 * kv) + 2 * q * D + 4 * seen * q,
         "S": 2 * D * (q + 2 * kv) + 2 * q * D + 4 * kept * q,
         "indexer": 2 * D * (Hi * Di + Di + Hi) + 0.5 * 2 * S * Hi * Di,
         "M": 2 * D * (inner + cfg.conv_dim + H) + 2 * cfg.conv_kernel
@@ -1022,7 +1181,7 @@ def _flops_per_step(cfg: HybridConfig, batch_size: int) -> float:
 def make_model(cfg: Optional[HybridConfig] = None, **overrides) -> Model:
     cfg = cfg or HybridConfig(**overrides)
     _check(cfg)
-    routing_stats, selection_stats = make_layer_stats(cfg)
+    routing_stats, selection_stats, window_stats = make_layer_stats(cfg)
     return Model(
         name="hybrid",
         init=lambda key, mesh: _init(cfg, key, mesh),
@@ -1035,6 +1194,7 @@ def make_model(cfg: Optional[HybridConfig] = None, **overrides) -> Model:
         flops_per_step=lambda bs: _flops_per_step(cfg, bs),
         routing_stats=routing_stats,
         selection_stats=selection_stats,
+        window_stats=window_stats,
     )
 
 

@@ -207,7 +207,7 @@ def _params(blocks, blk_q, blk_k, heads):
                              96 * 2**20))
 
 
-def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
+def _visible(shape, k_dim, *, diag, k_left, causal, ragged, window=None):
     """Which (query, key) pairs of one tile count, or None where all do.
     ``k_dim`` is the axis of ``shape`` the keys lie on. ``diag`` is the
     global position of the tile's first query less that of its first key
@@ -217,7 +217,9 @@ def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
     no multiple of the tile). Every tile of a causal call is masked, those
     below the diagonal too: a loop of their own without the mask was
     measured and bought nothing (PERF.md, PR 26), the VPU is not the
-    limit. The mask is the same for every head of a block."""
+    limit. The mask is the same for every head of a block. Under a
+    ``window`` a query also sees only its latest ``window`` keys, itself
+    included: the key's index less the query's is over ``diag - window``."""
     if not (causal or ragged):
         return None
     k_idx = jax.lax.broadcasted_iota(jnp.int32, shape, k_dim)
@@ -225,6 +227,8 @@ def _visible(shape, k_dim, *, diag, k_left, causal, ragged):
     if causal:
         q_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - k_dim)
         seen = k_idx - q_idx <= diag
+        if window is not None:
+            seen = jnp.logical_and(seen, k_idx - q_idx > diag - window)
         valid = seen if valid is None else jnp.logical_and(valid, seen)
     return valid
 
@@ -254,16 +258,38 @@ def _column(row):
 
 
 def _live_key_tiles(*, q_first, k_first, k_left, causal, blk_q, blk_k,
-                    span_k):
-    """How many of a span's key tiles, from its first, hold a key that some
-    query of the block sees. ``q_first``/``k_first`` are the global positions
-    of the block's first query and the span's first key, ``k_left`` the
-    span's count of real keys; all traced, so a ring hop in the causal
-    future comes out as 0."""
+                    span_k, window=None):
+    """``(first, stop)``: the span's key tiles that hold a key some query of
+    the block sees. ``q_first``/``k_first`` are the global positions of the
+    block's first query and the span's first key, ``k_left`` the span's
+    count of real keys; all traced, so a ring hop in the causal future
+    comes out empty. Without a ``window`` the first is tile 0; under one it
+    is the tile of the earliest key the block's FIRST query sees, so a
+    block walks ``window / blk_k + 1`` tiles wherever it lies."""
     stop = k_left
     if causal:  # keys at or before the block's last query
         stop = jnp.minimum(stop, q_first + blk_q - k_first)
-    return (jnp.clip(stop, 0, span_k) + (blk_k - 1)) // blk_k
+    stop = (jnp.clip(stop, 0, span_k) + (blk_k - 1)) // blk_k
+    if window is None:
+        return 0, stop
+    return jnp.clip(q_first - (window - 1) - k_first, 0, span_k) // blk_k, stop
+
+
+def _live_query_tiles(*, q_first, k_first, causal, blk_q, blk_k, span_q,
+                      window=None):
+    """``(first, stop)``: the span's query tiles that hold a query which
+    sees some key of the block `flash_bwd_dkv` owns. The first is the tile
+    whose LAST row is at or after the block's first key (every tile before
+    it lies wholly in the keys' past); without a ``window`` the rest of the
+    span follows, under one the last is the tile of the latest query that
+    still sees the block's LAST key."""
+    first, stop = 0, span_q // blk_q
+    if causal:
+        first = jnp.clip(k_first - q_first, 0, span_q) // blk_q
+    if window is not None:
+        stop = (jnp.clip(k_first + blk_k + window - 1 - q_first, 0, span_q)
+                + (blk_q - 1)) // blk_q
+    return first, stop
 
 
 # -- heads on lanes --------------------------------------------------------------
@@ -412,7 +438,8 @@ def _plan(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected, own_keys):
 
 
 def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
-                scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+                scale, causal, k_len, blk_q, blk_k, head_dim, selected,
+                window=None):
     # the selection's block, (1, span_k, blk_q) of the TRANSPOSED selection,
     # stands after v where the call has one
     sel_ref = rest[0] if selected else None
@@ -439,7 +466,7 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
         rows = pl.ds(at, blk_k)
         valid = _visible((blk_k, blk_q), 0, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
-                         ragged=k_len % blk_k != 0)
+                         ragged=k_len % blk_k != 0, window=window)
         if sel_ref is not None:
             valid = _chosen(valid, sel_ref[0, rows, :])
         for h, lanes in enumerate(heads):
@@ -463,9 +490,9 @@ def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, *rest,
                 preferred_element_type=jnp.float32)  # V^T P^T: (D, blk_q)
             m_ref[h] = m_new
 
-    jax.lax.fori_loop(0, _live_key_tiles(
+    jax.lax.fori_loop(*_live_key_tiles(
         q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
-        blk_q=blk_q, blk_k=blk_k, span_k=span_k), tile, None)
+        blk_q=blk_q, blk_k=blk_k, span_k=span_k, window=window), tile, None)
 
     @pl.when(si == n_s - 1)
     def _emit():
@@ -536,7 +563,7 @@ def _stat_rows(rows, heads, slabs, axis, walk=1):
 
 
 def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
-         blk_k, head_dim, out_dtype):
+         blk_k, head_dim, out_dtype, window=None):
     """q: (B, Sq, H*D); k/v: (B, Sk, Hkv*D) -> (o, lse (B*H, 1, Sq) f32).
     ``selection``, where there is one: ``(pairs (B, Sq, Sk), the same
     transposed)``, a byte a pair."""
@@ -554,7 +581,8 @@ def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
     return _pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           k_len=k_len, blk_q=blk_q, blk_k=blk_k,
-                          head_dim=head_dim, selected=bool(chosen)),
+                          head_dim=head_dim, selected=bool(chosen),
+                          window=window),
         "flash_fwd",
         grid=plan.grid,
         in_specs=[scalar, scalar, q_spec, k_spec, k_spec]
@@ -588,7 +616,8 @@ def _fwd(q, k, v, qo, ko, selection=None, *, scale, causal, k_len, blk_q,
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, *rest,
-                   scale, causal, k_len, blk_q, blk_k, head_dim, selected):
+                   scale, causal, k_len, blk_q, blk_k, head_dim, selected,
+                   window=None):
     sel_ref = rest[0] if selected else None  # (1, blk_q, span_k)
     dq_ref, acc_ref = rest[1:] if selected else rest
     qi, si = pl.program_id(2), pl.program_id(3)
@@ -614,7 +643,7 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         rows = pl.ds(at, blk_k)
         valid = _visible((blk_q, blk_k), 1, diag=q_first - (k_first + at),
                          k_left=k_left - at, causal=causal,
-                         ragged=k_len % blk_k != 0)
+                         ragged=k_len % blk_k != 0, window=window)
         if sel_ref is not None:
             valid = _chosen(valid, sel_ref[0, :, rows])
         for h in range(len(heads)):
@@ -628,9 +657,9 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
             acc_ref[h] += jnp.dot(ds.astype(k.dtype), k,
                                   preferred_element_type=jnp.float32)  # dS K
 
-    jax.lax.fori_loop(0, _live_key_tiles(
+    jax.lax.fori_loop(*_live_key_tiles(
         q_first=q_first, k_first=k_first, k_left=k_left, causal=causal,
-        blk_q=blk_q, blk_k=blk_k, span_k=span_k), tile, None)
+        blk_q=blk_q, blk_k=blk_k, span_k=span_k, window=window), tile, None)
 
     @pl.when(si == n_s - 1)
     def _emit():
@@ -640,7 +669,7 @@ def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, *rest, scale, causal, k_len, blk_q,
-                    blk_k, head_dim, selected, walk):
+                    blk_k, head_dim, selected, walk, window=None):
     sel_ref = rest[0] if selected else None  # (1, blk_k, span_q), transposed
     dk_ref, dv_ref, dk_acc, dv_acc = rest[1:] if selected else rest
     # K outer, Q streams; the last axis counts a span's ``walk`` steps
@@ -666,7 +695,7 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
         rows = pl.ds(at, blk_q)
         valid = _visible((blk_k, blk_q), 0, diag=q_first + at - k_first,
                          k_left=k_len - ki * blk_k, causal=causal,
-                         ragged=k_len % blk_k != 0)
+                         ragged=k_len % blk_k != 0, window=window)
         if sel_ref is not None:
             valid = _chosen(valid, sel_ref[0, :, rows])
         for h, lanes in enumerate(heads):
@@ -685,12 +714,9 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
             dk_acc[h // per] += jnp.dot(ds_t.astype(q.dtype), q,
                                         preferred_element_type=jnp.float32)
 
-    # The first query tile whose LAST row is at or after the block's first
-    # key; every tile before it lies wholly in the keys' past.
-    first = 0
-    if causal:
-        first = jnp.clip(k_first - q_first, 0, span_q) // blk_q
-    jax.lax.fori_loop(first, span_q // blk_q, tile, None)
+    jax.lax.fori_loop(*_live_query_tiles(
+        q_first=q_first, k_first=k_first, causal=causal, blk_q=blk_q,
+        blk_k=blk_k, span_q=span_q, window=window), tile, None)
 
     @pl.when(step == n_s - 1)
     def _emit():
@@ -700,7 +726,7 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection=None, *, scale,
-         causal, k_len, blk_q, blk_k, head_dim):
+         causal, k_len, blk_q, blk_k, head_dim, window=None):
     B, Sq, HD = q.shape
     Sk = k.shape[1]
     H, Hkv = HD // head_dim, k.shape[2] // head_dim
@@ -721,7 +747,8 @@ def _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection=None, *, scale,
     ).reshape(B * H, 1, Sq) - dlse.astype(jnp.float32)
     selected = selection is not None
     kernel_kw = dict(scale=scale, causal=causal, k_len=k_len, blk_q=blk_q,
-                     blk_k=blk_k, head_dim=head_dim, selected=selected)
+                     blk_k=blk_k, head_dim=head_dim, selected=selected,
+                     window=window)
     scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     plan = functools.partial(_plan, B, Sq, Sk, H, Hkv, head_dim,
@@ -786,17 +813,37 @@ def _fetches(grid, sees):
     return math.prod(grid[:max(live)]) * sees[max(live)]
 
 
-def _tiling(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected):
+def live_tiles(Sq, Sk, blk_q, blk_k, window=None):
+    """How many (query tile, key tile) pairs of a causal call at offsets 0
+    hold a pair some query sees, a head: what the kernels' loops walk. 528
+    of 1,024 at 16,384 x 16,384 in tiles of 512; 252 under a window of
+    4,096 (nine key tiles a query block, fewer at the sequence's start)."""
+    tiles = 0
+    for q_first in range(0, Sq, blk_q):
+        first = 0 if window is None else max(q_first - (window - 1), 0)
+        stop = min(q_first + blk_q, Sk)
+        tiles += max(0, -(-stop // blk_k) - first // blk_k)
+    return tiles
+
+
+def _tiling(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected,
+            window=None):
     """What a call of these (padded) extents builds, by kernel: the grid, the
     query and K/V heads a step holds, and the MiB a call's pipeline moves
     between HBM and VMEM by operand. `flash_attention` logs it once a trace;
     it is the record of whether a group's K, V and selection cross HBM once
-    for the group (`tests/test_tpu_compile.py` pins it at the cells)."""
+    for the group (`tests/test_tpu_compile.py` pins it at the cells). Under
+    a ``window`` also the tiles a head's loops walk, of those causality
+    alone leaves (`live_tiles`): the blocks a step FETCHES are the same
+    either way, whole spans, so a window saves arithmetic and no DMA."""
     span_q, span_k = _span(Sq, blk_q), _span(Sk, blk_k)
     plan = functools.partial(_plan, B, Sq, Sk, H, Hkv, D, itemsize, blk_q,
                              blk_k, selected)
     MiB = lambda n: round(n / 2**20, 1)
     out = {"tile": (blk_q, blk_k), "heads": (H, Hkv)}
+    if window is not None:
+        out["window"] = (window, live_tiles(Sq, Sk, blk_q, blk_k, window),
+                         live_tiles(Sq, Sk, blk_q, blk_k))
     g, g_kv, _, grid, _ = plan(own_keys=False)
     own = blk_q * g * D * itemsize * math.prod(grid[:3])
     streamed = {
@@ -823,25 +870,25 @@ def _tiling(B, Sq, Sk, H, Hkv, D, itemsize, blk_q, blk_k, selected):
 
 
 def _flash_fwd(q, k, v, offsets, selection, scale, causal, k_len, blk_q,
-               blk_k, head_dim, out_dtype):
+               blk_k, head_dim, out_dtype, window):
     o, lse = _fwd(q, k, v, *offsets, selection, scale=scale, causal=causal,
                   k_len=k_len, blk_q=blk_q, blk_k=blk_k, head_dim=head_dim,
-                  out_dtype=out_dtype)
+                  out_dtype=out_dtype, window=window)
     return (o, lse), (q, k, v, o, lse, offsets, selection)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 13)))
 def _flash(*args):
     return _flash_fwd(*args)[0]
 
 
-def _flash_bwd(scale, causal, k_len, blk_q, blk_k, head_dim, out_dtype, res,
-               cts):
+def _flash_bwd(scale, causal, k_len, blk_q, blk_k, head_dim, out_dtype,
+               window, res, cts):
     q, k, v, o, lse, (qo, ko), selection = res
     do, dlse = cts
     dq, dk, dv = _bwd(q, k, v, o, lse, do, dlse, qo, ko, selection,
                       scale=scale, causal=causal, k_len=k_len, blk_q=blk_q,
-                      blk_k=blk_k, head_dim=head_dim)
+                      blk_k=blk_k, head_dim=head_dim, window=window)
     return dq, dk, dv, None, None
 
 
@@ -856,7 +903,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 #: to 7 before groups; the sandbox's CPU, PR 33), and the cell's run does it
 #: three times under a limit of 360 s. Without groups the call stays bare:
 #: those programs' text is what it was.
-_flash_traced_once = jax.jit(_flash, static_argnums=tuple(range(5, 12)))
+_flash_traced_once = jax.jit(_flash, static_argnums=tuple(range(5, 13)))
 
 
 def _round_up(n: int, m: int) -> int:
@@ -889,6 +936,7 @@ def flash_attention(
     block_k: Optional[int] = None,
     return_lse: bool = False,
     selection: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ):
     """Blockwise-online attention. q: (B, Sq, H, D); k/v: (B, Sk, Hkv, D),
     Hkv dividing H: query head j reads K/V head j // (H / Hkv), and nothing
@@ -904,6 +952,15 @@ def flash_attention(
     tiles have keys on sublanes). Every tile up to the causal diagonal is
     still computed: a selection saves no arithmetic. Left out, the kernels'
     programs are the ones they were without this operand.
+
+    ``window`` (a static count, causal calls only): a query sees its latest
+    ``window`` keys, itself included, and no earlier one: key s of query t
+    where ``0 <= t - s < window``, in global positions. The mask gains the
+    lower edge and the loops walk only the tiles that hold such a pair
+    (`_live_key_tiles`; `flash_bwd_dkv` stops at the last query that sees
+    its key block): a call's arithmetic follows the window, not the
+    sequence. A window at or over the sequence changes nothing but the
+    program. Left out, the kernels are the programs they were.
 
     ``q_offset``/``k_offset`` are the GLOBAL positions of row 0 (ints or
     traced scalars) — sequence-parallel callers pass their shard offsets
@@ -935,6 +992,12 @@ def flash_attention(
             f"flash_attention: q has {H} heads and k {k.shape}, v {v.shape}: "
             "k and v want one shape whose heads divide q's (query head j "
             "reads K/V head j // (H / Hkv))")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"flash_attention: window {window} of a "
+            f"{'causal' if causal else 'non-causal'} call: a window is a "
+            "count of at least one key, the query's own included, and "
+            "bounds a CAUSAL query's keys from below")
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
 
     # Tile alignment: both extents are multiples of 128 (scores and their
@@ -953,7 +1016,7 @@ def flash_attention(
         _log.debug("flash_attention q%s k%s %s: %s", q.shape, k.shape,
                    q.dtype, _tiling(B, q2.shape[1], k2.shape[1], H, Hkv, D,
                                     q.dtype.itemsize, blk_q, blk_k,
-                                    selection is not None))
+                                    selection is not None, window))
 
     offsets = (jnp.asarray([q_offset], jnp.int32),
                jnp.asarray([k_offset], jnp.int32))
@@ -968,7 +1031,7 @@ def flash_attention(
         selection = (pairs, pairs.swapaxes(1, 2))
     flash = _flash if Hkv == H else _flash_traced_once
     o2, lse = flash(q2, k2, v2, offsets, selection, scale, causal, Sk, blk_q,
-                    blk_k, D, jnp.dtype(out_dtype))
+                    blk_k, D, jnp.dtype(out_dtype), window)
     out = o2[:, :Sq].reshape(B, Sq, H, D)
     if not return_lse:
         return out

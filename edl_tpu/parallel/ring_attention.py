@@ -35,23 +35,36 @@ from jax.sharding import Mesh, PartitionSpec as P
 _NEG_INF = -1e30
 
 
+def visible_pairs(S: int, window: Optional[int] = None) -> jax.Array:
+    """(S, S) bool, row t column s: causal query t sees key s; under a
+    ``window`` only its latest ``window`` keys, itself included."""
+    pos = jnp.arange(S)
+    seen = pos[None, :] <= pos[:, None]
+    if window is not None:
+        seen &= pos[:, None] - pos[None, :] < window
+    return seen
+
+
 def dense_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
-    scale: Optional[float] = None,
+    scale: Optional[float] = None, window: Optional[int] = None,
 ) -> jax.Array:
     """Reference O(S^2)-memory attention. q/k/v: (B, S, H, D).
 
     The correctness oracle for the ring kernel and the single-device
-    fallback; f32 softmax regardless of input dtype.
+    fallback; f32 softmax regardless of input dtype. Under a ``window`` (a
+    causal call's) a query sees its latest ``window`` keys, itself included.
     """
     B, S, H, D = q.shape
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     s = jnp.einsum(
         "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * scale
+    if window is not None and not causal:
+        raise ValueError("dense_attention: a window bounds a CAUSAL query's "
+                         "keys from below")
     if causal:
-        pos = jnp.arange(S)
-        s = jnp.where(pos[None, :] <= pos[:, None], s, _NEG_INF)
+        s = jnp.where(visible_pairs(S, window), s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
     return out.astype(q.dtype)
@@ -212,6 +225,7 @@ def ring_attention(
     causal: bool = True,
     scale: Optional[float] = None,
     flash: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Sequence-parallel attention on a mesh. q/k/v: (B, S, H, D) global.
 
@@ -221,8 +235,18 @@ def ring_attention(
     are simply unsharded. With no ``seq_axis`` in the mesh this degrades to
     dense attention under `jit` sharding propagation (``flash=False``) or
     to the Pallas kernel on each device's local batch/head block inside a
-    communication-free shard_map (``flash=True``).
+    communication-free shard_map (``flash=True``). A ``window`` is refused:
+    see below.
     """
+    if window is not None:
+        raise NotImplementedError(
+            f"ring_attention: window {window}: the ring passes every K/V "
+            "shard by every query shard and its hops know causality alone. "
+            "A windowed layer under sequence parallelism needs hops that "
+            "stop once a shard lies wholly before the window (a halo of "
+            "ceil(window / shard) neighbours, not the ring): call "
+            "`ops.flash_attention(..., window=...)` or `dense_attention` "
+            "on an unsharded sequence")
     n_sp = mesh.shape[seq_axis] if seq_axis in mesh.axis_names else 1
     if n_sp == 1:
         if not flash:

@@ -116,7 +116,8 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "nemotron-3-nano-30b-a3b", "fixed_b2_s8192", 1)
     assert "768" in cell["why"] and "12,288" in cell["why"]
-    assert len(bench["workloads"]) == 3  # PR 32 added the sparse cell
+    # PR 32 added the sparse cell, PR 35 the sliding-window cell
+    assert len(bench["workloads"]) == 4
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = run.load_json(REPO, entry["file"])
     assert entry["source"] == cfg["source"]
@@ -143,7 +144,8 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     assert how["args"] == {"scopes": ["attn_core"]}
     # every metric that was there still lists the cell it listed
     for m in bench["per_layer"]:
-        listed = [w for w in m["workloads"] if w != "train_keyevl2_1chip"]
+        listed = [w for w in m["workloads"] if w not in (
+            "train_keyevl2_1chip", "train_smallthinker21b_1chip")]
         if not m["name"].endswith(("train_hybrid",)) \
                 and listed not in ([CELL], []):
             assert listed[0] == "train_gpt2m_1chip"

@@ -120,11 +120,12 @@ JOINED = ("step_ms_p50.train", "device_idle_pct.train",
 def test_benchmark_json_is_valid_and_the_cell_is_there():
     bench = run.load_json(REPO, "BENCHMARK.json")
     run.validate(bench, BENCH)
-    cell = bench["workloads"][-1]
+    # the cell and its configuration stand third; PR 35 added one after them
+    cell, later = bench["workloads"][2], 7  # PR 35's per-layer metrics
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, "keye-vl-2.0-30b-a3b", "fixed_b1_s16384", 1)
     assert "1,024" in cell["why"] and "8,192" in cell["why"]
-    entry = bench["configs"][-1]
+    entry = bench["configs"][2]
     cfg = run.load_json(REPO, entry["file"])
     assert entry["name"] == cell["config"]
     assert entry["source"] == cfg["source"]
@@ -136,18 +137,20 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
         m["name"] for m in bench["per_layer"]
         if m["name"] in JOINED or m["name"] in NEW]
     assert len(reports) == len(JOINED) + len(NEW) == 25
-    # the new metrics stand at the end, in order, and list this cell alone
-    assert tuple(m["name"] for m in bench["per_layer"][-len(NEW):]) == NEW
-    for m in bench["per_layer"][-len(NEW):]:
+    # the new metrics stood at the end, in order, and list this cell alone
+    mine = bench["per_layer"][-len(NEW) - later:-later]
+    assert tuple(m["name"] for m in mine) == NEW
+    for m in mine:
         assert m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s"
-    # a metric the cell joined lists it last, after the cells it listed
+    # a metric the cell joined lists it after the cells it listed
     for m in bench["per_layer"]:
         if m["name"] in JOINED:
-            assert m["workloads"][-1] == CELL and len(m["workloads"]) >= 2
+            assert m["workloads"].index(CELL) >= 1 and m["workloads"][-2:] \
+                == [CELL, "train_smallthinker21b_1chip"]
     e2e = next(m for m in bench["end_to_end"]
                if m["name"] == "train_tokens_per_s")
-    assert e2e["workloads"] == ["train_gpt2m_1chip",
-                                "train_nemotron3nano_1chip", CELL]
+    assert e2e["workloads"][:3] == ["train_gpt2m_1chip",
+                                    "train_nemotron3nano_1chip", CELL]
     traffic = run.load_json(BENCH, "traffic", "fixed_b1_s16384.json")
     assert (traffic["batch"], traffic["seq_len"], traffic["warmup_steps"],
             traffic["queue_ahead"], traffic["batches_per_shard"]) \

@@ -508,3 +508,74 @@ def test_selection_kernels_compile(one_chip, seq, heads):
         of((1, seq, heads), jnp.float32)).compile().as_text()
     assert "indexer_scores" in text and "top_k_select" in text
     assert "flash_attention_interpreted" not in text
+
+
+@pytest.mark.parametrize("batch,seq,heads,kv_heads,head_dim,window", [
+    (1, 16384, 28, 4, 128, 4096), (2, 1024, 16, 8, 64, 300),
+    (1, 200, 7, 1, 64, 77)])
+def test_flash_with_a_window_compiles(one_chip, batch, seq, heads, kv_heads,
+                                      head_dim, window):
+    """The three kernels under a sliding window (loops that start at the
+    window's first tile and, in `flash_bwd_dkv`, stop at its last) through
+    Mosaic: at the window cell's shape, a group of 7 query heads of 128 to a
+    grid step (one a step in `flash_bwd_dkv`); with two heads of 64 to a
+    block and a window that is no multiple of the tile; padded to one
+    tile."""
+    q, k, v = _qkv(one_chip, seq, head_dim, batch, heads, kv_heads)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, window=window)
+                       .astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        q, k, v).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    assert "flash_attention_interpreted" not in text
+
+
+def test_window_train_step_at_the_cell(topo):
+    """`train_smallthinker21b_1chip`'s step: the configuration file's widths
+    (four published layers as `*EWEWEWE`, 16 of 64 gated relu experts held,
+    37,984 rows of the vocabulary) at the cell's 1 x 16,384 tokens,
+    per-layer remat, Adam. It compiles for the described v5e: the flash
+    kernels at (1, 16384, 28 on 4, 128) through Mosaic, with and without
+    the window of 4,096; the routing made outside the expert layers'
+    checkpoints; the grouped product over one pass of 32,768 rows a layer;
+    temporaries and arguments stay under 15.0 GiB (PR 35's reading: temp
+    3.907 GiB + arguments 7.337 GiB)."""
+    import json
+    import os
+
+    from edl_tpu.models import resolve
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "smallthinker-21b-a3b-instruct.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(bench, "traffic",
+                           "fixed_b1_s16384_ids37984.json")) as f:
+        traffic = json.load(f)
+    sizes = {ours: config[theirs]
+             for theirs, ours in config["maps_to"].items()}
+    model = resolve(config["model"], dict(sizes, seq_len=traffic["seq_len"],
+                                          remat=True))
+    compiled = _compiled_cell_step(topo, model, traffic["batch"],
+                                   traffic["seq_len"])
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert kernel in text
+    assert "tpu_custom_call" in text
+    assert "flash_attention_interpreted" not in text
+    # K and V go to the kernels as projected: 4 heads, not 28
+    assert not _kv_broadcasts(text, traffic["batch"] * traffic["seq_len"]
+                              * sizes["n_heads"] * sizes["head_dim"])
+    mem = compiled.memory_analysis()
+    print(f"window step for the described v5e: temp "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB")
+    assert mem.argument_size_in_bytes > 7.3 * 2**30  # the 7.88 GB of state
+    assert mem.temp_size_in_bytes <= 4.0 * 2**30
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        < 15.0 * 2**30
