@@ -25,17 +25,24 @@ This package stays stdlib-only and never imports JAX: the annotation is
 looked up in ``sys.modules``, so a process that has not imported JAX (the
 controller) records to the ring alone, and with no session active an
 annotation is a flag check.
+
+What JAX compiles is on the same record: inside :meth:`Tracer.compile_spans`
+every program JAX traces, lowers or compiles on a thread where the tracer has
+a span open becomes a span under that span (``jax_trace``, ``jax_lower``,
+``xla_compile``), from ``jax.monitoring``'s events, found in ``sys.modules``
+by the same rule.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import sys
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import (Any, Callable, ContextManager, Dict, Iterable, List,
-                    Optional, TextIO, Union)
+from typing import (Any, Callable, ContextManager, Dict, Iterable, Iterator,
+                    List, Optional, TextIO, Union)
 
 __all__ = [
     "Span",
@@ -81,7 +88,8 @@ class Span:
     component: str = ""
     attrs: Dict[str, Any] = field(default_factory=dict)
     #: the span that caused this one: the enclosing :meth:`Tracer.span` of
-    #: the same thread (None at the top, and for recorded intervals)
+    #: the same thread (None at the top, and for recorded intervals given
+    #: none)
     parent: Optional["Span"] = field(default=None, repr=False, compare=False)
     #: cleared inside the ``with`` by a caller that finds the work was not
     #: there (the wait that met the end of its input): the span then stays
@@ -114,6 +122,78 @@ def profiler_annotation() -> Optional[Callable[[str], ContextManager]]:
     else None. Never imports JAX itself."""
     jax = sys.modules.get("jax")
     return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
+#: JAX's compile events, by the span each becomes (:meth:`Tracer.compile_spans`)
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower",
+    "/jax/core/compile/backend_compile_duration": "xla_compile",
+}
+
+#: the persistent compile cache's answer, fired inside a backend compile
+_CACHE_ANSWERS = {"/jax/compilation_cache/cache_hits": "hit",
+                  "/jax/compilation_cache/cache_misses": "miss"}
+
+
+class _CompileListener:
+    """``jax.monitoring``'s compile events as one tracer's spans.
+
+    JAX stamps a compile event twice, on ``time.time()`` (the clock of
+    ``Span.start``): a scalar at its start and a time span at its end. An
+    event that ends on a thread where the tracer has a span open is recorded
+    under that span, with ``fun`` (JAX's ``fun_name``) and, on
+    ``xla_compile``, ``cache``: ``hit`` or ``miss`` where the persistent
+    cache answered on that thread inside the interval, else ``off``. An
+    event inside another on the same thread (a jitted function called while
+    a trace runs) is already in the outer one's interval and is not recorded
+    again, so a thread's compile spans never overlap and their seconds add.
+    """
+
+    def __init__(self, tracer: "Tracer"):
+        self.tracer = tracer
+        self._local = threading.local()  # depth of open events, cache answer
+
+    def register(self, monitoring) -> None:
+        monitoring.register_scalar_listener(self.on_start)
+        monitoring.register_event_listener(self.on_answer)
+        monitoring.register_event_time_span_listener(self.on_end)
+
+    def unregister(self, monitoring) -> None:
+        monitoring.unregister_scalar_listener(self.on_start)
+        monitoring.unregister_event_listener(self.on_answer)
+        monitoring.unregister_event_time_span_listener(self.on_end)
+
+    def on_start(self, event: str, value: float, **kwargs: Any) -> None:
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        local = self._local
+        local.depth = getattr(local, "depth", 0) + 1
+        if name == "xla_compile":
+            local.cache = "off"
+
+    def on_answer(self, event: str, **kwargs: Any) -> None:
+        answer = _CACHE_ANSWERS.get(event)
+        if answer is not None:
+            self._local.cache = answer
+
+    def on_end(self, event: str, start: float, end: float,
+               fun_name: str = "", **kwargs: Any) -> None:
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        local = self._local
+        # an end whose start came before the listener was there counts as
+        # outermost: the floor is 0
+        local.depth = max(getattr(local, "depth", 0) - 1, 0)
+        parent = self.tracer.current()
+        if local.depth or parent is None:
+            return
+        attrs = {"fun": fun_name}
+        if name == "xla_compile":
+            attrs["cache"] = getattr(local, "cache", "off")
+        self.tracer.record(name, start, end, parent=parent, **attrs)
 
 
 class _OpenSpan:
@@ -173,6 +253,10 @@ class Tracer:
         self.spans: List[Span] = []
         self._lock = threading.Lock()
         self._local = threading.local()
+        #: JAX's compile events while a :meth:`compile_spans` is open, and
+        #: how many are open
+        self._compile_listener: Optional[_CompileListener] = None
+        self._compile_users = 0
 
     def _open_spans(self) -> List[Span]:
         """This thread's stack of open context-managed spans."""
@@ -182,10 +266,16 @@ class Tracer:
             stack = self._local.stack = []
             return stack
 
+    def current(self) -> Optional[Span]:
+        """The calling thread's innermost open :meth:`span`, or None."""
+        stack = getattr(self._local, "stack", None)
+        return stack[-1] if stack else None
+
     # -- recording -------------------------------------------------------------
 
     def record(self, name: str, start: float, end: float, trace_id: str = "",
-               component: str = "", **attrs: Any) -> Span:
+               component: str = "", parent: Optional[Span] = None,
+               **attrs: Any) -> Span:
         """Record an interval measured by the caller (after-the-fact spans:
         the drain interval is only attributable once the new epoch is
         known). Zero-length intervals are clamped to a microsecond so phase
@@ -195,12 +285,14 @@ class Tracer:
         anything under ~2.4e-7 and a 1e-9 clamp silently rounds back to
         zero length.
 
-        A recorded interval has no parent and is not mirrored into the
-        profiler's trace: it is already over when it is known, and an
-        annotation can only be opened now."""
+        A recorded interval is not mirrored into the profiler's trace: it is
+        already over when it is known, and an annotation can only be opened
+        now. Its parent is ``parent`` (``current()`` gives the calling
+        thread's innermost open span), None by default."""
         return self._append(Span(
             name=name, start=start, end=end, trace_id=trace_id,
-            component=component or self.component, attrs=dict(attrs)))
+            component=component or self.component, attrs=dict(attrs),
+            parent=parent))
 
     def _append(self, span: Span) -> Span:
         if span.end <= span.start:
@@ -235,6 +327,36 @@ class Tracer:
         """Point-in-time marker (epoch observation, decision taken)."""
         now = time.time()
         return self.record(name, now, now, trace_id=trace_id, **attrs)
+
+    @contextlib.contextmanager
+    def compile_spans(self) -> Iterator[None]:
+        """While the ``with`` lasts, every program JAX traces, lowers or
+        compiles on a thread where this tracer has a span open is recorded
+        as a span under that span: ``jax_trace``, ``jax_lower`` or
+        ``xla_compile`` (:class:`_CompileListener` says which events, and
+        how nested ones fold into the outermost). A compile on a thread where
+        this tracer has nothing open is not recorded. The outermost ``with``
+        on a tracer registers the listeners with ``jax.monitoring`` and
+        removes them as it ends, also on an exception; in a process without
+        JAX it does nothing. The listeners run on compiles only: a step that
+        compiles nothing pays nothing."""
+        monitoring = getattr(sys.modules.get("jax"), "monitoring", None)
+        if monitoring is None:
+            yield
+            return
+        with self._lock:
+            if self._compile_listener is None:
+                self._compile_listener = _CompileListener(self)
+                self._compile_listener.register(monitoring)
+            self._compile_users += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self._compile_users -= 1
+                if not self._compile_users:
+                    self._compile_listener.unregister(monitoring)
+                    self._compile_listener = None
 
     # -- reading ---------------------------------------------------------------
 

@@ -568,22 +568,23 @@ class ElasticWorker(WorkerBase):
         status counters bridged onto the same scrape.
         """
         try:
-            if self.config.metrics_port is None:
-                return self._run(max_rescales)
-            from edl_tpu.obs.bridge import CoordinatorStatusBridge
-            from edl_tpu.obs.http import MetricsServer
+            with self._set_up():
+                if self.config.metrics_port is None:
+                    return self._run(max_rescales)
+                from edl_tpu.obs.bridge import CoordinatorStatusBridge
+                from edl_tpu.obs.http import MetricsServer
 
-            bridge = CoordinatorStatusBridge(self.client).register()
-            server = MetricsServer(port=self.config.metrics_port,
-                                   tracer=self.tracer,
-                                   health=self._health).start()
-            self.metrics_url = server.url  # edl: noqa[EDL001] set once at startup, before the serving thread handles requests
-            log.info("worker metrics at %s/metrics", server.url)
-            try:
-                return self._run(max_rescales)
-            finally:
-                bridge.unregister()
-                server.stop()
+                bridge = CoordinatorStatusBridge(self.client).register()
+                server = MetricsServer(port=self.config.metrics_port,
+                                       tracer=self.tracer,
+                                       health=self._health).start()
+                self.metrics_url = server.url  # edl: noqa[EDL001] set once at startup, before the serving thread handles requests
+                log.info("worker metrics at %s/metrics", server.url)
+                try:
+                    return self._run(max_rescales)
+                finally:
+                    bridge.unregister()
+                    server.stop()
         finally:
             if self._watch is not None:
                 self._watch.close()
@@ -658,7 +659,7 @@ class ElasticWorker(WorkerBase):
             # both) is built once, then the new mesh's step executable
             # compiles on a background thread while orbax reshards the
             # checkpoint onto the mesh.
-            fresh = trainer.init_state()
+            fresh = self._init_state(trainer)
             join_warm = self._start_warm_compile(trainer, fresh, trace_id=rid)
             t_restore0 = time.time()
             state = self._restore_or_init(trainer, fresh=fresh)
@@ -810,8 +811,12 @@ class ElasticWorker(WorkerBase):
                     # completion-lagged shards: flush them durably so the
                     # queue can actually drain (multihost's tail-flush rule),
                     # then keep polling — a peer may still fail and requeue.
+                    # This is the final save in practice: the one at the
+                    # queue's exhaustion then finds its step saved.
                     if self._carry_consumed or self._pending_commit:
-                        self._checkpoint_and_commit(state, None, block=True)
+                        with self.tracer.span("final_checkpoint", step=step):
+                            self._checkpoint_and_commit(state, None,
+                                                        block=True)
                         last_ckpt_step = int(state.step)
                     self._pause()
                     if self._epoch_changed(force=True):
@@ -846,7 +851,8 @@ class ElasticWorker(WorkerBase):
                 continue
 
             # Queue exhausted: final checkpoint, commit held leases, finish.
-            self._checkpoint_and_commit(state, None, block=True)
+            with self.tracer.span("final_checkpoint", step=step):
+                self._checkpoint_and_commit(state, None, block=True)
             # The final commit must actually LAND (not sit buffered): wait
             # out any outage so no completed shard is lost with the process.
             while len(self.client.outbox):

@@ -457,7 +457,7 @@ class MultiHostWorker(WorkerBase):
 
         # SIGTERM -> drain at the next round boundary (no-op install off
         # the main thread — pytest drives workers from threads too).
-        with main_thread_signal(signal.SIGTERM, _on_term):
+        with main_thread_signal(signal.SIGTERM, _on_term), self._set_up():
             try:
                 return self._run(max_rounds)
             finally:
@@ -633,7 +633,8 @@ class MultiHostWorker(WorkerBase):
         # "exhausted" plan) are deliberately NOT GC'd — a straggler may still
         # need to read them to exit; the litter is bounded by one tail's
         # worth of rounds and dies with the job's coordinator.
-        checkpoint_and_commit()
+        with self.tracer.span("final_checkpoint", step=step):
+            checkpoint_and_commit()
         if rank == 0 and len(self.client.outbox):
             # Completions buffered during an outage that is still open at
             # drain time: give the coordinator one budget's grace to come

@@ -8,19 +8,21 @@ config, the constructor's control-plane wiring (outbox facade, fault-
 tolerance policy, checkpointer and checkpoint plane, compile cache, epoch
 watch, instruments), the seeded jitter stream, draining the watch and the
 preempt-notice decision, mesh and trainer construction, restore-or-init,
-the body of one train step with its spans, and the result summary. A
+the body of one train step with its spans, the record of ``run()``'s
+set-up, and the result summary. A
 feature that both workers need is wired here and nowhere else.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import math
 import random
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh
@@ -312,6 +314,24 @@ class WorkerBase:
         #: what rescale warm-compile specializes the new mesh's step
         #: against. Written once from whichever thread places first.
         self._batch_avals: Optional[Dict[str, jax.ShapeDtypeStruct]] = None
+        #: wall time ``run()`` was entered, until its first step's loss is
+        #: on the host (`_set_up`, `_step_once`); None otherwise
+        self._setup_t0: Optional[float] = None
+
+    @contextlib.contextmanager
+    def _set_up(self) -> Iterator[None]:
+        """The frame of ``run()`` in which set-up is recorded: the span
+        ``worker_setup`` runs from here to the first step's loss on the host
+        (`_step_once`), and while it lasts every program JAX traces, lowers
+        or compiles under one of this worker's open spans is a span of its
+        own (`Tracer.compile_spans`: ``init_state``'s eager programs, the
+        step's under ``step_dispatch``, a placement's under ``place``)."""
+        self._setup_t0 = time.time()
+        try:
+            with self.tracer.compile_spans():
+                yield
+        finally:
+            self._setup_t0 = None
 
     # -- control plane ---------------------------------------------------------
 
@@ -462,11 +482,17 @@ class WorkerBase:
                        codec_channel=codec_channel,
                        compile_cache=self.compile_cache)
 
+    def _init_state(self, trainer: Trainer) -> TrainState:
+        """``trainer.init_state()`` under the span ``init_state``: the state
+        from the seed, its eager per-leaf programs compiled inside."""
+        with self.tracer.span("init_state"):
+            return trainer.init_state()
+
     def _restore_or_init(
         self, trainer: Trainer, fresh: Optional[TrainState] = None
     ) -> TrainState:
         if fresh is None:
-            fresh = trainer.init_state()
+            fresh = self._init_state(trainer)
         self._last_restore = {"source": "init", "bytes": 0}
         blob_step = self.ckpt.latest_step()
         if (self.ckpt_plane is not None
@@ -565,6 +591,10 @@ class WorkerBase:
             state, loss = step_fn(state, placed)
         with self.tracer.span("loss_sync", step=step) as sync:
             loss = loss_value(loss)
+        if self._setup_t0 is not None:  # the first step of this run()
+            self.tracer.record("worker_setup", self._setup_t0, time.time(),
+                               step=step)
+            self._setup_t0 = None
         # Live re-step pricing: every completed step feeds its wall seconds
         # to the policy's EMA.
         self.policy.note_step(dispatch.seconds + sync.seconds)

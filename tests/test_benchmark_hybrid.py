@@ -124,7 +124,14 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     assert entry["reduced"] == cfg["reduced"]
     for e in bench["configs"] + bench["workloads"]:
         assert len(e["why"]) <= 200 and "\n" not in e["why"]
-    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)]
+    # what moves the cell's throughput; the worker set-up's five, which move
+    # setup_s in every cell, stand after them
+    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+               and m["moves"] == "train_tokens_per_s"]
+    setup = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+             and m["moves"] == "setup_s"]
+    assert len(setup) == 5 and setup == [m["name"]
+                                         for m in bench["per_layer"][-5:]]
     assert len(reports) == 24 and "mfu_pct.train" not in reports \
         and "attn_roofline_pct.train" not in reports
     # PR 28's counter that the SSD kernels ran: data on a reader that was there

@@ -120,8 +120,10 @@ JOINED = ("step_ms_p50.train", "device_idle_pct.train",
 def test_benchmark_json_is_valid_and_the_cell_is_there():
     bench = run.load_json(REPO, "BENCHMARK.json")
     run.validate(bench, BENCH)
-    # the cell and its configuration stand third; PR 35 added one after them
-    cell, later = bench["workloads"][2], 7  # PR 35's per-layer metrics
+    # the cell and its configuration stand third, the window cell's after
+    # them. After this cell's metrics: the window cell's seven, then the
+    # worker set-up's five, which move setup_s in every cell
+    cell, later = bench["workloads"][2], 7 + 5
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         CELL, "keye-vl-2.0-30b-a3b", "fixed_b1_s16384", 1)
     assert "1,024" in cell["why"] and "8,192" in cell["why"]
@@ -132,7 +134,11 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     assert entry["reduced"] == cfg["reduced"]
     for e in bench["configs"] + bench["workloads"]:
         assert len(e["why"]) <= 200 and "\n" not in e["why"]
-    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)]
+    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+               and m["moves"] == "train_tokens_per_s"]
+    assert [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+            and m["moves"] == "setup_s"] \
+        == [m["name"] for m in bench["per_layer"][-5:]]
     assert reports == list(JOINED[:0]) + [
         m["name"] for m in bench["per_layer"]
         if m["name"] in JOINED or m["name"] in NEW]

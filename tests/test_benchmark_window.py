@@ -131,12 +131,18 @@ def test_benchmark_json_is_valid_and_the_cell_is_there():
     for e in bench["configs"] + bench["workloads"]:
         assert len(e["why"]) <= 200 and "\n" not in e["why"]
     assert len(bench["configs"]) == 4 and len(bench["workloads"]) == 4
-    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)]
+    reports = [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+               and m["moves"] == "train_tokens_per_s"]
     assert len(reports) == len(JOINED) + len(NEW) == 25
     assert set(reports) == set(JOINED) | set(NEW)
-    # the new metrics stand at the end, in order, and list this cell alone
-    assert tuple(m["name"] for m in bench["per_layer"][-len(NEW):]) == NEW
-    for m in bench["per_layer"][-len(NEW):]:
+    # the new metrics stand at the end, in order, and list this cell alone;
+    # after them only the worker set-up's five, which move setup_s
+    mine = bench["per_layer"][-len(NEW) - 5:-5]
+    assert [m["name"] for m in bench["per_layer"] if run.reports(m, CELL)
+            and m["moves"] == "setup_s"] \
+        == [m["name"] for m in bench["per_layer"][-5:]]
+    assert tuple(m["name"] for m in mine) == NEW
+    for m in mine:
         assert m["workloads"] == [CELL] and m["moves"] == "train_tokens_per_s"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}

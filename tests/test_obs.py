@@ -9,13 +9,18 @@ process-wide defaults stay untouched so these tests cannot contaminate
 elsewhere in the suite.
 """
 
+import contextlib
 import io
 import json
 import logging
 import math
+import subprocess
+import sys
 import threading
+import time
 import urllib.error
 import urllib.request
+from types import SimpleNamespace
 
 import pytest
 
@@ -265,6 +270,152 @@ def test_rescale_timeline_surfaces_unknown_phases():
     # the stray name is kept in phases AND called out, not dropped
     assert t["phases"]["teleport"]["seconds"] == pytest.approx(0.1)
     assert t["unknown_phases"] == ["teleport"]
+
+
+# -- JAX's compile events as spans ---------------------------------------------
+
+TRACE = "/jax/core/compile/jaxpr_trace_duration"
+LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE = "/jax/core/compile/backend_compile_duration"
+HIT = "/jax/compilation_cache/cache_hits"
+MISS = "/jax/compilation_cache/cache_misses"
+
+
+class FakeMonitoring:
+    """`jax.monitoring`'s three listener lists, fired the way JAX fires them
+    around a compile event: a scalar with the start time as it begins, a
+    time span as it ends."""
+
+    def __init__(self):
+        self.scalar, self.event, self.span = [], [], []
+
+    def register_scalar_listener(self, f):
+        self.scalar.append(f)
+
+    def register_event_listener(self, f):
+        self.event.append(f)
+
+    def register_event_time_span_listener(self, f):
+        self.span.append(f)
+
+    def unregister_scalar_listener(self, f):
+        self.scalar.remove(f)
+
+    def unregister_event_listener(self, f):
+        self.event.remove(f)
+
+    def unregister_event_time_span_listener(self, f):
+        self.span.remove(f)
+
+    @contextlib.contextmanager
+    def compiling(self, event, fun):
+        start = time.time()
+        for f in list(self.scalar):
+            f(event, start, fun_name=fun)
+        yield
+        for f in list(self.span):
+            f(event, start, time.time(), fun_name=fun)
+
+    def answer(self, event):
+        for f in list(self.event):
+            f(event)
+
+    def listeners(self):
+        return len(self.scalar), len(self.event), len(self.span)
+
+
+@pytest.fixture
+def fake_jax(monkeypatch):
+    fake = FakeMonitoring()
+    monkeypatch.setitem(sys.modules, "jax", SimpleNamespace(monitoring=fake))
+    return fake
+
+
+def test_tracer_record_takes_a_parent_and_current_is_the_innermost_span():
+    tr = Tracer(annotation=contextlib.nullcontext)
+    assert tr.current() is None
+    with tr.span("step_dispatch") as outer:
+        assert tr.current() is outer
+        with tr.span("inner") as inner:
+            assert tr.current() is inner
+        got = tr.record("xla_compile", 1.0, 2.0, parent=tr.current(),
+                        fun="jit(_step)")
+    assert tr.current() is None
+    assert got.parent is outer and got.attrs == {"fun": "jit(_step)"}
+    assert got.to_dict()["parent"] == "step_dispatch"
+    assert tr.record("drain", 1.0, 2.0).parent is None
+
+
+def test_compile_spans_record_outermost_compiles_under_the_open_span(
+        fake_jax):
+    tr = Tracer(annotation=contextlib.nullcontext)
+    with tr.compile_spans():
+        assert fake_jax.listeners() == (1, 1, 1)
+        with fake_jax.compiling(TRACE, "outside"):  # no span open: not ours
+            pass
+        with tr.span("step_dispatch", step=1):
+            with fake_jax.compiling(TRACE, "_step"):
+                with fake_jax.compiling(TRACE, "inner"):  # a jit in a trace
+                    pass
+                with fake_jax.compiling(COMPILE, "jit(eager)"):
+                    fake_jax.answer(HIT)
+            with fake_jax.compiling(LOWER, "jit(_step)"):
+                pass
+            with fake_jax.compiling(COMPILE, "jit(_step)"):
+                fake_jax.answer(MISS)
+            with fake_jax.compiling(COMPILE, "jit(_step)"):
+                fake_jax.answer("/jax/compilation_cache/tasks_using_cache")
+        def compile_elsewhere():  # a thread with no span of this tracer
+            with fake_jax.compiling(COMPILE, "jit(reference)"):
+                pass
+
+        elsewhere = threading.Thread(target=compile_elsewhere)
+        elsewhere.start()
+        elsewhere.join(10)
+        assert not elsewhere.is_alive()
+        with tr.span("place"):
+            with fake_jax.compiling(COMPILE, "jit(put)"):
+                fake_jax.answer(HIT)
+    assert fake_jax.listeners() == (0, 0, 0)
+    assert [(s.name, s.parent and s.parent.name, s.attrs)
+            for s in tr.spans] == [
+        ("jax_trace", "step_dispatch", {"fun": "_step"}),
+        ("jax_lower", "step_dispatch", {"fun": "jit(_step)"}),
+        ("xla_compile", "step_dispatch", {"fun": "jit(_step)",
+                                          "cache": "miss"}),
+        ("xla_compile", "step_dispatch", {"fun": "jit(_step)",
+                                          "cache": "off"}),
+        ("step_dispatch", None, {"step": 1}),
+        ("xla_compile", "place", {"fun": "jit(put)", "cache": "hit"}),
+        ("place", None, {}),
+    ]
+    compiles = [s for s in tr.spans if s.parent is not None]
+    assert all(a.end <= b.start for a, b in zip(compiles, compiles[1:]))
+
+
+def test_compile_spans_register_once_a_tracer_and_unregister_on_raise(
+        fake_jax):
+    a, b = Tracer(), Tracer()
+    with pytest.raises(KeyError):
+        with a.compile_spans():
+            with a.compile_spans():  # two workers on one tracer
+                assert fake_jax.listeners() == (1, 1, 1)
+                with b.compile_spans():
+                    assert fake_jax.listeners() == (2, 2, 2)
+            assert fake_jax.listeners() == (1, 1, 1)
+            raise KeyError("boom")
+    assert fake_jax.listeners() == (0, 0, 0)
+
+
+def test_compile_spans_without_jax_do_nothing_and_import_nothing():
+    code = ("import sys, edl_tpu.obs.tracing as t\n"
+            "tr = t.Tracer()\n"
+            "with tr.compile_spans():\n"
+            "    with tr.span('init_state'):\n"
+            "        pass\n"
+            "assert [s.name for s in tr.spans] == ['init_state']\n"
+            "assert 'jax' not in sys.modules\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
 
 
 # -- HTTP endpoints ------------------------------------------------------------

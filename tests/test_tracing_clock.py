@@ -256,6 +256,111 @@ def test_place_span_feeds_the_step_profiler(tmp_path):
         [s.seconds for s in tracer.find(name="place")]
 
 
+# -- the elastic worker's set-up --------------------------------------------------
+
+COMPILES = ("jax_trace", "jax_lower", "xla_compile")
+
+
+def small_worker(tmp_path, tracer, tasks=2, **config):
+    model = fit_a_line.MODEL
+    coord = InProcessCoordinator(task_lease_sec=600.0,
+                                 heartbeat_ttl_sec=600.0)
+    coord.add_tasks(shard_names("train", tasks))
+    return ElasticWorker(
+        model, coord.client("w0"),
+        SyntheticShardSource(model, batch_size=8, batches_per_shard=1),
+        ElasticConfig(checkpoint_dir=str(tmp_path / "ck"),
+                      checkpoint_interval=10**9,
+                      trainer=TrainerConfig(optimizer="adam"), **config),
+        device_planner=lambda world: jax.devices()[:1], tracer=tracer)
+
+
+def fresh_compile(scale):
+    """A program JAX has not seen: traced, lowered and compiled now."""
+    jax.jit(lambda x: x * scale + 1.0)(
+        np.ones(3, np.float32)).block_until_ready()
+
+
+def test_worker_records_its_set_up_and_every_program_it_compiles(tmp_path):
+    tracer = Tracer(component="test")
+    t0 = time.time()
+    small_worker(tmp_path, tracer, tasks=3).run()
+    (setup,) = tracer.find(name="worker_setup")
+    (init,) = tracer.find(name="init_state")
+    first_loss = tracer.find(name="loss_sync")[0]
+    assert setup.attrs == {"step": 1} and setup.parent is None
+    assert t0 <= setup.start <= init.start and init.end <= setup.end
+    assert first_loss.end <= setup.end <= first_loss.end + 1.0
+    # the blocking save once the queue ran dry: the flush of the leases the
+    # worker held, then the save at the queue's end, which finds it done
+    finals = tracer.find(name="final_checkpoint")
+    assert 1 <= len(finals) <= 2
+    assert all(f.attrs == {"step": 3} and f.start >= setup.end
+               for f in finals)
+    compiles = [s for s in tracer.spans if s.name in COMPILES]
+    assert {s.name for s in compiles} == set(COMPILES)
+    for s in compiles:  # under the worker span open where it compiled
+        assert s.parent.name in ("init_state", "step_dispatch", "place")
+        assert s.parent.start - 0.01 <= s.start <= s.end \
+            <= s.parent.end + 0.01
+        assert s.attrs["fun"]
+    step = [s for s in compiles
+            if s.name == "xla_compile" and s.parent.name == "step_dispatch"]
+    assert step and step[0].parent.attrs["step"] == 1
+    for s in step:
+        assert "_step" in s.attrs["fun"]
+        assert s.attrs["cache"] in ("hit", "miss", "off")
+    # the worker's thread compiles one program at a time: its spans do not
+    # overlap, so their seconds add up to no more than the set-up's
+    mine = sorted((s for s in compiles if s.parent.name != "place"),
+                  key=lambda s: s.start)
+    assert all(a.end <= b.start + 1e-6 for a, b in zip(mine, mine[1:]))
+    assert sum(s.seconds for s in mine if s.parent is init) <= init.seconds
+
+
+def test_a_compile_where_the_worker_has_no_span_open_is_not_recorded(
+        tmp_path):
+    tracer = Tracer(component="test")
+
+    def on_step(step, state):
+        if step == 1:
+            other = threading.Thread(target=fresh_compile, args=(3.0,))
+            other.start()
+            other.join(60)
+            assert not other.is_alive()
+            fresh_compile(5.0)  # on the worker's thread, in step_callback
+
+    small_worker(tmp_path, tracer, step_callback=on_step).run()
+    compiles = [s for s in tracer.spans if s.name in COMPILES]
+    assert all(s.parent is not None for s in compiles)
+    lambdas = [(s.name, s.parent.name, s.parent.attrs["step"])
+               for s in compiles if "<lambda>" in s.attrs["fun"]]
+    assert sorted(lambdas) == [("jax_lower", "step_callback", 1),
+                               ("jax_trace", "step_callback", 1),
+                               ("xla_compile", "step_callback", 1)]
+
+
+@pytest.mark.parametrize("fails", [False, True], ids=["returns", "raises"])
+def test_the_listener_is_gone_once_run_is_over(tmp_path, fails):
+    tracer = Tracer(component="test")
+
+    def on_step(step, state):
+        if fails:
+            raise RuntimeError("the callback failed")
+
+    worker = small_worker(tmp_path, tracer, step_callback=on_step)
+    if fails:
+        with pytest.raises(RuntimeError, match="callback failed"):
+            worker.run()
+    else:
+        worker.run()
+    assert tracer.find(name="worker_setup")
+    with tracer.span("after_run"):
+        fresh_compile(7.0)
+    assert [s.name for s in tracer.spans
+            if s.parent is not None and s.parent.name == "after_run"] == []
+
+
 # -- the LM engine's turn ---------------------------------------------------------
 
 LM_KW = dict(vocab_size=61, d_model=16, n_layers=2, n_heads=2, d_ff=32,
