@@ -35,6 +35,40 @@ def replicate(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def canonical_sharding(sharding: Any) -> Any:
+    """``sharding`` spelled as a jit output carries it: JAX reads an
+    output's XLA sharding back into a spec without trailing unsharded dims,
+    so ``P(None, "data", None)`` comes back as ``P(None, "data")``. Both
+    place a leaf identically but compare unequal, and jit keys its cache on
+    the comparison: a donated state spelled the long way compiles a second
+    program when the step's own outputs come back. Shardings other than
+    NamedSharding pass through."""
+    if not isinstance(sharding, NamedSharding):
+        return sharding
+    spec = tuple(sharding.spec)
+    while spec and spec[-1] is None:
+        spec = spec[:-1]
+    if len(spec) == len(sharding.spec):
+        return sharding
+    return NamedSharding(sharding.mesh, P(*spec),
+                         memory_kind=sharding.memory_kind)
+
+
+def with_canonical_sharding(x: jax.Array) -> jax.Array:
+    """``x`` relabelled with `canonical_sharding`, over the same buffers:
+    the placement is unchanged, so nothing is copied and no program runs
+    (a ``device_put`` across processes would run one). A traced ``x``
+    (under ``jax.eval_shape``) is placed nowhere and comes back as is."""
+    if isinstance(x, jax.core.Tracer):
+        return x
+    sharding = canonical_sharding(x.sharding)
+    if sharding is x.sharding:
+        return x
+    return jax.make_array_from_single_device_arrays(
+        x.shape, sharding, [shard.data for shard in x.addressable_shards]
+    )
+
+
 def batch_sharding(mesh: Mesh, axis: BatchAxis = "data") -> NamedSharding:
     """Leading-dim batch sharding over the data axis or axis hierarchy
     (absent axes drop out; none present: replicate)."""

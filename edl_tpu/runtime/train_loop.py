@@ -22,11 +22,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from edl_tpu.models.base import Model
 from edl_tpu.obs.metrics import get_registry
-from edl_tpu.parallel.sharding import batch_shardings, shard_batch
+from edl_tpu.parallel.sharding import (
+    batch_shardings,
+    canonical_sharding,
+    shard_batch,
+    with_canonical_sharding,
+)
 
 log = logging.getLogger("edl_tpu.runtime.train_loop")
 
@@ -336,16 +342,34 @@ class Trainer:
 
     def init_state(self, key: Optional[jax.Array] = None) -> TrainState:
         key = key if key is not None else jax.random.PRNGKey(self.config.seed)
-        params = self.model.init(key, self.mesh)
-        # Under jit, zeros_like/moment init inherits each param's sharding, so
-        # optimizer state for a row-sharded table is row-sharded too.
-        opt_state = jax.jit(self.opt.init)(params)
+        # Every leaf is created committed where the step returns it, in the
+        # spelling the step returns (`canonical_sharding`): each moment at
+        # its parameter's sharding (a row-sharded table's moments are
+        # row-sharded too), the step counter and the optimizer's counts
+        # replicated on the mesh. A leaf that is uncommitted, or spelled
+        # otherwise, comes back from the donating step changed, and step 2
+        # then compiles a second program for the new input shardings.
+        params = jax.tree_util.tree_map(
+            with_canonical_sharding, self.model.init(key, self.mesh)
+        )
+        replicated = NamedSharding(self.mesh, P())
+        opt_shardings = optax.tree_map_params(
+            self.opt,
+            # a traced parameter (under `jax.eval_shape`) is placed nowhere,
+            # and jit places its moments as it likes
+            lambda _, p: None if isinstance(p, jax.core.Tracer) else p.sharding,
+            jax.eval_shape(self.opt.init, params),
+            params,
+            transform_non_params=lambda _: replicated,
+        )
+        opt_state = jax.jit(self.opt.init, out_shardings=opt_shardings)(params)
         # Gate on param_spec exactly like the step-boundary pin: sharding the
         # moments WITHOUT being able to pin params would let XLA propagation
         # push the data-axis layout onto the params (implicit ZeRO-3 drift).
         if self.config.shard_opt_state and self.model.param_spec is not None:
             opt_state = self._shard_opt_state(opt_state)
-        return TrainState(jnp.zeros((), jnp.int32), params, opt_state)
+        step = jnp.zeros((), jnp.int32, device=replicated)
+        return TrainState(step, params, opt_state)
 
     def _zero_specs(self, tree: Any) -> Any:
         """Per-leaf ZeRO-1 shard specs for a params-shaped pytree (grads or
@@ -379,8 +403,6 @@ class Trainer:
         batch axis (largest divisible dim — `zero_shard_spec`). Leaves that
         already carry a real sharding (moments of sharded params) and
         scalars are untouched."""
-        from jax.sharding import NamedSharding
-
         from edl_tpu.parallel.collective import zero_shard_spec
         from edl_tpu.parallel.sharding import present_axes
 
@@ -390,22 +412,15 @@ class Trainer:
 
         def target_sharding(x):
             """New sharding for leaves that should reshard; None otherwise.
-            Unchanged leaves must NOT pass through device_put — it would
-            COMMIT previously-uncommitted arrays (e.g. optimizer counts) to
-            their current device and poison the jit with device conflicts."""
-            if not hasattr(x, "sharding") or x.ndim == 0:
-                return None
-            sh = x.sharding
-            replicated = (
-                isinstance(sh, NamedSharding)
-                and all(s is None for s in sh.spec)
-            ) or getattr(sh, "is_fully_replicated", False)
-            if not replicated:
-                return None  # already sharded (e.g. embedding-table moments)
+            Unchanged leaves (the counts, moments that stay replicated) keep
+            the mesh sharding `init_state` committed them to, the one the
+            step returns; only the resharded moments are copied."""
+            if x.ndim == 0 or any(s is not None for s in x.sharding.spec):
+                return None  # a count, or already sharded (table moments)
             spec = zero_shard_spec(x.shape, self.mesh, self.config.batch_axis)
             if spec is None:
                 return None  # no divisible dim: stays replicated
-            return NamedSharding(self.mesh, spec)
+            return canonical_sharding(NamedSharding(self.mesh, spec))
 
         # One batched device_put over just the resharded leaves (the
         # codebase's placement convention — see parallel/sharding.py).
@@ -676,10 +691,10 @@ class Trainer:
             )
         def state_aval(x):
             # Only committed arrays pin their sharding into the lowering.
-            # Uncommitted leaves (e.g. the step counter, fresh optimizer
-            # counts) sit on a single device and would otherwise conflict
-            # with the mesh-placed params; leaving their sharding
-            # unspecified lets jit place them exactly as the lazy path does.
+            # Every leaf of a state from `init_state` or a restore is
+            # committed to the sharding the step returns, so this pins what
+            # the lazy path's jit sees; an uncommitted leaf (a state built
+            # by hand) is left for jit to place, as the lazy path does.
             sharding = (
                 x.sharding if getattr(x, "_committed", False) else None
             )
@@ -771,9 +786,9 @@ class Trainer:
         """Record whether the step function recompiled since the last call.
 
         Warmup self-detects: cache growth is absorbed silently until the
-        cache holds steady across one step (the step-1 compile, plus the
-        legitimate second program when donated outputs commit a sharding
-        the freshly-placed init state didn't have). After that first
+        cache holds steady across one step (the step-1 compile; a state
+        from `init_state` or a checkpoint restore already carries the
+        shardings the step returns, so step 2 adds none). After that first
         stable step, any growth is a retrace — logged loudly, counted in
         ``self.retraces``, and surfaced in ``run()`` metrics. A wire-codec
         widening rebuilds ``_jit_step_wire`` and legitimately shrinks the

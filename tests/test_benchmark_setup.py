@@ -130,7 +130,8 @@ def test_the_five_read_a_worker_run_and_nothing_without_its_spans(tmp_path):
     assert all(v is not None for v in got.values()), got
     assert 0 < got["setup_init_state_s.train"] \
         <= got["setup_worker_s.train"]
-    assert got["setup_step_compiles.train"] >= 1
+    # a fresh state is placed where the step returns it: one program
+    assert got["setup_step_compiles.train"] == 1
     assert got["setup_trace_lower_s.train"] > 0
     assert got["setup_xla_compile_s.train"] > 0
     # a program that records none of these spans (an older one): every

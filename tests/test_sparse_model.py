@@ -161,9 +161,18 @@ def test_a_selection_of_every_causal_key_changes_nothing():
 #: K/V, no sum over the repeats, dK/dV leave `flash_bwd_dkv` in k's shape,
 #: and a call with groups goes through `jax.jit` (equal calls share a
 #: trace). "transformer" (no groups) is the parent's of PR 32, untouched.
+#: Both re-recorded ON PURPOSE when `Trainer.init_state` began to commit
+#: every leaf to the sharding the step returns, in the spelling it returns
+#: (they were 6cda28d2...0c08d and 40b71001...58a08): the step and its
+#: inputs' values are unchanged, only the lowering's input shardings. Each
+#: new hash is the earlier tree's step lowered on the state that its own
+#: first step returned, the program its steps 2 on ran: checked by
+#: lowering on the earlier tree (init_state(), one `_jit_step`, lower on
+#: its output) and on this tree (init_state()), with the same model, mesh
+#: and batch as below.
 LOWERED = {
-    "transformer": "6cda28d287b364371755d16242f437e25ef2316df25ea872f8030e7963a0c08d",
-    "hybrid": "40b71001a88715a7d8406b9a00f401659d669e2d623f656b932f801131658a08",
+    "transformer": "f9b0a9221daf9c13cf5de3db0dcb43564ba8abedde8866b99f6f31e95072528a",
+    "hybrid": "2be4484728c4ab6970b0725e348564a57f421ee20923f60fea402508ced674e1",
 }
 
 

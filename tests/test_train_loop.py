@@ -148,3 +148,61 @@ def test_zero1_sharded_opt_state_matches_replicated():
         sh = getattr(p, "sharding", None)
         if isinstance(sh, NamedSharding):
             assert all(s is None for s in sh.spec)
+
+
+def _tiny_hybrid():
+    """Sparse-attention and expert layers of `models.hybrid`, the family of
+    most benchmark cells, at toy widths."""
+    from edl_tpu.models import hybrid
+
+    return hybrid.make_model(
+        pattern="SE", seq_len=64, vocab_size=64, d_model=32, n_heads=4,
+        n_kv_heads=2, head_dim=8, indexer_heads=2, indexer_head_dim=8,
+        indexer_topk=16, router_score="softmax", expert_act="silu",
+        shared_width=0, n_experts=4, experts_count=2, experts_first=1,
+        top_k=2, expert_width=16, remat=True,
+    )
+
+
+@pytest.mark.parametrize(
+    "make_model, devices, config",
+    [
+        (lambda: fit_a_line.MODEL, 1, TrainerConfig(optimizer="adam")),
+        (lambda: fit_a_line.MODEL, 1,
+         TrainerConfig(optimizer="sgd", momentum=0.9)),
+        (lambda: mnist.MODEL, 2,
+         TrainerConfig(optimizer="adam", shard_opt_state=True)),
+        (lambda: ctr.make_model(sparse_dim=1001), 2,
+         TrainerConfig(optimizer="adagrad")),
+        (_tiny_hybrid, 1, TrainerConfig(optimizer="adam")),
+    ],
+    ids=["adam-1dev", "sgd-1dev", "adam-2dev-zero1", "adagrad-2dev-rowsharded",
+         "adam-1dev-hybrid"],
+)
+def test_a_fresh_state_is_where_the_step_returns_it(make_model, devices, config):
+    """Every leaf of `init_state()` — params, moments, counts, the step
+    counter — is committed to the sharding the donating step returns for
+    it, so three steps compile one program and no second one at step 2."""
+    model = make_model()
+    mesh = build_mesh(MeshSpec({"data": devices}), jax.devices()[:devices])
+    trainer = Trainer(model, mesh, config)
+    state = trainer.init_state()
+
+    def shardings(tree):
+        return jax.tree_util.tree_map(lambda x: x.sharding, tree)
+
+    fresh = shardings(state)
+    assert all(x._committed for x in jax.tree_util.tree_leaves(state))
+    rng = np.random.default_rng(0)
+    for n in range(3):
+        state, _ = trainer.train_step(
+            state, trainer.place_batch(model.synthetic_batch(rng, 8))
+        )
+        if n == 0:
+            assert shardings(state) == fresh
+    assert trainer._jit_cache_size() == 1
+    if devices > 1:  # the case places something across the mesh
+        assert any(
+            any(a is not None for a in s.spec)
+            for s in jax.tree_util.tree_leaves(fresh.opt_state)
+        )
